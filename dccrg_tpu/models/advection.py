@@ -33,32 +33,13 @@ from ..utils.fallback import fallback_call
 __all__ = ["Advection"]
 
 
-def _calibrated_edge(key: str, default: float) -> float:
-    """A flat-vs-boxed dispatch edge constant: prefer the boxed
-    per-level passes when ``flat_n_vox > edge * boxed_vol``.  Measured
-    on chip and written by ``tools/recalibrate.py --write``.  A missing,
-    malformed, or out-of-range file falls back to the default — a
-    calibration artifact must never break or silently pin the
-    dispatch."""
-    import json
-    import math
-    import pathlib
-
-    path = (pathlib.Path(__file__).resolve().parents[2]
-            / "tools" / "dispatch_calibration.json")
-    try:
-        edge = float(json.loads(path.read_text())[key])
-    except (OSError, KeyError, ValueError, TypeError):
-        return default
-    if not math.isfinite(edge) or not 0.5 <= edge <= 100.0:
-        return default
-    return edge
-
-
-def _flat_boxed_edge() -> float:
-    """2-level Pallas-kernel edge; default = the r2-measured ~2x flat
-    per-voxel advantage."""
-    return _calibrated_edge("flat_boxed_edge", 2.0)
+#: flat-vs-boxed dispatch edge per flat form: prefer the boxed per-level
+#: passes when ``flat_n_vox > edge * boxed_vol``.  Not yet measured on
+#: chip: 2.0 is the ~2x per-voxel advantage the 2-level kernel showed in
+#: round 2 (the multi-level kernel is assumed to be of the same class);
+#: 1.5 gives the streaming XLA pyramid modest slack for the boxed
+#: passes' per-level pass/concat overhead
+_BOXED_EDGE = {"pallas": 2.0, "ml_pallas": 2.0, "ml": 1.5}
 
 
 def build_face_tables(grid, hood_id, tables, dtype, hood_arrays=None):
@@ -206,20 +187,6 @@ def _table_specs(tabs):
     )
 
 
-def _ml_boxed_edge(kind: str) -> float:
-    """Multi-level (3+ level) whole-run edge, per FORM: the
-    VMEM-resident Pallas kernel and the streaming XLA pyramid have
-    different per-voxel rates, so each calibrates from a battery run
-    that measured ITS kind (tools/recalibrate.py names the key after
-    refined3_ml's recorded path).  Defaults until measured: 2.0 for the
-    kernel (the 2-level kernel's measured class of advantage), 1.5 for
-    the XLA form (streams like the boxed passes, modest slack for their
-    per-level pass/concat overhead)."""
-    if kind == "ml_pallas":
-        return _calibrated_edge("ml_pallas_boxed_edge", 2.0)
-    return _calibrated_edge("ml_boxed_edge", 1.5)
-
-
 class Advection:
     #: the reference's 9-double cell (density, velocity, flux, max_diff;
     #: lengths live in the geometry tables instead of per-cell storage)
@@ -276,16 +243,10 @@ class Advection:
             self._flat_run = self._build_flat_run()
             # cost-based choice when both fast paths qualify: prefer
             # boxed only when the flat form's voxel inflation exceeds
-            # its per-voxel rate advantage over the boxed passes.  Each
-            # compiled form reads its own edge constant from
-            # tools/dispatch_calibration.json (written by
-            # ``tools/recalibrate.py --write`` from the on-chip
-            # battery's pinned measurements: flat_boxed_edge for the
-            # 2-level kernel, ml_pallas_boxed_edge / ml_boxed_edge for
-            # the multi-level forms), with documented defaults until a
-            # battery run lands.  Interpret mode (tests) and the
-            # 2-level sharded XLA form keep the flat preference so the
-            # flat numerics stay exercised
+            # its per-voxel rate advantage over the boxed passes (one
+            # edge constant per compiled form, _BOXED_EDGE).  Interpret
+            # mode (tests) and the 2-level sharded XLA form keep the
+            # flat preference so the flat numerics stay exercised
             if (
                 self._flat_kind in ("pallas", "ml", "ml_pallas")
                 and self._flat_run is not None
@@ -294,8 +255,7 @@ class Advection:
                 boxed_vol = sum(
                     int(np.prod(b.shape)) for b in self.boxed.boxes.values()
                 )
-                edge = (_flat_boxed_edge() if self._flat_kind == "pallas"
-                        else _ml_boxed_edge(self._flat_kind))
+                edge = _BOXED_EDGE[self._flat_kind]
                 self._prefer_boxed = self._flat_n_vox > edge * boxed_vol
 
     # ------------------------------------------------------ static tables
@@ -396,7 +356,7 @@ class Advection:
         from ..parallel.exec_cache import traced_jit
         from ..parallel.halo import HaloExchange
         from ..parallel.mesh import SHARD_AXIS
-        from ..utils.compat import shard_map
+        from jax import shard_map
 
         ex = self._exchange
         host_face = {
@@ -563,7 +523,7 @@ class Advection:
         grid (ops/flat_amr.py): the entire run loop in VMEM, one launch.
         None when the grid/device/dtype does not qualify; the boxed path
         remains the general fallback (and the step()/indicator path)."""
-        from ..ops.dense_advection import have_pallas, pallas_available
+        from ..ops.dense_advection import pallas_available
         from ..ops.flat_amr import (
             build_flat_amr_sharded,
             build_flat_amr_tables,
@@ -595,7 +555,6 @@ class Advection:
             if (
                 tml["n_devices"] == 1
                 and np.dtype(self.dtype) == np.float32
-                and have_pallas()
                 and (interpret or pallas_available(self.dtype))
                 and flat_ml_kernel_fits(self._flat_n_vox, tml["vl"])
             ):
@@ -623,8 +582,6 @@ class Advection:
             return make_flat_amr_run_sharded(self.grid, ts, dtype=jdt)
 
         interpret = self.use_pallas == "interpret"
-        if not have_pallas():
-            return None
         if np.dtype(self.dtype) != np.float32:
             return None
         if not (interpret or pallas_available(self.dtype)):
@@ -781,7 +738,7 @@ class Advection:
         self.dense_kind = bundle["dense_kind"]
 
     def _build_dense_bundle(self) -> dict:
-        from ..utils.compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         from ..parallel.dense import HaloExtend

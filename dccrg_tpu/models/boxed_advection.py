@@ -53,7 +53,7 @@ floating-point association order.
 from __future__ import annotations
 
 import jax
-from ..utils.compat import shard_map
+from jax import shard_map
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P

@@ -133,7 +133,7 @@ class GameOfLife:
         """Split-phase step: collective and inner compute are dataflow-
         independent inside one XLA program; outer compute depends on the
         merged ghosts.  Bit-identical results to the blocking step."""
-        from ..utils.compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         from ..parallel.mesh import SHARD_AXIS, put_table, shard_spec
@@ -271,7 +271,7 @@ class GameOfLife:
         return run
 
     def _build_dense_bundle(self):
-        from ..utils.compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         from ..parallel.dense import HaloExtend
@@ -285,14 +285,13 @@ class GameOfLife:
         ring = HaloExtend(D)
 
         # single device + VMEM fit: the whole run in one Pallas launch
-        from ..ops.dense_advection import have_pallas, pallas_available
+        from ..ops.dense_advection import pallas_available
         from ..ops.gol_kernel import gol_run_fits, make_gol_run
 
         interpret = self.use_pallas == "interpret"
         fused_run = None
         if (
             self.use_pallas
-            and have_pallas()
             and D == 1
             and gol_run_fits(nyl, nx)
             and (interpret or pallas_available(np.float32))

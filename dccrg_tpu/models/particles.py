@@ -157,7 +157,7 @@ class Particles:
         whose per-cell sizes the voxel arithmetic cannot express, or an
         id space past the integer width jax can use) — the host path
         stays the general mechanism."""
-        from ..utils.compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as Pspec
 
         grid = self.grid

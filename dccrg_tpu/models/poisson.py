@@ -518,7 +518,7 @@ class Poisson:
         multi-device, f64, too large, no Pallas); the XLA solver stays
         the fallback and the oracle (solutions agree to solver
         tolerance — the in-kernel dot association differs)."""
-        from ..ops.dense_advection import have_pallas, pallas_available
+        from ..ops.dense_advection import pallas_available
         from ..ops.poisson_kernel import bicg_fits, make_bicg_solve
 
         t = self._flat_tables
@@ -533,7 +533,6 @@ class Poisson:
             or t.get("vl", 1) > 1
             or np.dtype(self.dtype) != np.float32
             or not bicg_fits(int(np.prod(t["shape"])))
-            or not have_pallas()
             or not (interpret or pallas_available(np.float32))
         ):
             return None

@@ -101,7 +101,7 @@ class Vlasov:
         self._step, self._run = bundle["step"], bundle["run"]
 
     def _build_dense_bundle(self) -> dict:
-        from ..utils.compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         info = self.info
@@ -154,7 +154,7 @@ class Vlasov:
         # rejection at first call disables it for the instance (the
         # flat-AMR / fused-GoL fallback pattern)
         fused_block = 0
-        from ..ops.dense_advection import have_pallas, pallas_available
+        from ..ops.dense_advection import pallas_available
         from ..ops.vlasov_kernel import (
             make_vlasov_step_blocked,
             pick_vlasov_block,
@@ -166,7 +166,6 @@ class Vlasov:
         body_fast = None
         if (
             self.use_pallas
-            and have_pallas()
             and np.dtype(dtype) == np.float32
             and blk
             and (interpret or pallas_available(np.float32))
@@ -399,7 +398,7 @@ class Vlasov:
         from ..parallel.exec_cache import traced_jit
         from ..parallel.halo import HaloExchange
         from ..parallel.stencil import ordered_sum
-        from ..utils.compat import shard_map
+        from jax import shard_map
         from .advection import _table_specs, build_split_tables
 
         grid = self.grid

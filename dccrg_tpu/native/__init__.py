@@ -1,14 +1,18 @@
 """Native (C++) host-side kernels with transparent numpy fallback.
 
-The library auto-builds ``libneighbor_kernels.so`` from the bundled source
-on first use (g++ is part of the supported toolchain); set
-``DCCRG_TPU_NATIVE=0`` to force the pure-numpy path.
+The library builds ``libneighbor_kernels-<key>.so`` from the bundled
+source on first use (g++ is part of the supported toolchain); set
+``DCCRG_TPU_NATIVE=0`` to force the pure-numpy path.  ``<key>`` hashes
+the source, the compile flags and the host CPU (:func:`build_keyed`), so
+a library built on another machine — a copied tree — is never loaded.
 """
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import pathlib
+import platform
 import subprocess
 
 import numpy as np
@@ -16,6 +20,7 @@ import numpy as np
 from ..core.neighbors import InconsistentGridError
 
 __all__ = [
+    "build_keyed",
     "native_find_neighbors",
     "native_sort_unique_u64",
     "native_invert_and_pairs",
@@ -25,9 +30,48 @@ __all__ = [
 ]
 
 _DIR = pathlib.Path(__file__).resolve().parent
-_LIB_PATH = _DIR / "libneighbor_kernels.so"
+_FLAGS = ("-O3", "-march=native", "-fopenmp", "-shared", "-fPIC")
 _lib = None
 _tried = False
+
+
+def _host_cpu() -> str:
+    """What ``-march=native`` compiles for: the CPU model and its
+    feature flags (Linux), else the platform's own description."""
+    try:
+        with open("/proc/cpuinfo") as f:
+            info = f.read().split("\n\n")[0]
+        return "\n".join(
+            ln for ln in info.splitlines()
+            if ln.split(":")[0].strip() in ("vendor_id", "model name",
+                                            "flags", "Features")
+        )
+    except OSError:
+        return f"{platform.machine()} {platform.processor()}"
+
+
+def build_keyed(src: pathlib.Path, stem: str, flags,
+                suffix: str = "") -> pathlib.Path:
+    """Compile ``src`` with g++ ``flags`` to
+    ``<src dir>/<stem>-<key><suffix>``,
+    where ``key`` hashes the source bytes, the flags and the host CPU;
+    an existing output with that key is reused.  Built from committed
+    source on the machine that runs it: a copied tree's binaries carry
+    another host's key and are never picked up.  Raises
+    ``OSError``/``CalledProcessError`` when the build fails."""
+    h = hashlib.sha256(src.read_bytes())
+    h.update("\0".join(flags).encode())
+    h.update(_host_cpu().encode())
+    out = src.parent / f"{stem}-{h.hexdigest()[:16]}{suffix}"
+    if not out.exists():
+        tmp = out.with_name(f"{out.name}.{os.getpid()}.tmp")
+        try:
+            subprocess.run(["g++", *flags, "-o", str(tmp), str(src)],
+                           check=True, capture_output=True)
+            os.replace(tmp, out)  # atomic: concurrent builders agree
+        finally:
+            tmp.unlink(missing_ok=True)
+    return out
 
 
 def _load():
@@ -37,18 +81,10 @@ def _load():
     _tried = True
     if os.environ.get("DCCRG_TPU_NATIVE", "1") == "0":
         return None
-    src = _DIR / "neighbor_kernels.cpp"
     try:
-        if not _LIB_PATH.exists() or _LIB_PATH.stat().st_mtime < src.stat().st_mtime:
-            subprocess.run(
-                [
-                    "g++", "-O3", "-march=native", "-fopenmp", "-shared",
-                    "-fPIC", "-o", str(_LIB_PATH), str(src),
-                ],
-                check=True,
-                capture_output=True,
-            )
-        lib = ctypes.CDLL(str(_LIB_PATH))
+        path = build_keyed(_DIR / "neighbor_kernels.cpp",
+                           "libneighbor_kernels", _FLAGS, suffix=".so")
+        lib = ctypes.CDLL(str(path))
     except (OSError, subprocess.CalledProcessError):
         return None
     i64p = np.ctypeslib.ndpointer(np.int64, flags="C")

@@ -21,7 +21,7 @@ seam instead:
   named ``TraceAnnotation`` span for TensorBoard/xprof;
 * a streaming exporter (:func:`stream_to`) appending incremental JSONL
   snapshots on a period, so a hung or killed run leaves phase evidence
-  behind (``tools/soak.py``, ``bench.py``, the on-chip battery);
+  behind (``tools/soak.py``);
 * a structured event timeline (``obs.timeline``) recording every
   completed phase as a begin/end span, exportable as Chrome trace-event
   JSON (:func:`export_chrome_trace`, view in perfetto);

@@ -1,8 +1,7 @@
 """JSON export of a telemetry snapshot (``telemetry.json``).
 
-``bench.py`` writes one file per bench run and folds the phase breakdown
-into ``BENCH_DETAIL.json``; ``tools/check_telemetry.py`` gates CI on the
-file containing every instrumented phase.
+``tools/check_telemetry.py`` writes one from its probe workload and
+gates CI on the file containing every instrumented phase.
 """
 from __future__ import annotations
 

@@ -15,9 +15,7 @@ process died is on disk as complete, parseable lines:
 cumulative (the registry's monotonic totals), so consumers diff
 consecutive lines for rates.
 
-Wired into ``tools/soak.py`` (per-subsystem child streams), ``bench.py``
-(the real-measurement child) and ``tools/onchip_r3.py`` battery
-children.
+Wired into ``tools/soak.py`` (per-subsystem child streams).
 """
 from __future__ import annotations
 
@@ -161,7 +159,7 @@ def stream_to(path: str, period: float = 30.0, registry=None,
     ``at_exit`` (the default) a final snapshot + stop is registered via
     ``atexit``, so a child process that simply runs to completion (or is
     interrupted between ticks) still leaves its closing state — the
-    one-call form the soak/bench/battery children use."""
+    one-call form the soak children use."""
     s = TelemetryStream(path, period=period, registry=registry, extra=extra,
                         truncate=truncate)
     s.start()

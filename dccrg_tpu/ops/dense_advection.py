@@ -16,14 +16,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAVE_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAVE_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = [
     "pallas_available",
@@ -42,15 +36,7 @@ _FUSED_VMEM_BUDGET = 72 * 1024 * 1024
 _FUSED_ARRAYS = 17
 
 
-def have_pallas() -> bool:
-    """Whether the Pallas modules imported (required even for the
-    interpreter path — the kernels reference pl/pltpu unconditionally)."""
-    return _HAVE_PALLAS
-
-
 def pallas_available(dtype) -> bool:
-    if not _HAVE_PALLAS:
-        return False
     if np.dtype(dtype) != np.float32:
         return False
     try:

@@ -37,19 +37,13 @@ concat, and kernel-launch boundary of the boxed path.
 from __future__ import annotations
 
 import jax
-from ..utils.compat import shard_map
+from jax import shard_map
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 import numpy as np
 
 from .dense_advection import _make_rolls
-
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAVE_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAVE_PALLAS = False
 
 __all__ = [
     "build_flat_amr_tables",

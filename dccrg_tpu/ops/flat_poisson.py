@@ -265,7 +265,7 @@ def make_flat_poisson_apply(tables, dtype, mesh=None):
         # pooling/broadcast stays slab-local (coarse blocks never
         # straddle slabs), so no collective permutes enter the solver's
         # hot loop for it
-        from ..utils.compat import shard_map
+        from jax import shard_map
         from ..parallel.mesh import SHARD_AXIS as _AX
         from jax.sharding import PartitionSpec as _P
 
@@ -310,7 +310,7 @@ def make_flat_poisson_apply(tables, dtype, mesh=None):
             flat = vox_arr.reshape(-1)
             return jnp.where(wb_valid, flat[wb_rows], 0)[None]
     else:
-        from ..utils.compat import shard_map
+        from jax import shard_map
 
         nzv, nyv, nxv = shape
         slab = nzv // D

@@ -9,7 +9,7 @@ constants (the TPU analogue of the reference's cached neighbor pointer
 lists + per-pair factors, ``poisson_solve.hpp:716-965``).  XLA's TPU
 lowering of the ``[R, K]`` row gather is the one measured loss in the
 benchmark suite (7.05e6 cell-iters/s on chip vs 52.7e6 on the CPU
-denominator, round-3 battery), so this module removes the gather:
+denominator, round-3 chip run), so this module removes the gather:
 
 Group the nonzero entries by their ROW OFFSET ``d = nbr_rows[r,k] - r``.
 All entries sharing an offset collapse into one dense term

@@ -30,10 +30,11 @@ Zero-cold-start warm restart: the LRU above dies with the process, so a
 restarted or rescaled worker used to pay the full compile storm on its
 first churn cycle even when its :class:`~dccrg_tpu.parallel.shapes.
 ShapeSignature` had been seen before.  :func:`enable_persistent_cache`
-wires jax's persistent compilation cache (``jax_compilation_cache_dir``,
-via ``DCCRG_COMPILE_CACHE_DIR`` — auto-enabled at import so child
-processes inherit it purely through the environment, the same discipline
-as ``DCCRG_FAULT``) under the bucketed-shape discipline: fresh processes
+wires jax's persistent compilation cache at ``JAX_COMPILATION_CACHE_DIR``
+when that is set (auto-enabled at import, so child processes inherit it
+purely through the environment), else at the fixed in-checkout
+:data:`DEFAULT_CACHE_DIR` for entry points that ask, under the
+bucketed-shape discipline: fresh processes
 still *trace* (host work), but XLA compiles are served from disk.  A
 jax monitoring listener counts the cache's own hit/miss events
 (``epoch.persistent_cache{result=hit|miss}``), and a trace whose compile
@@ -50,6 +51,7 @@ import re
 import threading
 import time
 from collections import OrderedDict
+from pathlib import Path
 from typing import NamedTuple
 
 from ..obs.registry import metrics as _metrics
@@ -302,40 +304,36 @@ def _on_cache_event(name: str, **kw) -> None:
         _metrics.inc("epoch.persistent_cache", result="miss")
 
 
-def enable_persistent_cache(path: str | None = None) -> str | None:
-    """Wire jax's persistent compilation cache at ``path`` (default:
-    ``DCCRG_COMPILE_CACHE_DIR``; no-op returning None when neither is
-    set).  Thresholds are dropped to zero so every module is cached —
-    the bucketed-shape discipline keeps the entry set small (one per
-    kernel per ShapeSignature), and a restarted/rescaled worker landing
-    on a previously-seen signature compiles nothing.  Called at import,
-    so child processes opt in purely via the environment."""
-    if path is None:
-        path = os.environ.get("DCCRG_COMPILE_CACHE_DIR") or None
-    if not path:
-        return None
+#: where :func:`enable_persistent_cache` keeps the cache when
+#: ``JAX_COMPILATION_CACHE_DIR`` is unset: one fixed path inside the
+#: checkout (the path is part of each entry's key, so it must not move
+#: between runs); listed in ``.gitignore``
+DEFAULT_CACHE_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
+
+
+def enable_persistent_cache() -> str:
+    """Wire jax's persistent compilation cache and return its directory:
+    ``JAX_COMPILATION_CACHE_DIR`` when set (no other directory is set in
+    code), else :data:`DEFAULT_CACHE_DIR`.  Thresholds are dropped to
+    zero so every module is cached — the bucketed-shape discipline keeps
+    the entry set small (one per kernel per ShapeSignature), and a
+    restarted/rescaled worker landing on a previously-seen signature
+    compiles nothing.  Idempotent."""
     import jax
 
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR") or str(
+        DEFAULT_CACHE_DIR)
     os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", str(path))
-    for opt, val in (
-        ("jax_persistent_cache_min_compile_time_secs", 0),
-        ("jax_persistent_cache_min_entry_size_bytes", -1),
-    ):
-        try:
-            jax.config.update(opt, val)
-        except Exception:  # noqa: BLE001 — knob absent on this jax
-            pass
+    jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     if not _PERSISTENT["listener"]:
-        try:
-            from jax._src import monitoring
+        from jax._src import monitoring
 
-            monitoring.register_event_listener(_on_cache_event)
-            _PERSISTENT["listener"] = True
-        except Exception:  # noqa: BLE001 — no monitoring: cache still
-            pass           # works, only the hit/miss split goes dark
-    _PERSISTENT["dir"] = str(path)
-    return str(path)
+        monitoring.register_event_listener(_on_cache_event)
+        _PERSISTENT["listener"] = True
+    _PERSISTENT["dir"] = path
+    return path
 
 
 class TracedKernel:
@@ -473,8 +471,8 @@ class ExecutableCache:
             self._entries.clear()
 
 
-# auto-wire the persistent compilation cache from the environment at
-# import (no-op when DCCRG_COMPILE_CACHE_DIR is unset) — child processes
-# receive the warm-restart cache the same way they receive their fault
-# schedule (DCCRG_FAULT): purely via env
-enable_persistent_cache()
+# auto-wire the persistent compilation cache when the environment names
+# one — child processes receive the warm-restart cache the same way they
+# receive their fault schedule (DCCRG_FAULT): purely via env
+if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    enable_persistent_cache()

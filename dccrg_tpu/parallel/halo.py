@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-from ..utils.compat import shard_map
+from jax import shard_map
 
 from ..obs.registry import metrics as _metrics
 from . import halo_dma
@@ -57,10 +57,8 @@ def interior_steps_per_exchange(ghost_depth: int,
     inside the loop each interior step — correct at ANY k, since the
     in-kernel protocol equals k solo steps — so this budget is the
     PLANNING bound for the follow-on that hoists one depth-k exchange
-    above the loop.  On jax 0.4.x the hoisted form must keep the DMA
-    start/wait split at program level (semaphore outputs across
-    ``pallas_call`` boundaries are unimplemented — see PR 7's notes),
-    exactly like the split-phase steps do."""
+    above the loop; the hoisted form keeps the DMA start/wait split at
+    program level, exactly like the split-phase steps do."""
     depth = max(int(ghost_depth), 0)
     radius = max(int(stencil_radius), 1)
     return max(depth // radius, 1)

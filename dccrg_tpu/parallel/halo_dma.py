@@ -28,9 +28,7 @@ Backend selection (``DCCRG_HALO_BACKEND``):
 collective oracle and compare bit-for-bit (see
 ``HaloExchange._verify_oracle``); mismatches are counted, never raised.
 
-Split start/wait: a DMA descriptor cannot yet cross a ``pallas_call``
-boundary on this jax (semaphore outputs are unimplemented in the 0.4.x
-interpreter), so each ring kernel starts *and* waits its copy; the
+Split start/wait: each ring kernel starts *and* waits its copy; the
 split-phase structure — interior compute issued with no data dependence
 on the in-flight payload, the ghost-row scatter as the wait — lives at
 the composed-program level exactly as it does for the collective
@@ -44,12 +42,13 @@ import os
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .mesh import SHARD_AXIS
 
 __all__ = [
     "BACKENDS",
-    "dma_supported",
     "interpret_mode",
     "resolve_backend",
     "ring_dma_start",
@@ -58,20 +57,6 @@ __all__ = [
 
 #: legal DCCRG_HALO_BACKEND values
 BACKENDS = ("collective", "pallas", "auto")
-
-try:  # Pallas is part of jax, but keep the engine importable without it
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAVE_PALLAS = True
-except Exception:  # noqa: BLE001 — any import failure means no DMA path
-    pl = pltpu = None
-    _HAVE_PALLAS = False
-
-
-def dma_supported() -> bool:
-    """Whether the Pallas TPU primitives are importable at all."""
-    return _HAVE_PALLAS
 
 
 def interpret_mode() -> bool:
@@ -97,15 +82,10 @@ def _env_backend() -> str:
 
 def resolve_backend() -> str:
     """The transport a new halo schedule should compile: the env choice,
-    with ``auto`` meaning pallas on TPU and collective everywhere else,
-    and an explicit ``pallas`` degrading to collective only when Pallas
-    itself cannot be imported."""
+    with ``auto`` meaning pallas on TPU and collective everywhere else."""
     env = _env_backend()
     if env == "auto":
-        return ("pallas" if _HAVE_PALLAS and not interpret_mode()
-                else "collective")
-    if env == "pallas" and not _HAVE_PALLAS:
-        return "collective"
+        return "collective" if interpret_mode() else "pallas"
     return env
 
 
@@ -140,20 +120,12 @@ def _dma_kernel(in_ref, out_ref, send_sem, recv_sem, *, k: int, D: int):
     rdma.wait()
 
 
-def _any_space():
-    """The HBM-resident ("ANY") memory space across pltpu spellings."""
-    space = getattr(pltpu, "ANY", None)
-    if space is None:
-        space = pltpu.TPUMemorySpace.ANY
-    return space
-
-
 def ring_copy(payload, k: int, D: int, *, interpret: bool):
     """DMA-ship one ring step's packed ``[S_k, ...]`` payload to device
     ``(d + k) % D``; returns the payload received from ``(d - k) % D``
     (the exact ``ppermute`` contract).  Must run inside a ``shard_map``
     body over :data:`SHARD_AXIS`."""
-    space = _any_space()
+    space = pl.ANY
     sem = pltpu.SemaphoreType.DMA
     return pl.pallas_call(
         functools.partial(_dma_kernel, k=k, D=D),

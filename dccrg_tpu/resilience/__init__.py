@@ -42,7 +42,7 @@ On top of recovery sits the **elastic fleet** (ISSUE 8):
   ``latest_valid()`` (new ``device.lost`` / ``step.hang`` fault sites
   prove every branch);
 * zero-cold-start warm restart — ``parallel/exec_cache.py`` wires
-  jax's persistent compilation cache (``DCCRG_COMPILE_CACHE_DIR``)
+  jax's persistent compilation cache (``JAX_COMPILATION_CACHE_DIR``)
   under the bucketed-shape discipline, so a restarted or rescaled
   worker landing on a seen ``ShapeSignature`` records
   ``epoch.recompiles == 0``.  ``tools/soak.py elastic`` is the proof

@@ -69,10 +69,8 @@ fleet.  This module is the front-end that exploits it:
   per **k** simulation steps, not per step.  The member ``call`` is
   wrapped in a ``lax.fori_loop`` stepping k interior steps inside the
   one vmapped jitted cohort body (the split-phase halo structure stays
-  at PROGRAM level — jax 0.4.x cannot split DMA start/wait across
-  ``pallas_call`` boundaries, so each interior step's exchange starts
-  and completes inside the loop body, exactly as the member program
-  does solo).  k is static per compiled body (``cohort_key`` carries
+  at PROGRAM level: each interior step's exchange starts and completes
+  inside the loop body, exactly as the member program does solo).  k is static per compiled body (``cohort_key`` carries
   it — changing only k at a held (signature, width) compiles exactly
   one new body); per-member ``remaining`` budgets ride along as a
   runtime argument so the occupancy mask freezes a member mid-k-block
@@ -508,8 +506,7 @@ class Cohort:
         owned row, by the budget clamp) and freezes the stale ghost
         fringe at its exchanged values.  The split-phase DMA structure
         stays at PROGRAM level inside the wide exchange, exactly as in
-        the member program (jax 0.4.x cannot split start/wait across
-        ``pallas_call`` boundaries)."""
+        the member program."""
         import jax
         import jax.numpy as jnp
 

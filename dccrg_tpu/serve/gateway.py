@@ -36,7 +36,7 @@ machine loss:
 
 * **Warm replacements**: routing keys on ``ShapeSignature.label()``
   (stable across processes) and every worker shares one
-  ``DCCRG_COMPILE_CACHE_DIR``, so a replacement worker serves the lost
+  ``JAX_COMPILATION_CACHE_DIR``, so a replacement worker serves the lost
   worker's cohorts with ``epoch.recompiles == 0``.
 
 * **Enforced admission** (closes ROADMAP item 2's policy slot): the

@@ -289,13 +289,7 @@ def main(argv=None) -> int:
     # first-device-use suffices — same contract as tests/conftest.py)
     if ("xla_force_host_platform_device_count"
             not in os.environ.get("XLA_FLAGS", "")):
-        try:
-            jax.config.update("jax_num_cpu_devices", a.n_devices)
-        except AttributeError:
-            os.environ["XLA_FLAGS"] = (
-                os.environ.get("XLA_FLAGS", "")
-                + f" --xla_force_host_platform_device_count="
-                f"{a.n_devices}").strip()
+        jax.config.update("jax_num_cpu_devices", a.n_devices)
     jax.config.update("jax_enable_x64", True)
     return run_worker(a.workdir, a.worker_id, a.n_devices,
                       max_idle_s=a.max_idle_s)

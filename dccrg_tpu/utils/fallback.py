@@ -5,7 +5,7 @@ always-available XLA form.  Whether the TPU compiler accepts a kernel
 can vary by hardware generation, so the first call may raise a lowering
 error — but a raise can equally be the caller's own mistake (bad state
 shape, wrong dtype) or a transient runtime fault (a one-off device OOM,
-a dropped tunnel).  The policy that distinguishes them: retry the
+a lost interconnect link).  The policy that distinguishes them: retry the
 failing call on the fallback path first.  If the fallback also raises,
 the error is the caller's and propagates unchanged.  If the fallback
 succeeds, the fast path is disabled for the instance only when the
@@ -14,11 +14,18 @@ call): immediately for a typed ``NotImplementedError``, after two
 consecutive marker-text hits otherwise (a transient error's text can
 coincidentally contain a marker).  Transient runtime faults fall back
 for this call only, so the kernel gets another chance next step.
+
+Every fall is counted in the obs registry as
+``kernel.fallbacks{label, kind}`` (``kind`` = ``transient`` or
+``disabled``), so a run that silently left its kernel is visible:
+``chip_smoke.py`` fails on any nonzero count.
 """
 from __future__ import annotations
 
 import sys
 import weakref
+
+from ..obs.registry import metrics
 
 __all__ = ["fallback_call"]
 
@@ -107,10 +114,12 @@ def fallback_call(label, fast, slow, disable, *args):
                 or falls >= _MAX_TRANSIENT_FALLS):
             print(f"{label} disabled ({e!r:.200}); using the fallback path",
                   file=sys.stderr)
+            metrics.inc("kernel.fallbacks", label=label, kind="disabled")
             disable()
         else:
             _transient_falls[key] = falls
             _marker_hits[key] = hits  # 0 resets: hits must be consecutive
+            metrics.inc("kernel.fallbacks", label=label, kind="transient")
             print(f"{label} fell back ({falls}/{_MAX_TRANSIENT_FALLS}, "
                   f"{e!r:.200}); will retry the fast path next call",
                   file=sys.stderr)

@@ -2,10 +2,11 @@
 sharding paths run on any host, mirroring the reference's
 "mpiexec -n N on localhost" testing model (reference tests/README:5-7).
 
-The benchmark (bench.py) runs on the real TPU; tests always run on the
-virtual CPU mesh for device-count-invariant assertions.  jax may already be
-imported by a pytest plugin, so the platform is set via jax.config (backends
-initialize lazily) rather than environment variables.
+The chip runs (chip_smoke.py, bench.py) drive a real TPU; tests always run
+on the virtual CPU mesh for device-count-invariant assertions.  jax may
+already be imported by a pytest plugin, so the platform is set via
+jax.config (backends initialize lazily); the environment variables are
+for the child processes some tests start.
 """
 import os
 
@@ -19,11 +20,6 @@ if "xla_force_host_platform_device_count" not in flags:
 import jax
 
 jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", 8)
-except AttributeError:
-    # older jax: the XLA_FLAGS fallback above is the only control; it was
-    # set before any backend initialized, so the 8-device mesh still forms
-    pass
+jax.config.update("jax_num_cpu_devices", 8)
 # the reference is double-precision throughout; tests assert in f64
 jax.config.update("jax_enable_x64", True)

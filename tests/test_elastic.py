@@ -469,7 +469,7 @@ WARM_CHILD = textwrap.dedent("""\
 
 def test_fresh_process_warm_start_zero_recompiles(tmp_path):
     """The zero-cold-start proof: two fresh processes resume the same
-    lineage under a shared ``DCCRG_COMPILE_CACHE_DIR`` and run one churn
+    lineage under a shared ``JAX_COMPILATION_CACHE_DIR`` and run one churn
     cycle; the second must land on the first's ShapeSignature with
     ``epoch.recompiles == 0`` — every compile a persistent-cache hit."""
     g, rng = make_adv_grid(2, seed=3)
@@ -482,7 +482,7 @@ def test_fresh_process_warm_start_zero_recompiles(tmp_path):
     CheckpointLineage(lineage_dir, keep=2).commit(g, s, ADV_SPEC)
 
     env = dict(os.environ)
-    env["DCCRG_COMPILE_CACHE_DIR"] = str(tmp_path / "cache")
+    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "cache")
     env["JAX_PLATFORMS"] = "cpu"
     reports = []
     for i in range(2):
@@ -501,6 +501,46 @@ def test_fresh_process_warm_start_zero_recompiles(tmp_path):
     assert b["recompiles"] == 0, b
     assert b["warm_compiles"] > 0, b
     assert b["persistent_cache"]["hits"] > 0, b
+
+
+CACHE_CHILD = textwrap.dedent("""
+    import sys
+    import jax
+    sys.path.insert(0, %r)
+    from dccrg_tpu.parallel import exec_cache
+    path = exec_cache.enable_persistent_cache()
+    print(path + "|" + str(jax.config.jax_compilation_cache_dir))
+""" % ROOT)
+
+
+def _cache_child(env):
+    env = {**env, "JAX_PLATFORMS": "cpu"}
+    r = subprocess.run([sys.executable, "-c", CACHE_CHILD], env=env,
+                       capture_output=True, text=True, timeout=120)
+    assert r.returncode == 0, r.stderr[-1500:]
+    wired, configured = r.stdout.strip().splitlines()[-1].split("|")
+    return wired, configured
+
+
+def test_persistent_cache_honours_jax_env(tmp_path):
+    """``JAX_COMPILATION_CACHE_DIR`` set: the cache lives there and no
+    other directory is configured in code."""
+    env = dict(os.environ)
+    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "jc")
+    wired, configured = _cache_child(env)
+    assert wired == configured == str(tmp_path / "jc")
+    assert (tmp_path / "jc").is_dir()
+
+
+def test_persistent_cache_defaults_inside_checkout():
+    """Unset: one fixed path inside the checkout (listed in .gitignore),
+    the same on every run so entries are found again."""
+    env = dict(os.environ)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    wired, configured = _cache_child(env)
+    assert wired == configured == os.path.join(ROOT, ".jax_cache")
+    with open(os.path.join(ROOT, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
 
 
 # ---------------------------------------------------- signature satellite

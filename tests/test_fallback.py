@@ -121,3 +121,36 @@ def test_both_paths_failing_propagates_the_fast_error():
     with pytest.raises(RuntimeError, match="Mosaic"):
         fallback_call("k", fast, slow, k.disable)
     assert not k.disabled  # the input was bad, not the kernel
+
+
+def _fallbacks(label, kind):
+    from dccrg_tpu.obs import metrics
+
+    return metrics.counter_value("kernel.fallbacks", label=label, kind=kind)
+
+
+def test_transient_fall_is_counted():
+    """Every fall reaches the obs registry, so a run that quietly left
+    its kernel shows it (chip_smoke.py fails on a nonzero count)."""
+    k = Kernel()
+    before = _fallbacks("counted-t", "transient")
+
+    def fast():
+        raise RuntimeError("RESOURCE_EXHAUSTED: transient")
+
+    assert fallback_call("counted-t", fast, lambda: 1, k.disable) == 1
+    assert _fallbacks("counted-t", "transient") == before + 1
+    assert not k.disabled
+
+
+def test_disabling_fall_is_counted():
+    k = Kernel()
+    before = _fallbacks("counted-d", "disabled")
+
+    def fast():
+        raise NotImplementedError("no lowering rule")
+
+    assert fallback_call("counted-d", fast, lambda: 1, k.disable) == 1
+    assert k.disabled
+    assert _fallbacks("counted-d", "disabled") == before + 1
+    assert _fallbacks("counted-d", "transient") == 0

@@ -229,10 +229,8 @@ def test_split_advection_bit_identical(monkeypatch, n_dev, backend):
 @pytest.mark.parametrize("periodic", [True, False],
                          ids=["periodic", "open"])
 def test_split_vlasov_matches_eager(monkeypatch, n_dev, periodic):
-    """The fused vlasov step matches the eager general step — bitwise
-    here (the split form reorders nothing), with the repo's 4-ULP
-    envelope as the licensed bound on jax 0.4.x (the acceptance
-    criterion's tolerance, matching the fused-kernel tests)."""
+    """The fused vlasov step matches the eager general step bitwise
+    (the split form reorders nothing)."""
     monkeypatch.setenv("DCCRG_HALO_BACKEND", "pallas")
     g = make_grid(n_dev=n_dev, length=(8, 8, 8), max_ref=1,
                   refine_ball=0.3, periodic=periodic)

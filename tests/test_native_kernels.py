@@ -142,3 +142,23 @@ def test_setops_helpers():
     start = np.array([0, 3, 3, 7, 10])
     got = csr_take(start, data, np.array([2, 0, 3]))
     np.testing.assert_array_equal(got, [30, 40, 50, 60, 0, 10, 20, 70, 80, 90])
+
+
+def test_build_keyed_names_binary_by_source_flags_and_host(tmp_path,
+                                                          monkeypatch):
+    """A build is keyed on source + flags + host CPU: an unchanged
+    input reuses the binary, any change (including another host's CPU,
+    e.g. a tree copied to another machine) builds a fresh one."""
+    from dccrg_tpu import native
+
+    src = tmp_path / "k.cpp"
+    src.write_text("extern \"C\" int f() { return 1; }\n")
+    flags = ("-O1", "-shared", "-fPIC")
+    a = native.build_keyed(src, "libk", flags, suffix=".so")
+    assert a.exists() and a.name.startswith("libk-")
+    assert native.build_keyed(src, "libk", flags, suffix=".so") == a
+    b = native.build_keyed(src, "libk", ("-O2",) + flags[1:], suffix=".so")
+    monkeypatch.setattr(native, "_host_cpu", lambda: "another cpu")
+    c = native.build_keyed(src, "libk", flags, suffix=".so")
+    assert len({a, b, c}) == 3
+    assert not list(tmp_path.glob("*.tmp"))

@@ -1,0 +1,199 @@
+"""Each main-path Pallas kernel compiles for a *described* TPU v5e.
+
+No chip is attached: ``jax.experimental.topologies`` describes a v5e
+2x2 host and the TPU compiler (installed with jax) lowers and compiles
+the kernels for it at the widths the chip smoke (``chip_smoke.py``) and
+``bench.py`` run them at.  This catches what the Pallas interpreter
+cannot — VMEM over the scoped limit, unaligned slices, unsupported
+lowerings — before any chip time is spent.
+
+The topology is described only inside the module-scoped fixture below
+(never at import or collection): one process at a time may load the
+TPU library, and every xdist worker imports this file.
+"""
+import os
+
+import numpy as np
+import pytest
+
+jax = pytest.importorskip("jax")
+jnp = jax.numpy
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    # the kernels run in f32 with 32-bit indices on the chip; conftest's
+    # x64 mode makes Mosaic's lowering of their int32 loop counters recurse
+    prev_x64 = jax.config.jax_enable_x64
+    jax.config.update("jax_enable_x64", False)
+    try:
+        yield topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure: no topology here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    finally:
+        jax.config.update("jax_enable_x64", prev_x64)
+        jax.config.update("jax_enable_compilation_cache", prev)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _spec(sharding, shape, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def test_fused_run_64x128x128(one_chip):
+    from dccrg_tpu.ops.dense_advection import fused_run_fits, make_fused_run
+
+    nz, ny, nx = 64, 128, 128
+    assert fused_run_fits(nz, ny, nx)
+    run = make_fused_run(nz, ny, nx, np.ones(3), 1.0)
+    s = lambda *shape: _spec(one_chip, shape)  # noqa: E731
+    _compile(run, s(nz, ny, nx), s(nz, ny, nx), s(nz, ny, nx),
+             s(nz, ny, nx), s(1, 1, nx), s(1, ny, 1), s(nz, 1, 1),
+             s(nz, 1, 1), s(), _spec(one_chip, (), jnp.int32))
+
+
+def test_blocked_direct_128x512x512(one_chip):
+    from dccrg_tpu.ops.dense_advection import (
+        make_flux_update_blocked_direct,
+        pick_step_block,
+    )
+
+    nz, ny, nx = 128, 512, 512
+    block = pick_step_block(nz, ny, nx)
+    assert block >= 2
+    upd = make_flux_update_blocked_direct(nz, ny, nx, block, np.ones(3), 1.0)
+    s = lambda *shape: _spec(one_chip, shape)  # noqa: E731
+    c, p = s(nz, ny, nx), s(1, ny, nx)
+    _compile(upd, c, p, p, c, c, c, p, p, s(1, 1, nx), s(1, ny, 1),
+             s(nz, 1, 1), s(nz, 1, 1), s())
+
+
+def test_plane_flux_update(one_chip):
+    from dccrg_tpu.ops.dense_advection import make_flux_update
+
+    nz, ny, nx = 32, 128, 128
+    upd = make_flux_update(nz, ny, nx, np.ones(3), 1.0)
+    s = lambda *shape: _spec(one_chip, shape)  # noqa: E731
+    _compile(upd, s(nz + 2, ny, nx), s(nz, ny, nx), s(nz, ny, nx),
+             s(nz + 2, ny, nx), s(1, 1, nx), s(1, ny, 1), s(nz, 1, 1),
+             s(nz, 1, 1), s())
+
+
+def test_flat_amr_refined(one_chip):
+    """bench.py's 48^3 ball-refined grid: a 96^3 fine-voxel layout, the
+    x extent lane-padded to 128 (it fits VMEM).  The unpadded form takes
+    ~150 s to compile here (unaligned lane rolls) and is not the
+    dispatched one at this size."""
+    from dccrg_tpu.ops.flat_amr import (
+        flat_amr_fits,
+        make_flat_amr_run,
+        pad_lane_extent,
+    )
+
+    n = 96
+    nxp = pad_lane_extent(n)
+    assert nxp == 128 and flat_amr_fits(n * n * nxp)
+    run = make_flat_amr_run(n, n, n, nx_pad=nxp)
+    a = _spec(one_chip, (n, n, n))
+    _compile(run, *([a] * 9), _spec(one_chip, ()),
+             _spec(one_chip, (), jnp.int32))
+
+
+def test_flat_ml_pallas(one_chip):
+    """The 3-level whole-run kernel at bench.py's refined3 size (16^3
+    level 0 refined twice: a 64^3 finest-voxel layout)."""
+    from dccrg_tpu.ops.flat_amr import (
+        flat_ml_kernel_fits,
+        make_flat_ml_run_pallas,
+    )
+
+    n, vl = 64, 2
+    assert flat_ml_kernel_fits(n ** 3, vl)
+    run = make_flat_ml_run_pallas(n, n, n, vl, (True, True))
+    a = _spec(one_chip, (n, n, n))
+    _compile(lambda *x: run(*x[:9], x[9:11], x[11], x[12]),
+             *([a] * 11), _spec(one_chip, ()),
+             _spec(one_chip, (), jnp.int32))
+
+
+def test_gol_500(one_chip):
+    from dccrg_tpu.ops.flat_amr import pad_extent
+    from dccrg_tpu.ops.gol_kernel import gol_run_fits, make_gol_run
+
+    n = 500
+    nxp, nyp = pad_extent(n, 128), pad_extent(n, 8)
+    assert gol_run_fits(nyp, nxp)
+    run = make_gol_run(n, n, False, False, ny_pad=nyp, nx_pad=nxp)
+    _compile(run, _spec(one_chip, (n, n)), _spec(one_chip, (), jnp.int32))
+
+
+def test_poisson_bicg(one_chip):
+    from dccrg_tpu.ops.poisson_kernel import bicg_fits, make_bicg_solve
+
+    shape = (64, 64, 64)
+    assert bicg_fits(int(np.prod(shape)))
+    solve = make_bicg_solve(shape, True)
+    a = _spec(one_chip, shape)
+    _compile(solve, *([a] * 14), _spec(one_chip, (), jnp.int32),
+             _spec(one_chip, ()), _spec(one_chip, ()))
+
+
+def test_vlasov_blocked(one_chip):
+    from dccrg_tpu.ops.vlasov_kernel import (
+        make_vlasov_step_blocked,
+        pick_vlasov_block,
+    )
+
+    n, B = 32, 8 ** 3
+    block = pick_vlasov_block(n, n, n, B)
+    assert block >= 2
+    step = make_vlasov_step_blocked(n, n, n, B, (n, n, n),
+                                    (True, True, True), block=block)
+    v = _spec(one_chip, (1, 1, 1, B))
+    _compile(step, _spec(one_chip, (n, n, n, B)),
+             _spec(one_chip, (1, n, n, B)), _spec(one_chip, (1, n, n, B)),
+             v, v, v, _spec(one_chip, ()))
+
+
+def test_halo_dma_ring_4_chips(topo):
+    """The async-DMA halo ring (the default TPU halo transport) on a
+    4-chip mesh: each device ships its payload to (d + k) % 4."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from dccrg_tpu.parallel.halo_dma import ring_copy
+    from dccrg_tpu.parallel.mesh import SHARD_AXIS
+
+    D = 4
+    mesh = Mesh(np.array(topo.devices[:D]), (SHARD_AXIS,))
+    spec = P(SHARD_AXIS)
+
+    def body(x):
+        return ring_copy(x[0], 1, D, interpret=False)[None]
+
+    fn = jax.shard_map(body, mesh=mesh, in_specs=spec, out_specs=spec,
+                       check_vma=False)
+    x = jax.ShapeDtypeStruct((D, 1024, 128), jnp.float32,
+                             sharding=NamedSharding(mesh, spec))
+    _compile(fn, x)

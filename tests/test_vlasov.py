@@ -296,36 +296,11 @@ def test_device_count_invariance():
     np.testing.assert_allclose(res[0], res[1], rtol=1e-12, atol=1e-15)
 
 
-def _jax_rounds_bit_identical() -> bool:
-    """jax >= 0.5 Pallas interpret mode reproduces the XLA body bit for
-    bit; the 0.4.x interpreter lowers a few ops through different
-    float32 association and lands within a few ULP instead (ROADMAP jax
-    version pin item)."""
-    import jax
-
-    return tuple(int(p) for p in jax.__version__.split(".")[:2]) >= (0, 5)
-
-
 def _assert_fused_matches(a, b):
-    """Bit-identity on current jax; a tight ULP envelope on old jax.
-
-    The tolerance is deliberately ULP-denominated (4 ULP of the larger
-    magnitude, elementwise) rather than a relative epsilon: the only
-    licensed difference is final-rounding association, and anything
-    beyond a few ULP is a real kernel bug that a rtol would hide on
-    small values."""
+    """The Pallas interpreter reproduces the XLA body bit for bit."""
     a = np.asarray(a, np.float32)
     b = np.asarray(b, np.float32)
-    if _jax_rounds_bit_identical():
-        assert np.array_equal(a, b), np.abs(a - b).max()
-        return
-    ulp = np.spacing(np.maximum(np.abs(a), np.abs(b)))
-    bad = np.abs(a - b) > 4 * ulp
-    assert not bad.any(), (
-        f"{int(bad.sum())} elements beyond 4 ULP; max diff "
-        f"{np.abs(a - b).max()} at magnitude "
-        f"{np.maximum(np.abs(a), np.abs(b))[bad].max()}"
-    )
+    assert np.array_equal(a, b), np.abs(a - b).max()
 
 
 @pytest.mark.parametrize("n_dev,nz", [(1, 8), (2, 8), (1, 16), (2, 32)])
@@ -335,8 +310,7 @@ def _assert_fused_matches(a, b):
 )
 def test_fused_step_matches_xla(n_dev, nz, periodic):
     """The blocked fused kernel (one HBM pass, halo planes re-split in
-    VMEM) matches the XLA three-split body — bit-identical on current
-    jax, within 4 ULP under the 0.4.x Pallas interpreter — including
+    VMEM) matches the XLA three-split body bit for bit — including
     multi-block devices (nzl > block: interior strided halo rows and the
     cross-block zi splice) and open boundaries on every axis."""
     g = make(n=8, nz=nz, n_dev=n_dev, periodic=periodic)

@@ -1217,7 +1217,7 @@ def _fleet_probe() -> list:
     saved_env = {k: os.environ.get(k)
                  for k in ("DCCRG_GATEWAY_QUEUE_MAX",
                            "DCCRG_GATEWAY_STALL_S",
-                           "DCCRG_COMPILE_CACHE_DIR")}
+                           "JAX_COMPILATION_CACHE_DIR")}
     gw = None
     try:
         fr_dir = os.path.join(td, "flightrec")
@@ -1229,7 +1229,7 @@ def _fleet_probe() -> list:
         # race it
         os.environ["DCCRG_GATEWAY_STALL_S"] = "120"
         os.environ["DCCRG_GATEWAY_QUEUE_MAX"] = "4"
-        os.environ["DCCRG_COMPILE_CACHE_DIR"] = os.path.join(td, "cache")
+        os.environ["JAX_COMPILATION_CACHE_DIR"] = os.path.join(td, "cache")
         workers = [WorkerHandle(w, os.path.join(td, w), n_devices=4)
                    for w in ("w0", "w1")]
         for w in workers:

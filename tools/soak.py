@@ -34,7 +34,7 @@ from ``latest_valid()``).  The completed run must converge to a
 fixed-mesh reference bit-identically (GoL exact, advection 1e-11),
 and a fork-a-fresh-process warm-start proof must then resume from the
 lineage with ``epoch.recompiles == 0`` on the held ShapeSignature
-(the persistent compilation cache, ``DCCRG_COMPILE_CACHE_DIR``).
+(the persistent compilation cache, ``JAX_COMPILATION_CACHE_DIR``).
 
 Black box (ISSUE 10): crash and elastic children arm the flight
 recorder (``obs/flightrec.py``) at their workdir — the ring checkpoints
@@ -547,20 +547,12 @@ def one(seed):
         assert m1 <= m0 * (1 + 1e-5), (seed, m0, m1)  # open z only loses
     assert np.isfinite(np.asarray(state['f'])).all(), seed
     # fused blocked kernel (interpret) must be bit-identical to the XLA
-    # three-split body on current jax; the 0.4.x Pallas interpreter
-    # rounds a few ULP differently (see tests/test_vlasov.py), so old
-    # jax gets the same ULP tolerance there
+    # three-split body
     vf = Vlasov(g, nv=4, dtype=np.float32, use_pallas="interpret")
     assert vf._fused_block > 0, seed
     sf = vf.run(s0, 6, dt)
-    a32 = np.asarray(sf['f'], np.float32)
-    b32 = np.asarray(state['f'], np.float32)
-    if tuple(int(p) for p in jax.__version__.split('.')[:2]) >= (0, 5):
-        assert np.array_equal(a32, b32), seed
-    else:
-        ulp = np.spacing(np.maximum(np.abs(a32), np.abs(b32)))
-        assert (np.abs(a32 - b32) <= 4 * ulp).all(), (
-            seed, float(np.abs(a32 - b32).max()))
+    assert np.array_equal(np.asarray(sf['f'], np.float32),
+                          np.asarray(state['f'], np.float32)), seed
     # general/AMR path on a randomly refined grid: every bin's unsplit
     # update must equal the advection general step with that bin's
     # constant velocity (the oracle the path is built to match)
@@ -775,13 +767,7 @@ wd, seed, nd, total, every = (sys.argv[1], int(sys.argv[2]),
                               int(sys.argv[5]))
 import jax
 jax.config.update('jax_platforms', 'cpu')
-try:
-    jax.config.update('jax_num_cpu_devices', nd)
-except AttributeError:   # old jax: pre-init XLA_FLAGS is the only knob
-    import os as _os
-    if 'xla_force_host_platform_device_count' not in _os.environ.get('XLA_FLAGS', ''):
-        _os.environ['XLA_FLAGS'] = (_os.environ.get('XLA_FLAGS', '')
-            + ' --xla_force_host_platform_device_count=%d' % nd).strip()
+jax.config.update('jax_num_cpu_devices', nd)
 jax.config.update('jax_enable_x64', True)
 import os
 import numpy as np
@@ -1136,13 +1122,7 @@ wd, seed, nd, total, every, do_rescale = (
     int(sys.argv[5]), int(sys.argv[6]))
 import jax
 jax.config.update('jax_platforms', 'cpu')
-try:
-    jax.config.update('jax_num_cpu_devices', 8)
-except AttributeError:   # old jax: pre-init XLA_FLAGS is the only knob
-    import os as _os
-    if 'xla_force_host_platform_device_count' not in _os.environ.get('XLA_FLAGS', ''):
-        _os.environ['XLA_FLAGS'] = (_os.environ.get('XLA_FLAGS', '')
-            + ' --xla_force_host_platform_device_count=8').strip()
+jax.config.update('jax_num_cpu_devices', 8)
 jax.config.update('jax_enable_x64', True)
 import os
 import numpy as np
@@ -1348,7 +1328,7 @@ print('ELASTIC_CHILD_DONE', flush=True)
 #: the zero-cold-start proof child: resume the elastic run's advection
 #: lineage on ``nd`` devices, run one deterministic churn cycle, and
 #: report the grid's ShapeSignature + the recompile/warm-compile split.
-#: Run twice with DCCRG_COMPILE_CACHE_DIR shared: the first populates
+#: Run twice with JAX_COMPILATION_CACHE_DIR shared: the first populates
 #: the persistent compilation cache for the signature, the second — a
 #: genuinely fresh process — must record ``epoch.recompiles == 0`` on
 #: the SAME signature (every compile served from disk).
@@ -1357,13 +1337,7 @@ PROOF_CHILD = r"""import sys, json
 wd, nd, out = sys.argv[1], int(sys.argv[2]), sys.argv[3]
 import jax
 jax.config.update('jax_platforms', 'cpu')
-try:
-    jax.config.update('jax_num_cpu_devices', 8)
-except AttributeError:
-    import os as _os
-    if 'xla_force_host_platform_device_count' not in _os.environ.get('XLA_FLAGS', ''):
-        _os.environ['XLA_FLAGS'] = (_os.environ.get('XLA_FLAGS', '')
-            + ' --xla_force_host_platform_device_count=8').strip()
+jax.config.update('jax_num_cpu_devices', 8)
 jax.config.update('jax_enable_x64', True)
 import os
 import numpy as np
@@ -1430,7 +1404,7 @@ def run_elastic(lo: int, hi: int, stream_dir: str | None = None,
     3. the completed run's final states must match the reference —
        GoL exactly, advection to the 1e-11 cross-layout tolerance;
     4. the warm-start proof: two fresh processes resume the final
-       lineage under a shared ``DCCRG_COMPILE_CACHE_DIR`` and run one
+       lineage under a shared ``JAX_COMPILATION_CACHE_DIR`` and run one
        churn cycle; the second must land on the first's ShapeSignature
        with ``epoch.recompiles == 0`` (every compile a persistent-cache
        hit).
@@ -1514,7 +1488,7 @@ def run_elastic(lo: int, hi: int, stream_dir: str | None = None,
             p, log = launch(
                 ELASTIC_CHILD,
                 [ref, seed, nd_ref, total_steps, every, 0],
-                {"DCCRG_COMPILE_CACHE_DIR": cache_dir},
+                {"JAX_COMPILATION_CACHE_DIR": cache_dir},
             )
             rc = p.wait()
             log.close()
@@ -1534,7 +1508,7 @@ def run_elastic(lo: int, hi: int, stream_dir: str | None = None,
                 hb = os.path.join(wd, f"heartbeat_{attempt}.jsonl")
                 env_extra = {
                     "DCCRG_ELASTIC_HEARTBEAT": hb,
-                    "DCCRG_COMPILE_CACHE_DIR": cache_dir,
+                    "JAX_COMPILATION_CACHE_DIR": cache_dir,
                 }
                 fault = "none"
                 if attempt == 0:
@@ -1608,7 +1582,7 @@ def run_elastic(lo: int, hi: int, stream_dir: str | None = None,
                 out = os.path.join(wd, f"proof_{i}.json")
                 p, log = launch(
                     PROOF_CHILD, [wd, nd, out],
-                    {"DCCRG_COMPILE_CACHE_DIR": cache_dir},
+                    {"JAX_COMPILATION_CACHE_DIR": cache_dir},
                     log_name=f"proof_{i}.log",
                 )
                 prc = p.wait()
@@ -1832,13 +1806,13 @@ def _fleet_admission_ab(record, n_devices: int = 4) -> bool:
     dl_deadline, burst_deadline = 5.0, 2.0
     keys = ("DCCRG_GATEWAY_ADMISSION", "DCCRG_GATEWAY_PARK_EVERY",
             "DCCRG_GATEWAY_STALL_S", "DCCRG_GATEWAY_QUEUE_MAX",
-            "DCCRG_SLO_QUEUE_S", "DCCRG_COMPILE_CACHE_DIR")
+            "DCCRG_SLO_QUEUE_S", "JAX_COMPILATION_CACHE_DIR")
     saved = {k: os.environ.get(k) for k in keys}
     os.environ["DCCRG_GATEWAY_PARK_EVERY"] = str(chunk)
     os.environ["DCCRG_GATEWAY_STALL_S"] = "600"
     os.environ["DCCRG_GATEWAY_QUEUE_MAX"] = "64"
     os.environ.pop("DCCRG_SLO_QUEUE_S", None)
-    os.environ["DCCRG_COMPILE_CACHE_DIR"] = os.path.join(tmp, "cache")
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = os.path.join(tmp, "cache")
     metrics.enabled = True
 
     def tenant_count(name, tenant):
@@ -2022,7 +1996,7 @@ def run_fleet(lo: int, hi: int, stream_dir: str | None = None,
             with open(specs_path, "w") as f:
                 json.dump(specs, f)
             env = {
-                "DCCRG_COMPILE_CACHE_DIR": os.path.join(tmp, "cache"),
+                "JAX_COMPILATION_CACHE_DIR": os.path.join(tmp, "cache"),
                 "XLA_FLAGS":
                     f"--xla_force_host_platform_device_count={n_devices}",
                 "JAX_PLATFORMS": "cpu",
@@ -2264,24 +2238,8 @@ except Exception as _e:  # telemetry must never break the fuzz
 """
 
 
-#: every body pins an 8-device virtual CPU mesh via the new-jax config
-#: knob; old jax (0.4.x) lacks it — swap in the XLA_FLAGS spelling
-#: before the backend initializes (the utils/compat.py bridge, applied
-#: at the driver so the bodies stay on the current-jax vocabulary)
-_NUM_DEVICES_LINE = "jax.config.update('jax_num_cpu_devices', 8)\n"
-_NUM_DEVICES_COMPAT = """\
-try:
-    jax.config.update('jax_num_cpu_devices', 8)
-except AttributeError:   # old jax: pre-init XLA_FLAGS is the only knob
-    import os as _os
-    if 'xla_force_host_platform_device_count' not in _os.environ.get('XLA_FLAGS', ''):
-        _os.environ['XLA_FLAGS'] = (_os.environ.get('XLA_FLAGS', '')
-            + ' --xla_force_host_platform_device_count=8').strip()
-"""
-
-
 def run(name: str, lo: int, hi: int, stream_dir: str | None = None) -> bool:
-    code = BODIES[name].replace(_NUM_DEVICES_LINE, _NUM_DEVICES_COMPAT)
+    code = BODIES[name]
     if stream_dir:
         import os
 

@@ -2,17 +2,14 @@
 """Perf-regression gate: diff two rounds' telemetry phase breakdowns.
 
 Compares the CURRENT round's phase timings (``telemetry.json``, a
-``BENCH_DETAIL.json`` record, or a streaming JSONL snapshot — the last
-line wins) against a BASELINE of the same shapes and fails (exit 1) when
+bench-style record, or a streaming JSONL snapshot — the last line
+wins) against a BASELINE of the same shapes and fails (exit 1) when
 any gated phase's mean time regresses by more than ``--threshold``
 (fractional: 0.35 = +35%).  Phases named via ``--allow`` are reported
 but never fail the gate (the allowlist knob for intentional changes).
 
-Baseline discovery (``--baseline`` omitted): first of
-``tools/telemetry_prev.json`` (the previous round's probe, archived by
-``bench.py`` before it overwrites ``telemetry.json``), then
-``BENCH_DETAIL.json``'s embedded phase table.  ``bench.py`` runs this
-gate per round and attaches the verdict to the bench record; CI can run
+Baseline discovery (``--baseline`` omitted): ``tools/telemetry_prev.json``
+(the previous round's ``telemetry.json``).  CI runs
 it standalone:
 
     python tools/telemetry_diff.py                      # auto-discover
@@ -37,7 +34,6 @@ gate catches exactly that.  ``--no-history`` disables both.
 from __future__ import annotations
 
 import argparse
-import glob
 import json
 import os
 import pathlib
@@ -490,8 +486,7 @@ def load_phases(path: str) -> dict:
     telemetry-bearing shapes this repo produces:
 
     * ``telemetry.json`` — top-level ``phases``;
-    * ``BENCH_DETAIL.json`` / ``BENCH_r*.json`` records —
-      ``detail.telemetry.phases``;
+    * a bench-style record — ``detail.telemetry.phases``;
     * a streaming ``*.jsonl`` — the LAST complete line's ``phases``
       (cumulative, so the last snapshot is the round's final state).
     """
@@ -523,24 +518,10 @@ def load_phases(path: str) -> dict:
 
 
 def discover_baseline() -> str | None:
-    """The newest prior-round phase source available in the repo."""
+    """The prior round's phase source in the repo
+    (``tools/telemetry_prev.json``), or None when there is none."""
     prev = ROOT / "tools" / "telemetry_prev.json"
-    if prev.exists():
-        return str(prev)
-    detail = ROOT / "BENCH_DETAIL.json"
-    if detail.exists():
-        try:
-            load_phases(str(detail))
-            return str(detail)
-        except (ValueError, json.JSONDecodeError):
-            pass
-    for cand in sorted(glob.glob(str(ROOT / "BENCH_r*.json")), reverse=True):
-        try:
-            load_phases(cand)
-            return cand
-        except (ValueError, json.JSONDecodeError):
-            continue
-    return None
+    return str(prev) if prev.exists() else None
 
 
 def compare(current: dict, baseline: dict, threshold: float = 0.35,
@@ -708,7 +689,8 @@ def main(argv=None) -> int:
     baseline_path = args.baseline or discover_baseline()
     if baseline_path is None:
         print("telemetry_diff: no baseline round found — PASS (vacuous); "
-              "run bench.py once to establish one", file=sys.stderr)
+              "copy a round's telemetry.json to tools/telemetry_prev.json "
+              "to establish one", file=sys.stderr)
         return 0
     try:
         current = load_phases(args.current)
