@@ -1033,7 +1033,7 @@ def bench_cost(length: int = 4, steps: int = 16):
 
 
 def halo_overlap_summary(steps: int = 20, length: int = 8, reps: int = 3,
-                         seed: int = 0, profile: bool = True) -> dict:
+                         seed: int = 0) -> dict:
     """Eager vs host-split vs fused split-phase stepping per model
     (gol / advection / vlasov) on the current device mesh (ISSUE 7).
 
@@ -1047,15 +1047,10 @@ def halo_overlap_summary(steps: int = 20, length: int = 8, reps: int = 3,
       an upper bound showing the dispatch overhead the fused form
       removes;
     * ``fused`` — the model's ``overlap=True`` step: start → interior →
-      finish → boundary inside ONE compiled program.
-
-    ``overlap_fraction`` per model is MEASURED (not inferred): a
-    profiled fused round merged against the device timeline
-    (``obs.merge_profile``), None when the backend leaves no execution
-    lines."""
+      finish → boundary inside ONE compiled program."""
     import jax
 
-    from dccrg_tpu import CartesianGeometry, Grid, make_mesh, obs
+    from dccrg_tpu import CartesianGeometry, Grid, make_mesh
     from dccrg_tpu.models import Advection, GameOfLife, Vlasov
 
     g = (
@@ -1092,38 +1087,6 @@ def halo_overlap_summary(steps: int = 20, length: int = 8, reps: int = 3,
             jax.block_until_ready(s)
             times.append((time.perf_counter() - t0) / steps)
         return float(np.median(times))
-
-    def measured_overlap(step, state, model):
-        """Profiled fused round -> overlap.fraction{model=...}."""
-        import tempfile
-
-        obs.enable()
-        obs.enable_timeline()
-
-        def stamped(s):
-            t0 = time.perf_counter()
-            out = step(s)
-            obs.metrics.phase_add("halo.start", time.perf_counter() - t0)
-            t0 = time.perf_counter()
-            jax.block_until_ready(out)
-            obs.metrics.phase_add("halo.exchange",
-                                  time.perf_counter() - t0)
-            return out
-
-        try:
-            with tempfile.TemporaryDirectory() as td:
-                with obs.profile_trace(td):
-                    s = state
-                    for _ in range(4):
-                        s = stamped(s)
-                _merged, summary = obs.merge_profile(
-                    td, extra_labels={"model": model}
-                )
-            if not summary["device_evidence"]:
-                return None
-            return summary["overlap"]["halo"]["fraction"]
-        except Exception:  # noqa: BLE001 — measurement, never the bench
-            return None
 
     out: dict = {"n_devices": g.n_devices, "steps": steps,
                  "n_cells": int(len(cells)),
@@ -1175,10 +1138,6 @@ def halo_overlap_summary(steps: int = 20, length: int = 8, reps: int = 3,
         rec["fused_vs_eager"] = round(
             rec["eager_step_s"] / max(rec["fused_step_s"], 1e-12), 3
         )
-        if profile:
-            rec["overlap_fraction"] = measured_overlap(
-                step_f, state_f, model
-            )
         out["models"][model] = rec
     return out
 

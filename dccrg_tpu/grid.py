@@ -135,7 +135,18 @@ class Grid:
         replaces the reference's level-by-level refinement replay,
         ``dccrg.hpp:3647-3716``).  The set is validated: exact domain
         tiling and the 2:1 balance invariant both raise on a corrupt
-        file."""
+        file.
+
+        Timed as the ``grid.initialize`` phase, holding
+        ``grid.partition`` and the ``epoch.build`` of the first epoch."""
+        from .obs import metrics
+
+        with metrics.phase("grid.initialize"):
+            return self._initialize(mesh, n_devices, leaf_set)
+
+    def _initialize(self, mesh, n_devices, leaf_set) -> "Grid":
+        from .obs import metrics
+
         self._assert_uninitialized()
         self.mesh = mesh if mesh is not None else make_mesh(n_devices=n_devices)
         self.n_devices = self.mesh.devices.size
@@ -193,12 +204,13 @@ class Grid:
             settings + self.geometry.params_to_file_bytes()
             + (cells.tobytes() if leaf_set is not None else b""),
         )
-        if self._lb_method in ("HSFC", "SFC", "HILBERT"):
-            owner = hilbert_partition(self.mapping, cells, self.n_devices)
-        elif self._lb_method == "MORTON":
-            owner = morton_partition(self.mapping, cells, self.n_devices)
-        else:
-            owner = block_partition(cells, self.n_devices)
+        with metrics.phase("grid.partition"):
+            if self._lb_method in ("HSFC", "SFC", "HILBERT"):
+                owner = hilbert_partition(self.mapping, cells, self.n_devices)
+            elif self._lb_method == "MORTON":
+                owner = morton_partition(self.mapping, cells, self.n_devices)
+            else:
+                owner = block_partition(cells, self.n_devices)
         self.leaves = LeafSet(cells=cells, owner=owner.astype(np.int32))
         self.initialized = True
         if leaf_set is not None:

@@ -24,7 +24,10 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
+from ..obs.registry import metrics
+from ..parallel.exec_cache import traced_jit
 from ..parallel.mesh import put_table, shard_spec
 from ..parallel.stencil import StencilTables, gather_neighbors, ordered_sum
 from ..utils.collectives import fetch
@@ -215,32 +218,47 @@ class Advection:
         self.dense = (grid.epoch.dense if allow_dense and not overlap
                       else None)
         self.boxed = None
+        #: (epoch, dtype, shape) -> halo bytes per step (``_record_run``)
+        self._bps_key, self._bps = None, 0
+        with metrics.phase("advection.init"):
+            self._init_paths(allow_boxed)
+
+    def _init_paths(self, allow_boxed):
+        """Build every candidate whole-run path, each under its own
+        ``advection.init.<part>`` span (dense; tables, step, boxed, flat),
+        so set-up time spent on a path that does not engage shows."""
+        grid, hood_id = self.grid, self.hood_id
         if self.dense is not None:
-            self._init_dense()
+            with metrics.phase("advection.init.dense"):
+                self._init_dense()
             return
-        self.tables = StencilTables(grid, hood_id, with_geometry=True)
-        self._exchange = grid.halo(hood_id)
-        # halo schedule tables ride into the cached kernels as runtime
-        # arguments (parallel/exec_cache.py): an epoch rebuild with the
-        # same shape signature reuses every compiled step
-        self._rings = (tuple(self._exchange.ring_send)
-                       + tuple(self._exchange.ring_recv))
-        self._build_face_tables()
-        self._step = self._build_step()
-        self._max_dt = self._build_max_dt()
-        self._max_diff = self._build_max_diff()
-        if self.overlap:
-            self._step = self._build_split_step()
+        with metrics.phase("advection.init.tables"):
+            self.tables = StencilTables(grid, hood_id, with_geometry=True)
+            self._exchange = grid.halo(hood_id)
+            # halo schedule tables ride into the cached kernels as runtime
+            # arguments (parallel/exec_cache.py): an epoch rebuild with the
+            # same shape signature reuses every compiled step
+            self._rings = (tuple(self._exchange.ring_send)
+                           + tuple(self._exchange.ring_recv))
+            self._build_face_tables()
+        with metrics.phase("advection.init.step"):
+            self._step = self._build_step()
+            self._max_dt = self._build_max_dt()
+            self._max_diff = self._build_max_diff()
+            if self.overlap:
+                self._step = self._build_split_step()
         if allow_boxed and not self.overlap:
             from ..parallel.boxed import build_boxed
 
-            self.boxed = build_boxed(grid, hood_id)
-            if self.boxed is not None:
-                self._boxed_run = self._build_boxed_run(self.boxed)
+            with metrics.phase("advection.init.boxed"):
+                self.boxed = build_boxed(grid, hood_id)
+                if self.boxed is not None:
+                    self._boxed_run = self._build_boxed_run(self.boxed)
             # the flat two-level scheme qualifies independently of the
             # boxed layout (e.g. wrap-adjacent refinement is gated out of
             # slab-mode boxed but handled exactly by the flat rolls)
-            self._flat_run = self._build_flat_run()
+            with metrics.phase("advection.init.flat"):
+                self._flat_run = self._build_flat_run()
             # cost-based choice when both fast paths qualify: prefer
             # boxed only when the flat form's voxel inflation exceeds
             # its per-voxel rate advantage over the boxed passes (one
@@ -278,8 +296,6 @@ class Advection:
                 str(np.dtype(self.dtype)))
 
     def _build_step(self):
-        from ..parallel.exec_cache import traced_jit
-
         ex_body = self._exchange.raw_body
 
         def build():
@@ -353,7 +369,6 @@ class Advection:
         ``face_dir == 0`` in both forms before the ordered reduction."""
         from jax.sharding import PartitionSpec as P
 
-        from ..parallel.exec_cache import traced_jit
         from ..parallel.halo import HaloExchange
         from ..parallel.mesh import SHARD_AXIS
         from jax import shard_map
@@ -457,8 +472,6 @@ class Advection:
         return lambda state, dt: fn(*args, state, dt)
 
     def _build_max_dt(self):
-        from ..parallel.exec_cache import traced_jit
-
         def build():
             def max_dt(t, state):
                 # CFL: min over local cells of length/|v| per dim, global
@@ -486,8 +499,6 @@ class Advection:
         return lambda state: fn(t, state)
 
     def _build_max_diff(self):
-        from ..parallel.exec_cache import traced_jit
-
         ex_body = self._exchange.raw_body
 
         def build():
@@ -546,7 +557,8 @@ class Advection:
         # VMEM-resident Pallas kernel when a single device, f32, and the
         # budget allow, else the XLA pyramid form (any device count) —
         # VERDICT-r4's extension of the fast path past levels {0, 1}
-        tml = build_flat_ml_tables(self.grid)
+        with metrics.phase("advection.init.flat.ml_tables"):
+            tml = build_flat_ml_tables(self.grid)
         if tml is not None:
             from ..ops.flat_amr import flat_ml_kernel_fits
 
@@ -570,7 +582,8 @@ class Advection:
             return make_flat_ml_run(self.grid, tml, dtype=jdt)
 
         # multi-device: z-slab-sharded XLA form (no Pallas requirement)
-        ts = build_flat_amr_sharded(self.grid)
+        with metrics.phase("advection.init.flat.sharded_tables"):
+            ts = build_flat_amr_sharded(self.grid)
         if ts is not None:
             jdt = (
                 jnp.float32
@@ -586,7 +599,8 @@ class Advection:
             return None
         if not (interpret or pallas_available(self.dtype)):
             return None
-        t = build_flat_amr_tables(self.grid)
+        with metrics.phase("advection.init.flat.amr_tables"):
+            t = build_flat_amr_tables(self.grid)
         if t is None:
             return None
         nz1, ny1, nx1 = t["shape"]
@@ -616,7 +630,6 @@ class Advection:
             "wb_valid": jnp.asarray(t["wb_valid"]),
         }
 
-        @jax.jit
         def run_fn(tabs, state, steps, dt):
             def field(name):
                 return state[name][0][tabs["rows"]].reshape(nz1, ny1, nx1)
@@ -641,6 +654,7 @@ class Advection:
                 "flux": jnp.zeros_like(state["flux"]),
             }
 
+        run_fn = traced_jit("advection.flat_run", run_fn)
         return lambda state, steps, dt: run_fn(tabs, state, steps, dt)
 
     def _build_ml_pallas_run(self, t, interpret):
@@ -664,7 +678,6 @@ class Advection:
         wb_rows = jnp.asarray(t["wb_rows"][0])
         wb_valid = jnp.asarray(t["wb_valid"][0])
 
-        @jax.jit
         def run_fn(state, steps, dt):
             def field(name):
                 return (state[name][0][rows]
@@ -688,7 +701,7 @@ class Advection:
                 "flux": jnp.zeros_like(state["flux"]),
             }
 
-        return run_fn
+        return traced_jit("advection.ml_pallas_run", run_fn)
 
     def _build_boxed_run(self, layout):
         """Multi-step run over the boxed per-level AMR layout — one unified
@@ -742,7 +755,6 @@ class Advection:
         from jax.sharding import PartitionSpec as P
 
         from ..parallel.dense import HaloExtend
-        from ..parallel.exec_cache import traced_jit
         from ..parallel.mesh import SHARD_AXIS, shard_spec
 
         info = self.dense
@@ -923,7 +935,6 @@ class Advection:
             # are plain pallas-kernel operands either way, so lifting
             # them through the jit boundary cannot perturb the kernel —
             # and only the plain wrapper closes over the device copies
-            @jax.jit
             def fused_run_fn(masks, state, steps, dt):
                 fmx, fmy, fmzu, fmzd = masks
                 new_rho = fused(
@@ -931,6 +942,8 @@ class Advection:
                     state["vz"][0], fmx, fmy, fmzu, fmzd, dt, steps,
                 )
                 return {**state, "density": new_rho[None]}
+
+            fused_run_fn = traced_jit("advection.fused_run", fused_run_fn)
 
             def fused_run(state, steps, dt):
                 return fused_run_fn(
@@ -965,7 +978,6 @@ class Advection:
                 check_vma=False,
             )
 
-            @jax.jit
             def dense_run_fn(zf_up, zf_dn, state, steps, dt):
                 (new_rho,) = run_sm(
                     zf_up, zf_dn,
@@ -973,6 +985,8 @@ class Advection:
                     jnp.asarray(dt, dtype), jnp.asarray(steps, jnp.int32),
                 )
                 return {**state, "density": new_rho}
+
+            dense_run_fn = traced_jit("advection.dense_run", dense_run_fn)
 
             def dense_run(state, steps, dt):
                 return dense_run_fn(zf_up_dev, zf_dn_dev, state, steps, dt)
@@ -1071,7 +1085,12 @@ class Advection:
 
     def initialize_state(self):
         """Rotating-hump initial condition (initialize.hpp:36-80): solid-body
-        rotation about the domain center, cosine density hump."""
+        rotation about the domain center, cosine density hump.  Timed as
+        ``advection.init_state``."""
+        with metrics.phase("advection.init_state"):
+            return self._initialize_state()
+
+    def _initialize_state(self):
         grid = self.grid
         cells = grid.get_cells()
         centers = grid.geometry.get_center(cells)
@@ -1146,7 +1165,7 @@ class Advection:
         velocities are valid forever (``initialize_state`` ends with a
         full-state exchange and the fields are static), so only density
         staleness meters the budget."""
-        from ..parallel.exec_cache import WideStepSpec, traced_jit
+        from ..parallel.exec_cache import WideStepSpec
         from ..parallel.mesh import put_table
         from ..parallel.wide_halo import get_wide_plan, wide_enabled
 
@@ -1286,165 +1305,182 @@ class Advection:
     def _record_run(self, path: str, steps, state) -> None:
         """Post-run reconciliation (obs.fused): the whole-run paths keep
         their ghost traffic inside jit, so the host seam sees nothing —
-        record ``steps x schedule bytes`` once per dispatch instead."""
+        record ``steps x schedule bytes`` once per dispatch instead.  The
+        bytes per step are computed once per halo schedule (one per
+        epoch) and density dtype and shape, not on every call."""
         from ..obs import fused
 
         if not self.grid.telemetry.enabled:
             return
+        rho = state["density"]
         try:
-            bps = self.grid.halo(None).bytes_moved(
-                {"density": state["density"]}
-            )
+            ex = self.grid.halo(None)
+            key = (ex, rho.dtype, rho.shape)
+            if key != self._bps_key:
+                self._bps = ex.bytes_moved({"density": rho})
+                self._bps_key = key
         except Exception:  # noqa: BLE001 — telemetry must never raise
-            bps = 0
-        fused.record_run("advection", path, steps, bps)
+            self._bps_key, self._bps = None, 0
+        fused.record_run("advection", path, steps, self._bps)
 
     def run(self, state, steps: int, dt):
         """Advance ``steps`` timesteps in a single device-side loop
         (``lax.fori_loop``) — one dispatch for the whole run, the
         compiler-friendly form of the reference's while-loop driver
         (2d.cpp:321+).  Use this for tight stepping; ``step`` for loops
-        interleaved with host logic (AMR, load balancing, IO)."""
+        interleaved with host logic (AMR, load balancing, IO).
+
+        Each call is marked on the profiler's host plane as
+        ``advection.run``, holding ``advection.run.record`` (the
+        ``fused.*`` counters), ``advection.run.args`` (steps and dt made
+        device scalars) and ``advection.run.launch`` (the jitted
+        whole-run call, ``jit_advection_<path>_run`` on the device)."""
+        with TraceAnnotation("advection.run"):
+            path, launch = self._launcher()
+            with TraceAnnotation("advection.run.record"):
+                self._record_run(path, steps, state)
+            with TraceAnnotation("advection.run.args"):
+                # the gather-path loops take steps as a Python int (jit's
+                # own scalar path); the whole-run kernels a device scalar
+                n = (steps if path in ("general", "split")
+                     else jnp.asarray(steps, jnp.int32))
+                d = jnp.asarray(dt, self.dtype)
+            with TraceAnnotation("advection.run.launch"):
+                return launch(state, n, d)
+
+    def _launcher(self):
+        """``(path, fn(state, steps, dt))`` of the whole-run dispatch."""
         if getattr(self, "_fused_run", None) is not None:
-            self._record_run("fused", steps, state)
-            return self._fused_run(
-                state, jnp.asarray(steps, jnp.int32), jnp.asarray(dt, self.dtype)
-            )
+            return "fused", self._fused_run
         if (
             getattr(self, "_prefer_boxed", False)
             and getattr(self, "_boxed_run", None) is not None
         ):
-            self._record_run("boxed", steps, state)
-            return self._boxed_run(
-                state, jnp.asarray(steps, jnp.int32), jnp.asarray(dt, self.dtype)
-            )
+            return "boxed", self._boxed_run
         if getattr(self, "_flat_run", None) is not None:
-            # the flat kernel is an optimization; if the TPU compiler
-            # rejects it (op support varies by generation), fall back to
-            # the boxed/general dispatch permanently for this instance —
-            # but only after the fallback succeeds on the same inputs
-            # (utils/fallback.py's policy), so a caller error propagates
-            self._record_run("flat", steps, state)
-            return fallback_call(
-                "flat AMR kernel",
-                lambda: self._flat_run(
-                    state, jnp.asarray(steps, jnp.int32),
-                    jnp.asarray(dt, self.dtype),
-                ),
-                lambda: self._run_general(state, steps, dt),
-                self._disable_flat,
-            )
-        return self._run_general(state, steps, dt)
+            return "flat", self._flat_or_general
+        return self._general_launcher()
+
+    def _flat_or_general(self, state, steps, dt):
+        # the flat kernel is an optimization; if the TPU compiler
+        # rejects it (op support varies by generation), fall back to
+        # the boxed/general dispatch permanently for this instance —
+        # but only after the fallback succeeds on the same inputs
+        # (utils/fallback.py's policy), so a caller error propagates
+        return fallback_call(
+            "flat AMR kernel",
+            lambda: self._flat_run(state, steps, dt),
+            lambda: self._run_general(state, steps, dt),
+            self._disable_flat,
+        )
 
     def _disable_flat(self):
         self._flat_run = None
 
     def _run_general(self, state, steps, dt):
-        """The non-flat whole-run dispatch: boxed, dense, or the general
-        gather-path fori_loop."""
+        """The non-flat whole-run dispatch, recorded under its own path
+        (the flat kernel's fallback)."""
+        path, fn = self._general_launcher()
+        self._record_run(path, steps, state)
+        return fn(state, steps, dt)
+
+    def _general_launcher(self):
+        """The non-flat dispatch: boxed, dense, or the general
+        gather-path fori_loop (built on first use)."""
         if getattr(self, "_boxed_run", None) is not None:
-            self._record_run("boxed", steps, state)
-            return self._boxed_run(
-                state, jnp.asarray(steps, jnp.int32), jnp.asarray(dt, self.dtype)
-            )
+            return "boxed", self._boxed_run
         if getattr(self, "_dense_run", None) is not None:
-            self._record_run("dense", steps, state)
-            return self._dense_run(
-                state, jnp.asarray(steps, jnp.int32), jnp.asarray(dt, self.dtype)
-            )
+            return "dense", self._dense_run
         if not hasattr(self, "_run"):
-            from ..parallel.exec_cache import (
-                record_run_donation,
-                run_donate_enabled,
+            self._run = self._build_general_run()
+        return ("split" if self.overlap else "general"), self._run
+
+    def _build_general_run(self):
+        """The gather-path whole run: ``steps`` of the (split-phase or
+        blocking) step in one ``fori_loop``."""
+        from ..parallel.exec_cache import (
+            record_run_donation,
+            run_donate_enabled,
+        )
+
+        donate = run_donate_enabled()
+
+        def probe_wrap(dispatch):
+            """Measure donation effectiveness per dispatch via the
+            ``is_deleted`` probe, like the ensemble's stacked-state
+            donation path."""
+            if not donate:
+                return dispatch
+
+            def wrapped(state, steps, dt):
+                probe = state["density"]
+                out = dispatch(state, steps, dt)
+                record_run_donation("advection", probe)
+                return out
+
+            return wrapped
+
+        if getattr(self, "_split_fn", None) is not None:
+            inner = self._split_fn
+
+            def build():
+                def run_fn(rings, ti, to, local, state, steps, dt):
+                    return jax.lax.fori_loop(
+                        0, steps,
+                        lambda i, st: inner(rings, ti, to, local, st, dt),
+                        state,
+                    )
+
+                # state is positional arg 4; donation joins the
+                # cache key so flipping DCCRG_RUN_DONATE re-keys
+                return traced_jit(
+                    "advection.split_run", run_fn,
+                    donate_argnums=(4,) if donate else (),
+                )
+
+            fn = self.grid.exec_cache.get(
+                self._kernel_key("advection.split_run") + (donate,),
+                build,
+            )
+            args = self._split_args
+            return probe_wrap(lambda state, steps, dt: fn(
+                *args, state, steps, dt
+            ))
+        if hasattr(self, "_step_fn"):
+            inner = self._step_fn
+
+            def build():
+                def run_fn(rings, t, dev, state, steps, dt):
+                    return jax.lax.fori_loop(
+                        0, steps,
+                        lambda i, st: inner(rings, t, dev, st, dt),
+                        state,
+                    )
+
+                # state is positional arg 3
+                return traced_jit(
+                    "advection.general_run", run_fn,
+                    donate_argnums=(3,) if donate else (),
+                )
+
+            fn = self.grid.exec_cache.get(
+                self._kernel_key("advection.general_run") + (donate,),
+                build,
+            )
+            rings, t, dev = self._rings, self.tables.tree(), self._dev
+            return probe_wrap(lambda state, steps, dt: fn(
+                rings, t, dev, state, steps, dt
+            ))
+        # dense XLA-only path: the step came from the cached dense
+        # bundle (plain (state, dt) signature)
+        inner = self._step
+
+        def run_fn(state, steps, dt):
+            return jax.lax.fori_loop(
+                0, steps, lambda i, st: inner(st, dt), state
             )
 
-            donate = run_donate_enabled()
-
-            def probe_wrap(dispatch):
-                """Measure donation effectiveness per dispatch via the
-                ``is_deleted`` probe, like the ensemble's stacked-state
-                donation path."""
-                if not donate:
-                    return dispatch
-
-                def wrapped(state, steps, dt):
-                    probe = state["density"]
-                    out = dispatch(state, steps, dt)
-                    record_run_donation("advection", probe)
-                    return out
-
-                return wrapped
-
-            if getattr(self, "_split_fn", None) is not None:
-                from ..parallel.exec_cache import traced_jit
-
-                inner = self._split_fn
-
-                def build():
-                    def run_fn(rings, ti, to, local, state, steps, dt):
-                        return jax.lax.fori_loop(
-                            0, steps,
-                            lambda i, st: inner(rings, ti, to, local, st,
-                                                dt),
-                            state,
-                        )
-
-                    # state is positional arg 4; donation joins the
-                    # cache key so flipping DCCRG_RUN_DONATE re-keys
-                    return traced_jit(
-                        "advection.split_run", run_fn,
-                        donate_argnums=(4,) if donate else (),
-                    )
-
-                fn = self.grid.exec_cache.get(
-                    self._kernel_key("advection.split_run") + (donate,),
-                    build,
-                )
-                args = self._split_args
-                self._run = probe_wrap(lambda state, steps, dt: fn(
-                    *args, state, steps, dt
-                ))
-            elif hasattr(self, "_step_fn"):
-                from ..parallel.exec_cache import traced_jit
-
-                inner = self._step_fn
-
-                def build():
-                    def run_fn(rings, t, dev, state, steps, dt):
-                        return jax.lax.fori_loop(
-                            0, steps,
-                            lambda i, st: inner(rings, t, dev, st, dt),
-                            state,
-                        )
-
-                    # state is positional arg 3
-                    return traced_jit(
-                        "advection.run", run_fn,
-                        donate_argnums=(3,) if donate else (),
-                    )
-
-                fn = self.grid.exec_cache.get(
-                    self._kernel_key("advection.run") + (donate,), build
-                )
-                rings, t, dev = self._rings, self.tables.tree(), self._dev
-                self._run = probe_wrap(lambda state, steps, dt: fn(
-                    rings, t, dev, state, steps, dt
-                ))
-            else:
-                # dense XLA-only path: the step came from the cached
-                # dense bundle (plain (state, dt) signature)
-                inner = self._step
-
-                @jax.jit
-                def run_fn(state, steps, dt):
-                    return jax.lax.fori_loop(
-                        0, steps, lambda i, st: inner(st, dt), state
-                    )
-
-                self._run = run_fn
-        self._record_run("split" if self.overlap else "general",
-                         steps, state)
-        return self._run(state, steps, jnp.asarray(dt, self.dtype))
+        return traced_jit("advection.general_run", run_fn)
 
     def max_time_step(self, state) -> float:
         return float(self._max_dt(state))

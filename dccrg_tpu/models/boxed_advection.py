@@ -58,6 +58,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from ..parallel.exec_cache import traced_jit
 from ..parallel.mesh import SHARD_AXIS, put_table, shard_spec
 
 __all__ = ["build_boxed_run"]
@@ -540,7 +541,6 @@ def build_boxed_run(adv, layout):
 
     # the boxed tables ride into the jit as a RUNTIME argument pytree
     # (not closed over): same-shape boxings share one executable
-    @jax.jit
     def run_impl(statics_arg, state, steps, dt):
         dt = jnp.asarray(dt, dtype)
         steps = jnp.asarray(steps, jnp.int32)
@@ -553,6 +553,8 @@ def build_boxed_run(adv, layout):
             "density": density,
             "flux": jnp.zeros_like(state["flux"]),
         }
+
+    run_impl = traced_jit("advection.boxed_run", run_impl)
 
     def run(state, steps, dt):
         return run_impl(statics_dev, state, steps, dt)

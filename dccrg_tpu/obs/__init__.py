@@ -16,29 +16,27 @@ seam instead:
   outside jit boundaries, so jitted programs never carry per-call dict
   churn;
 * a JSON exporter (:func:`export_json` -> ``telemetry.json``, consumed
-  by ``bench.py``) and an opt-in ``jax.profiler`` trace context
-  (:func:`profile_trace`) that annotates each instrumented phase with a
-  named ``TraceAnnotation`` span for TensorBoard/xprof;
+  by ``bench.py``) and a ``jax.profiler`` capture context
+  (:func:`profile_trace`);
+* program spans on the profiler's own clock: every phase is also a
+  ``jax.profiler.TraceAnnotation`` of its name (``<layer>.<what>``:
+  ``grid.*``, ``epoch.*``, ``amr.*``, ``advection.*``, ``halo.*``), and
+  ``Advection.run`` marks its per-call parts the same way, so any
+  capture (``profile_trace``, or a harness's own ``start_trace``) holds
+  them on its host plane beside the device ops, with no clock alignment
+  (``benchmark/`` reduces such captures to its per-layer metrics);
 * a streaming exporter (:func:`stream_to`) appending incremental JSONL
   snapshots on a period, so a hung or killed run leaves phase evidence
   behind (``tools/soak.py``);
 * a structured event timeline (``obs.timeline``) recording every
   completed phase as a begin/end span, exportable as Chrome trace-event
-  JSON (:func:`export_chrome_trace`, view in perfetto);
+  JSON (:func:`export_chrome_trace`, view in perfetto), with the
+  cross-process fleet merge (:func:`merge_chrome_traces`) and its
+  validator (:func:`validate_merged_trace`);
 * per-device memory gauges (:func:`sample_hbm` ->
   ``hbm.bytes_in_use{device=d}``), sampled at epoch rebuilds and bench
   checkpoints, and post-run reconciliation counters for the fused
   whole-run kernels that bypass the host halo seam (``obs.fused``);
-* the device timeline (``obs.xplane`` + ``obs.merge``): XSpace protos
-  from ``profile_trace`` captures decoded without tensorflow, clock-
-  aligned against the host timeline via sync beacons, and merged into
-  one Chrome trace (host phases as parent track, one pid per device,
-  async ``b``/``e`` collectives) — with measured gauges on top:
-  ``overlap.fraction{phase=halo}``, ``device.busy_fraction{device=d}``
-  and per-kernel ``device.kernel_time_us`` attribution keyed by the
-  same labels ``epoch.recompiles`` counts.  ``DCCRG_XPLANE=0`` opts
-  out; deviceless captures degrade to a documented no-op.
-
 * the request-level SLO plane (ISSUE 10): ``obs.slo`` — post-hoc
   quantiles (``p50/p95/p99``) and cross-process merges over the
   exported log-bucketed histograms (the serving front-end records
@@ -89,7 +87,7 @@ can be switched off independently (``DCCRG_TIMELINE=0``).
 """
 from .registry import MetricsRegistry, metrics, disable, enable
 from .export import export_json
-from .trace import profile_trace, trace_span
+from .trace import profile_trace
 from .stream import TelemetryStream, stream_to, maybe_flush
 from .events import (
     EventTimeline,
@@ -98,6 +96,8 @@ from .events import (
     export_chrome_trace,
     enable_timeline,
     disable_timeline,
+    merge_chrome_traces,
+    validate_merged_trace,
 )
 from .hbm import sample_hbm
 from . import fused
@@ -105,20 +105,10 @@ from . import slo
 from . import live
 from . import alerts
 from . import cost
-from . import xplane
 from .flightrec import (
     FlightRecorder,
     recorder as flight_recorder,
     validate_flightrec,
-)
-from .merge import (
-    ClockAlignment,
-    MergedTrace,
-    build_merged,
-    build_from_capture,
-    merge_profile,
-    merge_chrome_traces,
-    validate_merged_trace,
 )
 
 __all__ = [
@@ -128,7 +118,6 @@ __all__ = [
     "disable",
     "export_json",
     "profile_trace",
-    "trace_span",
     "TelemetryStream",
     "stream_to",
     "maybe_flush",
@@ -144,15 +133,9 @@ __all__ = [
     "live",
     "alerts",
     "cost",
-    "xplane",
     "FlightRecorder",
     "flight_recorder",
     "validate_flightrec",
-    "ClockAlignment",
-    "MergedTrace",
-    "build_merged",
-    "build_from_capture",
-    "merge_profile",
     "merge_chrome_traces",
     "validate_merged_trace",
 ]

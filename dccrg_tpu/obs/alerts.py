@@ -309,9 +309,6 @@ def default_rules() -> list:
         AlertRule("halo-exchanges-per-step", "halo.exchanges_per_step",
                   source="gauge", kind="ceiling",
                   threshold=2.0, clear=1.5, for_s=0.0),
-        AlertRule("overlap-fraction", "overlap.fraction",
-                  source="gauge", kind="floor",
-                  threshold=0.10, clear=0.15, for_s=5.0),
     ]
 
 

@@ -8,8 +8,10 @@ timeline keeps the individual spans: every completed ``phase`` /
 migrations, AMR commits, checkpoint I/O) lands here as one
 ``(name, begin, duration, thread)`` record, plus any explicit
 ``events.span(...)`` the caller opens.  Export produces matched ``B``/``E``
-trace-event pairs on a microsecond timebase, viewable alongside the
-``jax.profiler`` traces ``obs.profile_trace`` captures.
+trace-event pairs on a microsecond timebase; :func:`merge_chrome_traces`
+joins the exports of several processes into one fleet trace on their
+shared wall-clock epoch-zero, and :func:`validate_merged_trace` checks
+one.
 
 Bounded: past ``max_events`` new spans are dropped (and counted) so a
 soak run cannot grow host memory without limit — the aggregate registry
@@ -32,6 +34,8 @@ __all__ = [
     "export_chrome_trace",
     "enable_timeline",
     "disable_timeline",
+    "merge_chrome_traces",
+    "validate_merged_trace",
 ]
 
 
@@ -149,8 +153,7 @@ class EventTimeline:
 
     def spans(self) -> list:
         """Snapshot of the recorded spans as plain dicts (``begin`` in
-        the timeline's ``perf_counter`` timebase) — the host half the
-        device-timeline merge (``obs.merge``) consumes."""
+        the timeline's ``perf_counter`` timebase)."""
         with self._lock:
             events = list(self._events)
         return [
@@ -161,10 +164,8 @@ class EventTimeline:
 
     def rebase(self, origin_perf: float, origin_wall: float = 0.0) -> None:
         """Move the timeline origin: spans keep their absolute ``begin``
-        stamps, exports re-zero on the new origin.  Used by synthetic
-        timelines built on a foreign clock (``obs.merge`` reconstructs a
-        host track from a capture's own annotations when the live
-        timeline is gone)."""
+        stamps, exports re-zero on the new origin (a timeline built on
+        a foreign clock, e.g. one replayed from another process)."""
         self._t0_perf = float(origin_perf)
         self._t0_wall = float(origin_wall)
 
@@ -275,8 +276,7 @@ def disable_timeline() -> None:
 def export_chrome_trace(path: str, tl: EventTimeline | None = None) -> dict:
     """Write the timeline as Chrome trace-event JSON to ``path`` (temp
     file + rename, like ``export_json``) and return the trace object.
-    Load in perfetto / ``chrome://tracing`` next to the xplane traces
-    from ``obs.profile_trace``."""
+    Load in perfetto / ``chrome://tracing``."""
     t = tl if tl is not None else timeline
     trace = t.chrome_trace()
     tmp = str(path) + ".tmp"
@@ -284,3 +284,172 @@ def export_chrome_trace(path: str, tl: EventTimeline | None = None) -> dict:
         json.dump(trace, f, default=float)
     os.replace(tmp, str(path))
     return trace
+
+
+# --------------------------------------------------------- fleet merge
+
+
+def merge_chrome_traces(sources: list, out_path: str | None = None) -> dict:
+    """Unify per-process timeline exports into one fleet trace.  Every
+    source (a path or an already-loaded trace dict) must carry
+    ``otherData.origin_unix_s`` — the wall-clock anchor each process's
+    timeline origin recorded; the earliest origin becomes the fleet's
+    shared epoch-zero and every event shifts onto it.  Pids are
+    renumbered per process so soak / multiprocess-battery children
+    cannot collide, with process_name metadata rewritten to say which
+    child each track came from."""
+    loaded = []
+    for src in sources:
+        if isinstance(src, (str, os.PathLike)):
+            with open(src) as f:
+                loaded.append((os.path.basename(str(src)), json.load(f)))
+        else:
+            loaded.append((f"proc{len(loaded)}", src))
+    origins = []
+    for name, tr in loaded:
+        o = (tr.get("otherData") or {}).get("origin_unix_s")
+        if o is None:
+            raise ValueError(
+                f"fleet merge: {name} carries no origin_unix_s anchor"
+            )
+        origins.append(float(o))
+    epoch0 = min(origins) if origins else 0.0
+    events = []
+    pid_map: dict = {}
+    sources_meta = []
+    for i, ((name, tr), origin) in enumerate(zip(loaded, origins)):
+        shift_us = (origin - epoch0) * 1e6
+        sources_meta.append({"source": name, "origin_unix_s": origin,
+                             "shift_us": round(shift_us, 3)})
+        for ev in tr.get("traceEvents", []):
+            ev = dict(ev)
+            key = (i, ev.get("pid"))
+            if key not in pid_map:
+                pid_map[key] = len(pid_map) + 1
+            ev["pid"] = pid_map[key]
+            if "ts" in ev:
+                ev["ts"] = round(ev["ts"] + shift_us, 3)
+            if ev.get("ph") == "M" and ev.get("name") == "process_name":
+                base = (ev.get("args") or {}).get("name", "")
+                ev["args"] = {"name": f"{name}: {base}" if base else name}
+            events.append(ev)
+    fleet = {
+        "traceEvents": events,
+        "displayTimeUnit": "ms",
+        "otherData": {
+            "producer": "dccrg_tpu.obs.events (fleet)",
+            "origin_unix_s": epoch0,
+            "sources": sources_meta,
+        },
+    }
+    if out_path is not None:
+        tmp = str(out_path) + ".tmp"
+        with open(tmp, "w") as f:
+            json.dump(fleet, f, default=float)
+        os.replace(tmp, str(out_path))
+    return fleet
+
+
+# ---------------------------------------------------------- validation
+
+
+def validate_merged_trace(path_or_trace) -> list:
+    """Schema-validate a timeline export or a fleet trace: ``B``/``E`` pairs
+    matched in stack order per (pid, tid) with monotonic timestamps,
+    ``X`` events non-negative and time-ordered per device track, every
+    device pid distinct with a ``process_name`` metadata record, and
+    every async ``b`` closed by a same-id ``e`` no earlier than its
+    begin.  Returns failure strings (empty = valid)."""
+    if isinstance(path_or_trace, dict):
+        data = path_or_trace
+    else:
+        try:
+            with open(path_or_trace) as f:
+                data = json.load(f)
+        except (OSError, json.JSONDecodeError) as e:
+            return [f"merged trace unreadable: {e}"]
+    events = data.get("traceEvents")
+    if not isinstance(events, list):
+        return ["merged trace has no traceEvents list"]
+    failures: list = []
+    stacks: dict = {}
+    last_ts: dict = {}
+    last_x: dict = {}
+    named_pids = set()
+    async_open: dict = {}
+    for i, ev in enumerate(events):
+        if not isinstance(ev, dict) or "ph" not in ev:
+            failures.append(f"event {i}: not a trace event")
+            continue
+        ph = ev["ph"]
+        pid = ev.get("pid")
+        key = (pid, ev.get("tid"))
+        if ph == "M":
+            if ev.get("name") == "process_name":
+                named_pids.add(pid)
+            continue
+        ts = ev.get("ts")
+        if not isinstance(ts, (int, float)):
+            failures.append(f"event {i}: bad ts {ts!r}")
+            continue
+        if ph in ("B", "E"):
+            if ts < last_ts.get(key, float("-inf")):
+                failures.append(
+                    f"event {i}: ts {ts} went backwards on {key}"
+                )
+            last_ts[key] = ts
+            stack = stacks.setdefault(key, [])
+            if ph == "B":
+                stack.append((ev.get("name"), ts))
+            elif not stack:
+                failures.append(
+                    f"event {i}: E {ev.get('name')!r} with empty stack "
+                    f"on {key}"
+                )
+            else:
+                bname, bts = stack.pop()
+                if bname != ev.get("name"):
+                    failures.append(
+                        f"event {i}: E {ev.get('name')!r} closes "
+                        f"B {bname!r}"
+                    )
+                if ts < bts:
+                    failures.append(
+                        f"event {i}: span {bname!r} ends before it begins"
+                    )
+        elif ph == "X":
+            if ev.get("dur", 0) < 0:
+                failures.append(f"event {i}: X with negative dur")
+            if ts < last_x.get(key, float("-inf")):
+                failures.append(
+                    f"event {i}: X events out of order on {key}"
+                )
+            last_x[key] = ts
+        elif ph == "b":
+            async_open[(pid, ev.get("id"))] = (i, ts)
+        elif ph == "e":
+            opened = async_open.pop((pid, ev.get("id")), None)
+            if opened is None:
+                failures.append(
+                    f"event {i}: async e id={ev.get('id')!r} never began"
+                )
+            elif ts < opened[1]:
+                failures.append(
+                    f"event {i}: async id={ev.get('id')!r} ends before "
+                    f"its begin"
+                )
+    for key, stack in stacks.items():
+        if stack:
+            failures.append(
+                f"{key}: {len(stack)} unmatched B events "
+                f"({[n for n, _ in stack]})"
+            )
+    for (pid, aid), (i, _ts) in async_open.items():
+        failures.append(f"event {i}: async b id={aid!r} never ended")
+    # every X-bearing pid must be named (one pid per device, labeled)
+    for key in last_x:
+        if key[0] not in named_pids:
+            failures.append(
+                f"pid {key[0]}: device track has no process_name metadata"
+            )
+    return failures

@@ -14,7 +14,11 @@ Design constraints (ISSUE 1):
   still each count);
 * **host-side only** — recording happens outside jit boundaries; the
   instrumented seams skip recording when handed tracers (see
-  ``parallel/halo.py``), so jitted code never embeds telemetry ops.
+  ``parallel/halo.py``), so jitted code never embeds telemetry ops;
+* **one span mechanism** — every ``phase`` is also a
+  ``jax.profiler.TraceAnnotation`` of the same name, so a profiler
+  capture shows it on its host plane, on the device ops' clock (a no-op
+  while no capture is live).
 
 Values are kept as plain Python scalars so a report JSON-serializes
 without custom encoders.
@@ -23,10 +27,11 @@ from __future__ import annotations
 
 import math
 import os
+import sys
 import threading
 import time
 import weakref
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 
 __all__ = ["MetricsRegistry", "metrics", "enable", "disable"]
 
@@ -40,6 +45,15 @@ def _labels_key(labels: dict) -> tuple:
 
 def _labels_str(key: tuple) -> str:
     return ",".join(f"{k}={v}" for k, v in key)
+
+
+def trace_annotation(name: str):
+    """``jax.profiler.TraceAnnotation(name)`` once jax is imported (a
+    process that never imported jax has no profiler capture to mark),
+    else a null context: this module imports nothing beyond the
+    stdlib."""
+    prof = sys.modules.get("jax.profiler")
+    return prof.TraceAnnotation(name) if prof is not None else nullcontext()
 
 
 def _scalar(value):
@@ -60,10 +74,6 @@ class MetricsRegistry:
 
     def __init__(self, enabled: bool = True):
         self.enabled = bool(enabled)
-        #: when True, ``phase`` additionally opens a named
-        #: ``jax.profiler.TraceAnnotation`` span (opt-in via
-        #: ``obs.profile_trace``; requires jax)
-        self.annotate = False
         self._lock = threading.Lock()
         self._counters: dict = {}   # (name, labelkey) -> number
         self._gauges: dict = {}     # (name, labelkey) -> number
@@ -247,46 +257,38 @@ class MetricsRegistry:
 
     @contextmanager
     def phase(self, name: str):
-        """Time a named phase.  Re-entrant: only the outermost span of a
+        """Time a named phase, marked on the profiler's host plane by a
+        :func:`trace_annotation` of the same name whether or not the
+        registry records.  Re-entrant: only the outermost span of a
         name (per thread) adds wall time and a completion, so recursive
         instrumented paths (e.g. a rebuild inside a migration) never
         double-count."""
-        if not self.enabled:
-            yield
-            return
-        depths = getattr(self._tls, "depths", None)
-        if depths is None:
-            depths = self._tls.depths = {}
-        outer = depths.get(name, 0)
-        depths[name] = outer + 1
-        ann = None
-        if self.annotate:
+        with trace_annotation(name):
+            if not self.enabled:
+                yield
+                return
+            depths = getattr(self._tls, "depths", None)
+            if depths is None:
+                depths = self._tls.depths = {}
+            outer = depths.get(name, 0)
+            depths[name] = outer + 1
+            t0 = time.perf_counter()
             try:
-                import jax
-
-                ann = jax.profiler.TraceAnnotation(name)
-                ann.__enter__()
-            except Exception:  # noqa: BLE001 — tracing must never break work
-                ann = None
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            dt = time.perf_counter() - t0
-            if ann is not None:
-                ann.__exit__(None, None, None)
-            if outer == 0:
-                del depths[name]
-                with self._lock:
-                    rec = self._phases.get(name)
-                    if rec is None:
-                        self._phases[name] = [dt, 1]
-                    else:
-                        rec[0] += dt
-                        rec[1] += 1
-                self._span_hooks(name, t0, dt)
-            else:
-                depths[name] = outer
+                yield
+            finally:
+                dt = time.perf_counter() - t0
+                if outer == 0:
+                    del depths[name]
+                    with self._lock:
+                        rec = self._phases.get(name)
+                        if rec is None:
+                            self._phases[name] = [dt, 1]
+                        else:
+                            rec[0] += dt
+                            rec[1] += 1
+                    self._span_hooks(name, t0, dt)
+                else:
+                    depths[name] = outer
 
     # -------------------------------------------------------------- reads
 

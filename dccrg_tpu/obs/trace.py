@@ -1,57 +1,26 @@
-"""Opt-in ``jax.profiler`` tracing around instrumented phases.
+"""``jax.profiler`` capture around a region.
 
 ``profile_trace(log_dir)`` captures a full profiler trace (view with
-TensorBoard / xprof) and, for its duration, makes every
-``metrics.phase(...)`` span emit a named ``TraceAnnotation`` — so the
-halo/epoch/LB/AMR/checkpoint seams show up as labeled host spans
-alongside the device timeline.  This is the deep-inspection hook
-SURVEY.md §5 calls for on top of the phase timers.
+TensorBoard / xprof, or read with ``jax.profiler.ProfileData``).  Every
+``metrics.phase(...)`` span and the per-call spans of the model dispatch
+are ``TraceAnnotation``s, so they land on the profiler's host plane on
+the same clock as the device ops, whoever started the capture.
 """
 from __future__ import annotations
 
 from contextlib import contextmanager
 
-from .registry import metrics
-
-__all__ = ["profile_trace", "trace_span"]
+__all__ = ["profile_trace"]
 
 
 @contextmanager
-def profile_trace(log_dir: str, annotate: bool = True, registry=None):
-    """Capture a jax.profiler trace of the enclosed region.
-
-    ``annotate`` also switches the registry's phase spans to emit
-    ``TraceAnnotation`` markers while the trace runs (restored after).
-
-    Clock-sync beacons (``obs.xplane.emit_clock_sync``) are dropped at
-    both ends of the capture: the profiler runs on its own timebase, and
-    the beacons are what lets ``obs.merge`` place the captured device
-    spans on the host ``EventTimeline`` clock.  Skipped (with the whole
-    xplane plane) under ``DCCRG_XPLANE=0``."""
+def profile_trace(log_dir: str):
+    """Capture a jax.profiler trace of the enclosed region into
+    ``log_dir``."""
     import jax
 
-    from .xplane import emit_clock_sync
-
-    reg = registry if registry is not None else metrics
-    prev = reg.annotate
-    if annotate:
-        reg.annotate = True
     jax.profiler.start_trace(str(log_dir))
     try:
-        emit_clock_sync()
         yield
     finally:
-        try:
-            emit_clock_sync()
-        finally:
-            jax.profiler.stop_trace()
-            reg.annotate = prev
-
-
-@contextmanager
-def trace_span(name: str):
-    """A single named ``TraceAnnotation`` span (host timeline marker)."""
-    import jax
-
-    with jax.profiler.TraceAnnotation(name):
-        yield
+        jax.profiler.stop_trace()

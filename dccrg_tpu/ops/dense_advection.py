@@ -133,6 +133,7 @@ def make_flux_update(nzl: int, ny: int, nx: int, area, inv_vol: float,
         )
     call = pl.pallas_call(
         kernel,
+        name="advection_plane",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(nzl,),
@@ -277,6 +278,7 @@ def make_flux_update_blocked_direct(nzl: int, ny: int, nx: int, block: int,
         )
     call = pl.pallas_call(
         kernel,
+        name="advection_blocked_direct",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(m,),
@@ -398,6 +400,7 @@ def make_fused_run(nzl: int, ny: int, nx: int, area, inv_vol: float,
         )
     call = pl.pallas_call(
         kernel,
+        name="advection_fused_run",
         in_specs=[smem, smem] + [vmem] * 8,
         out_specs=vmem,
         scratch_shapes=[pltpu.VMEM((nzl, ny, nx), jnp.float32)],

@@ -43,6 +43,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 import numpy as np
 
+from ..parallel.exec_cache import traced_jit
 from .dense_advection import _make_rolls
 
 __all__ = [
@@ -408,6 +409,7 @@ def make_flat_amr_run(nz1: int, ny1: int, nx1: int, *,
         )
     call = pl.pallas_call(
         kernel,
+        name="advection_flat_run",
         in_specs=[smem] + [vmem] * 9,
         out_specs=vmem,
         scratch_shapes=[pltpu.VMEM((nz1, ny1, nxp), jnp.float32)],
@@ -635,7 +637,6 @@ def make_flat_amr_run_sharded(grid, tables, dtype=jnp.float32):
     statics = tuple(put_table(tables[k], mesh) for k in
                     ("rows", "leaf_fine", "leaf_ext", "wb_rows", "wb_valid"))
 
-    @jax.jit
     def run_impl(statics_arg, state, steps, dt):
         rho = sm(
             *statics_arg,
@@ -647,6 +648,8 @@ def make_flat_amr_run_sharded(grid, tables, dtype=jnp.float32):
             "density": rho.astype(state["density"].dtype),
             "flux": jnp.zeros_like(state["flux"]),
         }
+
+    run_impl = traced_jit("advection.flat_run", run_impl)
 
     def run_fn(state, steps, dt):
         return run_impl(statics, state, steps, dt)
@@ -966,7 +969,6 @@ def make_flat_ml_run(grid, tables, dtype=jnp.float32):
 
     # tables as runtime args (not closed over): same-shape meshes reuse
     # the executable and multi-controller tables stay legal
-    @jax.jit
     def run_impl(statics_arg, state, steps, dt):
         rho = sm(
             *statics_arg,
@@ -978,6 +980,8 @@ def make_flat_ml_run(grid, tables, dtype=jnp.float32):
             "density": rho.astype(state["density"].dtype),
             "flux": jnp.zeros_like(state["flux"]),
         }
+
+    run_impl = traced_jit("advection.flat_run", run_impl)
 
     def run_fn(state, steps, dt):
         return run_impl(statics, state, steps, dt)
@@ -1107,6 +1111,7 @@ def make_flat_ml_run_pallas(nz1: int, ny1: int, nx1: int, vl: int,
         )
     call = pl.pallas_call(
         kernel,
+        name="advection_flat_ml_run",
         in_specs=[smem] + [vmem] * (9 + n_caps),
         out_specs=vmem,
         scratch_shapes=[pltpu.VMEM((nz1, ny1, nx1), jnp.float32)],

@@ -181,8 +181,10 @@ def build_epoch(
     by shape survive the rebuild.  Builds handed no hints produce the
     deterministic natural buckets.
 
-    Telemetry: the whole build is the ``epoch.build`` phase (per-hood
-    neighbor searches under ``epoch.hood_build``); the resulting table
+    Telemetry: the whole build is the ``epoch.build`` phase, holding
+    ``epoch.hood_build`` (per-hood neighbor searches),
+    ``epoch.row_layout``, ``epoch.finish_hood`` (per-hood device tables
+    and schedules) and ``epoch.detect_dense``; the resulting table
     shapes land as ``epoch.*`` gauges.
     """
     from ..obs import metrics
@@ -252,21 +254,24 @@ def _build_epoch_impl(
         pairs = np.zeros((0, 2), dtype=np.int64)
 
     # --- row layout
-    epoch, len_all = _row_layout(mapping, topology, leaves, D, pairs,
-                                 prev_R=hints.get("R"))
+    with metrics.phase("epoch.row_layout"):
+        epoch, len_all = _row_layout(mapping, topology, leaves, D, pairs,
+                                     prev_R=hints.get("R"))
 
     # --- pass 2: per-hood device tables + schedules
     for hid, (offsets, lists, to_start, to_src, h_pairs, is_outer) in (
         hood_raw.items()
     ):
-        epoch.hoods[hid] = _finish_hood(
-            epoch, offsets, lists, to_start, to_src, h_pairs, len_all,
-            is_outer, prev_K=hints.get("K", {}).get(hid),
+        with metrics.phase("epoch.finish_hood"):
+            epoch.hoods[hid] = _finish_hood(
+                epoch, offsets, lists, to_start, to_src, h_pairs, len_all,
+                is_outer, prev_K=hints.get("K", {}).get(hid),
+            )
+    with metrics.phase("epoch.detect_dense"):
+        epoch.dense = (
+            detect_dense(mapping, topology, leaves, D)
+            if uniform_geometry else None
         )
-    epoch.dense = (
-        detect_dense(mapping, topology, leaves, D)
-        if uniform_geometry else None
-    )
     return epoch
 
 

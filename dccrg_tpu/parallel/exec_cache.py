@@ -69,7 +69,6 @@ __all__ = [
     "note_trace",
     "trace_counts",
     "reset_trace_counts",
-    "kernel_labels",
     "mesh_key",
     "enable_persistent_cache",
     "persistent_cache_dir",
@@ -371,13 +370,6 @@ class TracedKernel:
         return out
 
 
-#: XLA module name ("jit_<sanitized label>") -> traced_jit label.  The
-#: attribution link the device-timeline merge closes: kernel events in an
-#: xplane capture carry their ``hlo_module`` name, and this table maps
-#: them back onto the SAME labels ``epoch.recompiles{kernel}`` counts.
-_KERNEL_MODULES: dict = {}
-
-
 def _module_name(label: str) -> str:
     """The HLO module name a kernel labeled ``label`` compiles under:
     jax names modules ``jit_<fn.__name__>``, and :func:`traced_jit`
@@ -385,20 +377,14 @@ def _module_name(label: str) -> str:
     return "jit_" + re.sub(r"[^0-9A-Za-z_]", "_", label)
 
 
-def kernel_labels() -> dict:
-    """Snapshot of the ``hlo_module name -> kernel label`` table for
-    every kernel built through :func:`traced_jit` in this process."""
-    with _trace_lock:
-        return dict(_KERNEL_MODULES)
-
-
 def traced_jit(label: str, fn, **jit_kwargs) -> TracedKernel:
     """``jax.jit(fn)`` with trace accounting under ``label`` (see
     :class:`TracedKernel`).  The wrapper is renamed to the sanitized
-    label so the compiled program's ``hlo_module`` name — which every
-    device-timeline kernel span carries — is ``jit_<label>``: device
+    label so the compiled program's module — the name a profiler
+    capture gives every op it ran — is ``jit_<label>``
+    (``advection.dense_run`` -> ``jit_advection_dense_run``): device
     time attributes back to exactly the kernel names the recompile
-    counters use (:func:`kernel_labels` holds the mapping)."""
+    counters use."""
     import jax
 
     def marked(*args):
@@ -407,8 +393,6 @@ def traced_jit(label: str, fn, **jit_kwargs) -> TracedKernel:
 
     module = _module_name(label)
     marked.__name__ = marked.__qualname__ = module[len("jit_"):]
-    with _trace_lock:
-        _KERNEL_MODULES[module] = label
     return TracedKernel(jax.jit(marked, **jit_kwargs), label)
 
 

@@ -339,8 +339,8 @@ class HaloExchange:
 
         Each step is wrapped in a ``named_scope`` keyed by its ring
         distance k (``perm[0]`` is ``(0, k)`` by construction), so the
-        collective's HLO ops — and with them the device-timeline spans
-        the xplane merge extracts — carry a name that is STABLE across
+        collective's HLO ops — and with them the ops of a profiler
+        capture — carry a name that is STABLE across
         epoch rebuilds: ``halo.ring.k3.start`` attributes to ring
         distance 3 in every trace, regardless of how the schedule was
         rebuilt."""
@@ -825,8 +825,7 @@ class HaloExchange:
         if _metrics.enabled and not _tracing(state):
             # timed as its own phase (not halo.exchange): the span from
             # a halo.start begin to the next halo.exchange (finish) end
-            # is the collective's in-flight window — the denominator of
-            # the measured overlap fraction (obs/merge.py)
+            # is the collective's in-flight window
             self._record(state, "split")
             t0 = time.perf_counter()
             out = self._start_dispatch(state)

@@ -142,8 +142,8 @@ def ring_dma_start(blk, ks, D: int, send_tabs, *, interpret: bool):
     step's payload for this device's ``[R, ...]`` block; returns the
     per-ring-distance ``[S_k, ...]`` payloads.  The drop-in DMA form of
     ``HaloExchange.ring_start`` — same named-scope stamps
-    (``halo.ring.k<k>.start``), so device-timeline attribution
-    (``obs/merge.py``) reads identically for both transports."""
+    (``halo.ring.k<k>.start``), so a profiler capture names both
+    transports' ops alike."""
     out = []
     for k, sr in zip(ks, send_tabs):
         with jax.named_scope(f"halo.ring.k{k}.start"):

@@ -67,8 +67,8 @@ timers = PhaseTimers(registry=_global_metrics)
 def jax_trace(log_dir: str):
     """Capture a jax.profiler trace around a region (view with
     TensorBoard / xprof) — kept for back-compat; ``obs.profile_trace``
-    is the full form (adds per-phase TraceAnnotation spans)."""
+    is the same capture."""
     from ..obs.trace import profile_trace
 
-    with profile_trace(log_dir, annotate=True):
+    with profile_trace(log_dir):
         yield

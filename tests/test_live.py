@@ -407,8 +407,7 @@ def test_alert_counters_and_default_rules():
     assert "alerts.evaluate" in reg.report()["phases"]
     names = {r.name for r in alerts.default_rules()}
     assert names == {"deadline-miss-rate", "queue-depth",
-                     "halo-exchanges-per-step", "overlap-fraction",
-                     "worker-lost"}
+                     "halo-exchanges-per-step", "worker-lost"}
 
 
 def test_load_rules_and_env(tmp_path, monkeypatch):

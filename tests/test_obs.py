@@ -671,23 +671,26 @@ def test_fused_reconciliation_disabled_records_nothing():
 
 def test_profile_trace_materializes_trace_dir(tmp_path):
     """obs.profile_trace must actually leave a trace on disk (previously
-    only exercised manually via TensorBoard/xprof) — and restore the
-    annotation flag after."""
+    only exercised manually via TensorBoard/xprof), and a registry phase
+    opened inside it must be on the capture's host plane."""
     import jax
     import jax.numpy as jnp
+    from jax.profiler import ProfileData
 
     from dccrg_tpu import obs
 
     log_dir = tmp_path / "trace"
-    prev = obs.metrics.annotate
     with obs.profile_trace(str(log_dir)):
-        assert obs.metrics.annotate is True
         jax.block_until_ready(jnp.ones((16, 16)) @ jnp.ones((16, 16)))
         with obs.metrics.phase("trace.probe"):
             pass
-    assert obs.metrics.annotate is prev
     files = [p for p in log_dir.rglob("*") if p.is_file()]
     assert files, "profiler trace directory did not materialize"
+    (xplane,) = log_dir.rglob("*.xplane.pb")
+    names = {e.name for plane in ProfileData.from_file(str(xplane)).planes
+             if plane.name.startswith("/host:CPU")
+             for line in plane.lines for e in line.events}
+    assert "trace.probe" in names
 
 
 # --------------------------------------------------------------- CI gate

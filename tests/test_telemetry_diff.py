@@ -229,29 +229,27 @@ def test_history_file_rolls_and_feeds_drift(diff, tmp_path):
 
 
 def test_gauge_floor_gate(diff, tmp_path):
-    """ISSUE 6: overlap.fraction is gated as a FLOOR — a drop below
-    (1 - threshold) x baseline fails; rises, vacuous sides and
-    zero-baseline values never do; a labeled series vanishing is a
-    coverage loss."""
-    base = {"overlap.fraction": {"phase=halo": 0.6}}
-    assert diff.compare_gauges(
-        {"overlap.fraction": {"phase=halo": 0.55}}, base
-    )["verdict"] == "PASS"
-    assert diff.compare_gauges(
-        {"overlap.fraction": {"phase=halo": 0.9}}, base
-    )["verdict"] == "PASS"
-    bad = diff.compare_gauges(
-        {"overlap.fraction": {"phase=halo": 0.2}}, base, threshold=0.35
-    )
+    """A floor-gated gauge (GATED_GAUGES_MIN: the cohort peak occupancy)
+    fails on a drop below (1 - threshold) x baseline; rises, vacuous
+    sides and zero-baseline values never do; a labeled series vanishing
+    is a coverage loss."""
+    g = "ensemble.cohort_peak_occupancy"
+    assert g in diff.GATED_GAUGES_MIN
+    base = {g: {"sig=a": 0.6}}
+    assert diff.compare_gauges({g: {"sig=a": 0.55}}, base)["verdict"] \
+        == "PASS"
+    assert diff.compare_gauges({g: {"sig=a": 0.9}}, base)["verdict"] \
+        == "PASS"
+    bad = diff.compare_gauges({g: {"sig=a": 0.2}}, base, threshold=0.35)
     assert bad["verdict"] == "FAIL"
     assert "0.2" in bad["failures"][0]
-    missing = diff.compare_gauges({"overlap.fraction": {}}, base)
+    missing = diff.compare_gauges({g: {}}, base)
     assert missing["verdict"] == "FAIL"
     assert "coverage loss" in missing["failures"][0]
     assert diff.compare_gauges(None, base)["verdict"] == "PASS"
     assert diff.compare_gauges({}, None)["verdict"] == "PASS"
     assert diff.compare_gauges(
-        {}, {"overlap.fraction": {"phase=halo": 0}}
+        {}, {g: {"sig=a": 0}}
     )["verdict"] == "FAIL"  # label present with value 0 still must exist
 
 

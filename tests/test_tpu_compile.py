@@ -56,9 +56,14 @@ def _spec(sharding, shape, dtype=jnp.float32):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _compile(fn, *args):
+def _compile(fn, *args, kernel=None):
+    """Compile for the described chip; ``kernel`` is the Pallas name the
+    custom call must carry (the name a profiler capture gives its op)."""
     compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    if kernel is not None:
+        assert f"%{kernel}" in text
     return compiled
 
 
@@ -71,7 +76,8 @@ def test_fused_run_64x128x128(one_chip):
     s = lambda *shape: _spec(one_chip, shape)  # noqa: E731
     _compile(run, s(nz, ny, nx), s(nz, ny, nx), s(nz, ny, nx),
              s(nz, ny, nx), s(1, 1, nx), s(1, ny, 1), s(nz, 1, 1),
-             s(nz, 1, 1), s(), _spec(one_chip, (), jnp.int32))
+             s(nz, 1, 1), s(), _spec(one_chip, (), jnp.int32),
+             kernel="advection_fused_run")
 
 
 def test_blocked_direct_128x512x512(one_chip):
@@ -87,7 +93,7 @@ def test_blocked_direct_128x512x512(one_chip):
     s = lambda *shape: _spec(one_chip, shape)  # noqa: E731
     c, p = s(nz, ny, nx), s(1, ny, nx)
     _compile(upd, c, p, p, c, c, c, p, p, s(1, 1, nx), s(1, ny, 1),
-             s(nz, 1, 1), s(nz, 1, 1), s())
+             s(nz, 1, 1), s(nz, 1, 1), s(), kernel="advection_blocked_direct")
 
 
 def test_plane_flux_update(one_chip):
@@ -98,7 +104,7 @@ def test_plane_flux_update(one_chip):
     s = lambda *shape: _spec(one_chip, shape)  # noqa: E731
     _compile(upd, s(nz + 2, ny, nx), s(nz, ny, nx), s(nz, ny, nx),
              s(nz + 2, ny, nx), s(1, 1, nx), s(1, ny, 1), s(nz, 1, 1),
-             s(nz, 1, 1), s())
+             s(nz, 1, 1), s(), kernel="advection_plane")
 
 
 def test_flat_amr_refined(one_chip):
@@ -118,7 +124,7 @@ def test_flat_amr_refined(one_chip):
     run = make_flat_amr_run(n, n, n, nx_pad=nxp)
     a = _spec(one_chip, (n, n, n))
     _compile(run, *([a] * 9), _spec(one_chip, ()),
-             _spec(one_chip, (), jnp.int32))
+             _spec(one_chip, (), jnp.int32), kernel="advection_flat_run")
 
 
 def test_flat_ml_pallas(one_chip):
@@ -135,7 +141,7 @@ def test_flat_ml_pallas(one_chip):
     a = _spec(one_chip, (n, n, n))
     _compile(lambda *x: run(*x[:9], x[9:11], x[11], x[12]),
              *([a] * 11), _spec(one_chip, ()),
-             _spec(one_chip, (), jnp.int32))
+             _spec(one_chip, (), jnp.int32), kernel="advection_flat_ml_run")
 
 
 def test_gol_500(one_chip):

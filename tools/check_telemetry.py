@@ -24,21 +24,10 @@ Checks (exit 1 on any failure):
   the probe fails unless ``resilience.injected``,
   ``checkpoint.crc_failures``, ``lineage.generations_skipped`` and
   ``p2p.retries`` all recorded;
-* a profiled round (ISSUE 6): one split-phase drive captured under
-  ``jax.profiler`` must produce the measured device-timeline plane —
-  ``overlap.fraction{phase=halo}`` in (0, 1], per-device busy gauges,
-  kernel attribution intersecting ``epoch.recompiles``, and a
-  schema-valid merged trace (``<out>.merged_trace.json``, also checkable
-  standalone via ``--validate-merged-trace``); captures with no
-  execution lines (deviceless backends, ``DCCRG_XPLANE=0``) are the
-  documented no-op;
 * a halo-backend round (ISSUE 7): a forced ``DCCRG_HALO_BACKEND=pallas``
   + ``DCCRG_HALO_VERIFY=1`` grid runs blocking and split exchanges
   through the async-DMA ring bodies (interpreted on CPU) and must leave
-  ``halo.verify_checks`` with zero ``halo.verify_mismatches``; the
-  profiled round additionally drives the fused split-phase advection and
-  vlasov steps and requires their per-model
-  ``overlap.fraction{model=..., phase=halo}`` gauges;
+  ``halo.verify_checks`` with zero ``halo.verify_mismatches``;
 * an elastic round (ISSUE 8): one forced rescale down AND up through a
   checkpoint lineage (payload bit-identical both ways, the
   ``elastic.rescale`` phase + ``elastic.rescales{direction}`` counters
@@ -65,8 +54,8 @@ Checks (exit 1 on any failure):
   naming the dead worker, and a journal reopen must replay the retired
   state (``gateway.{accepted,rejected,redispatched,journal_replays}``
   all required nonzero);
-* side artifacts (``<out>.stream.jsonl`` / ``.trace.json`` /
-  ``.merged_trace.json``) land next to ``--out`` — or under ``tools/``
+* side artifacts (``<out>.stream.jsonl`` / ``.trace.json``) land next
+  to ``--out`` — or under ``tools/``
   when ``--out`` is the repo root's ``telemetry.json``, keeping bench
   byproducts out of the root (``--artifact-dir`` overrides);
 * unless ``--skip-overhead``: enabling telemetry must not slow the
@@ -431,83 +420,6 @@ def drive(g, adv, state, dt, steps: int):
         state = adv.step(state, dt)
     jax.block_until_ready(state["density"])
     return state
-
-
-def drive_split(g, adv, state, dt, steps: int):
-    """The split-phase step loop — the source paper's
-    ``start_remote_neighbor_copies`` / compute / ``wait`` pattern: ghost
-    payloads go in flight, interior compute dispatches with no data
-    dependence on them, then the wait merges.  This is the drive the
-    device-timeline probe profiles: the in-flight windows it opens (the
-    ``halo.start`` -> ``halo.exchange`` host spans) are the denominator
-    of the measured ``overlap.fraction{phase=halo}``."""
-    import jax
-
-    for i in range(steps):
-        from dccrg_tpu import obs
-
-        with obs.timeline.context(step=i):
-            fields = {"density": state["density"]}
-            handle = g.start_remote_neighbor_copy_updates(fields)
-            interior = adv.step(state, dt)     # overlaps the collective
-            fields = g.wait_remote_neighbor_copy_updates(fields, handle)
-            state = adv.step({**interior, **fields}, dt)
-    jax.block_until_ready(state["density"])
-    return state
-
-
-def drive_fused(step_once, state, steps: int):
-    """Drive a FUSED split-phase step (ISSUE 7: advection/vlasov
-    ``overlap=True``, GoL's overlap step): the whole start → interior →
-    finish → boundary program is ONE dispatch, so the host-visible
-    in-flight window is dispatch → completion.  Each step stamps the
-    dispatch as a ``halo.start`` span and the completing sync as
-    ``halo.exchange`` — the window shape ``obs/merge.py`` pairs — so the
-    merged trace measures how much device compute the window hid.  (For
-    a fused step this window bounds the true in-flight interval from
-    above; the fraction is still a measured floor-gateable overlap
-    signal, not an inference.)"""
-    import jax
-
-    from dccrg_tpu import obs
-
-    for i in range(steps):
-        with obs.timeline.context(step=i):
-            t0 = time.perf_counter()
-            state = step_once(state)
-            obs.metrics.phase_add("halo.start", time.perf_counter() - t0)
-            t0 = time.perf_counter()
-            jax.block_until_ready(state)
-            obs.metrics.phase_add("halo.exchange",
-                                  time.perf_counter() - t0)
-    return state
-
-
-def build_fused_model(g, model: str):
-    """A fused split-phase stepper for one model on grid ``g``:
-    ``(step_once, state)``.  Shared by the device-timeline probe and
-    ``tools/trace_report.py --run --model``."""
-    import numpy as np
-
-    from dccrg_tpu.models import Advection, GameOfLife, Vlasov
-
-    if model == "advection":
-        adv = Advection(g, dtype=np.float32, allow_dense=False,
-                        overlap=True)
-        state = adv.initialize_state()
-        dt = np.float32(0.4 * adv.max_time_step(state))
-        return (lambda s: adv.step(s, dt)), state
-    if model == "vlasov":
-        vl = Vlasov(g, nv=2, dtype=np.float32, overlap=True)
-        state = vl.initialize_state()
-        dt = np.float32(0.5 * vl.max_time_step())
-        return (lambda s: vl.step(s, dt)), state
-    if model == "gol":
-        gol = GameOfLife(g, overlap=True)
-        cells = g.get_cells()
-        state = gol.new_state(alive_cells=cells[:: 3])
-        return gol.step, state
-    raise ValueError(f"unknown model {model!r}")
 
 
 def _resilience_probe(g, state) -> list:
@@ -1757,114 +1669,12 @@ def _live_probe(g, adv, state, dt, steps: int, reps: int = 11,
     return failures
 
 
-def _device_timeline_probe(g, adv, state, dt, out_path: str,
-                           merged_path: str | None = None) -> list:
-    """Profiled round (ISSUE 6): capture one split-phase drive under
-    ``jax.profiler``, merge the xplane capture with the host timeline,
-    and require the measured plane to materialize — a schema-valid
-    merged trace next to ``telemetry.json``, a nonzero
-    ``overlap.fraction{phase=halo}`` gauge, per-device busy gauges, and
-    per-kernel device-time attribution intersecting the
-    ``epoch.recompiles`` kernel set.  On a backend whose capture holds
-    no execution lines at all (no device planes, no XLA runtime
-    threads), or under ``DCCRG_XPLANE=0``, the probe is the documented
-    no-op: it notes the absence and requires nothing."""
-    from dccrg_tpu import obs
-    from dccrg_tpu.obs.xplane import xplane_enabled
-
-    failures: list = []
-    if not xplane_enabled():
-        print("device-timeline probe skipped (DCCRG_XPLANE=0)",
-              file=sys.stderr)
-        return failures
-    if merged_path is None:
-        merged_path = artifact_path(out_path, ".merged_trace.json")
-    with tempfile.TemporaryDirectory() as td:
-        try:
-            with obs.profile_trace(td):
-                drive_split(g, adv, state, dt, 6)
-            # compacted export: the probe trace rides next to
-            # telemetry.json in the repo — gauges use the full spans,
-            # the artifact keeps the longest per device (truncation
-            # noted in otherData.device_spans_dropped)
-            _merged, summary = obs.merge_profile(
-                td, out_path=merged_path, out_max_spans=250,
-            )
-        except Exception as e:  # noqa: BLE001 — probe must report, not die
-            return [f"device-timeline probe failed: {e!r}"]
-    if not summary["device_evidence"]:
-        print("device-timeline probe: capture holds no execution lines "
-              "(deviceless backend) — overlap/busy gauges not required",
-              file=sys.stderr)
-        return failures
-    # ISSUE 7: fused split-phase rounds — one compiled start → interior
-    # → finish → boundary program per model — must measure their own
-    # overlap, recorded per model so telemetry_diff's floor gate watches
-    # each series (not just the host-split GoL/advection drive above)
-    for model in ("advection", "vlasov"):
-        try:
-            step_once, mstate = build_fused_model(g, model)
-            mstate = drive_fused(step_once, mstate, 1)   # warm compiles
-            with tempfile.TemporaryDirectory() as td:
-                with obs.profile_trace(td):
-                    drive_fused(step_once, mstate, 4)
-                obs.merge_profile(td, extra_labels={"model": model})
-        except Exception as e:  # noqa: BLE001 — probe reports, not dies
-            failures.append(
-                f"fused split-phase {model} probe failed: {e!r}"
-            )
-    rep = obs.metrics.report()
-    gauges = rep["gauges"]
-    frac = gauges.get("overlap.fraction", {}).get("phase=halo")
-    if frac is None:
-        failures.append("overlap.fraction{phase=halo} gauge missing "
-                        "after the profiled round")
-    elif not 0.0 < frac <= 1.0:
-        failures.append(
-            f"overlap.fraction{{phase=halo}} = {frac}: the split-phase "
-            "probe must measure nonzero in-(0,1] overlap"
-        )
-    for model in ("advection", "vlasov"):
-        mfrac = gauges.get("overlap.fraction", {}).get(
-            f"model={model},phase=halo"
-        )
-        if mfrac is None:
-            failures.append(
-                f"overlap.fraction{{model={model},phase=halo}} gauge "
-                "missing after the fused split-phase round"
-            )
-        elif not 0.0 < mfrac <= 1.0:
-            failures.append(
-                f"overlap.fraction{{model={model},phase=halo}} = "
-                f"{mfrac}: the fused round must measure nonzero "
-                "in-(0,1] overlap"
-            )
-    if not gauges.get("device.busy_fraction"):
-        failures.append("device.busy_fraction{device=d} gauges missing "
-                        "after the profiled round")
-    attributed = set(rep["counters"].get("device.kernel_time_us", {}))
-    recompiled = set(rep["counters"].get("epoch.recompiles", {}))
-    if not attributed & recompiled:
-        failures.append(
-            "device-time attribution names never intersect the "
-            f"epoch.recompiles kernel set (attributed: "
-            f"{sorted(attributed)[:6]}; compiled: "
-            f"{sorted(recompiled)[:6]}) — the compiled->ran loop is "
-            "broken"
-        )
-    failures += [
-        f"merged trace: {f}"
-        for f in obs.validate_merged_trace(merged_path)
-    ]
-    return failures
-
-
 def run_check(out_path: str, steps: int = 20, skip_overhead: bool = False,
               reps: int = 11, threshold: float = 1.05,
               artifact_dir: str | None = None) -> list:
     """Run the workload + checks; returns a list of failure strings
     (empty = pass) and writes ``telemetry.json`` to ``out_path`` (side
-    artifacts — stream/trace/merged-trace — via :func:`artifact_path`)."""
+    artifacts — stream/trace — via :func:`artifact_path`)."""
     _ensure_env()
     import numpy as np
 
@@ -1902,27 +1712,19 @@ def run_check(out_path: str, steps: int = 20, skip_overhead: bool = False,
     failures += _slo_probe()
 
     if not skip_overhead:
-        # measured BEFORE the profiled round: the xplane ingest/merge
-        # allocates MBs of span records whose GC pauses would otherwise
-        # land inside the timed reps and flake the 5% budget
         failures += _overhead_probe(g, adv, state, dt, steps,
                                     reps=reps, threshold=threshold)
     failures += _live_probe(g, adv, state, dt, steps,
                             reps=reps, threshold=threshold,
                             skip_overhead=skip_overhead)
-    # after the timed overhead reps for the same reason as the xplane
-    # round: the cost probe's burst ensembles allocate enough that
-    # their GC debt would land inside the 5% budget's timed halves
+    # after the timed overhead reps: the cost probe's burst ensembles
+    # allocate enough that their GC debt would land inside the 5%
+    # budget's timed halves
     # (the budget is still measured with the cost model armed —
     # DCCRG_COST_MODEL defaults on, asserted inside the probe)
     failures += _cost_probe()
     failures += _elastic_probe(g, state)
     failures += _fleet_probe()
-    failures += _device_timeline_probe(
-        g, adv, state, dt, out_path,
-        merged_path=artifact_path(out_path, ".merged_trace.json",
-                                  artifact_dir),
-    )
 
     report = g.report()
     for phase in REQUIRED_PHASES:
@@ -2026,7 +1828,7 @@ def main(argv=None) -> int:
     ap.add_argument("--out", default=str(ROOT / "telemetry.json"),
                     help="where to write telemetry.json")
     ap.add_argument("--artifact-dir", default=None,
-                    help="where the stream/trace/merged-trace side "
+                    help="where the stream/trace side "
                          "artifacts land (default: next to --out, or "
                          "tools/ when --out is at the repo root — the "
                          "root stays free of bench byproducts)")
@@ -2047,8 +1849,8 @@ def main(argv=None) -> int:
                     help="only schema-validate an existing Chrome "
                          "trace-event export and exit")
     ap.add_argument("--validate-merged-trace", default=None, metavar="FILE",
-                    help="only schema-validate an existing merged "
-                         "host+device (or fleet) trace and exit")
+                    help="only schema-validate an existing fleet "
+                         "trace (tools/trace_report.py --fleet) and exit")
     args = ap.parse_args(argv)
     if args.validate_stream or args.validate_trace or \
             args.validate_merged_trace:
@@ -2067,7 +1869,7 @@ def main(argv=None) -> int:
                          for f in validate_chrome_trace(args.validate_trace)]
         if args.validate_merged_trace:
             _ensure_env()
-            from dccrg_tpu.obs.merge import validate_merged_trace
+            from dccrg_tpu.obs.events import validate_merged_trace
 
             failures += [
                 f"merged: {f}"

@@ -13,14 +13,12 @@ or ensemble processes the same way one process would have recorded them:
     python tools/slo_report.py run1.json run2.json    # merged fleet view
     python tools/slo_report.py --json slo.json        # machine-readable
 
-Drill-down: ``--trace`` takes a Chrome/merged trace (the
-``obs.merge_profile`` output, or any ``export_chrome_trace`` file whose
-timeline recorded ``request.e2e`` spans) and prints the N slowest
-requests with the kernel/device spans that overlap each one's window —
-the "this request was slow BECAUSE that kernel ran long" cross-reference
-the merged device timeline exists for:
+Drill-down: ``--trace`` takes a Chrome trace (any
+``export_chrome_trace`` file whose timeline recorded ``request.e2e``
+spans, or a fleet trace of several) and prints the N slowest requests
+with the spans of other tracks that overlap each one's window:
 
-    python tools/slo_report.py --trace tools/telemetry.json.merged_trace.json
+    python tools/slo_report.py --trace tools/telemetry.json.trace.json
 
 This tool loads ``dccrg_tpu/obs/slo.py`` directly from its file (the
 module is stdlib-only by contract), so reporting never imports jax.
@@ -156,9 +154,9 @@ def _trace_spans(events: list) -> list:
 
 def slowest_requests(trace: dict, top: int = 5,
                      kernels_per_request: int = 6) -> list:
-    """The ``top`` slowest ``request.e2e`` spans in a (merged) trace,
-    each cross-referenced with the longest spans from OTHER pids —
-    device kernel tracks in a merged trace — overlapping its window."""
+    """The ``top`` slowest ``request.e2e`` spans in a trace, each
+    cross-referenced with the longest spans from OTHER pids (the other
+    processes of a fleet trace) overlapping its window."""
     events = trace.get("traceEvents") if isinstance(trace, dict) else trace
     spans = _trace_spans(events or [])
     requests = sorted(

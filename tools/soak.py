@@ -2282,7 +2282,7 @@ def merge_fleet(stream_dir: str) -> str | None:
         return None
     sys.path.insert(0, str(ROOT))
     try:
-        from dccrg_tpu.obs.merge import merge_chrome_traces
+        from dccrg_tpu.obs.events import merge_chrome_traces
 
         out = os.path.join(stream_dir, "fleet_trace.json")
         fleet = merge_chrome_traces(traces, out_path=out)

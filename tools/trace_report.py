@@ -1,159 +1,26 @@
 #!/usr/bin/env python
-"""Device-timeline report: what ran, where the time went, what overlapped.
+"""Fleet trace: unify per-process timeline exports into one trace.
 
-Consumes the merged host+device timeline (``dccrg_tpu.obs.merge``) and
-prints the three answers a perf PR needs:
+    python tools/trace_report.py --fleet T1 T2 .. [--merged-out FILE] [--json]
 
-* **top kernels by device time** — attribution keyed by the same kernel
-  labels ``epoch.recompiles{kernel}`` counts (``traced_jit`` names the
-  compiled modules), so "what compiled" and "what ran" line up;
-* **overlap summary** — the measured ``overlap.fraction{phase=halo}``:
-  how much of the collective in-flight window (``halo.start`` dispatch
-  -> ``halo.exchange`` wait) coincided with interior device compute;
-* **host-gap hunting** — windows where every device sat idle, with the
-  host phases that were open (where dispatch overhead hides).
+Every source is a Chrome trace-event export of one process's
+``obs.timeline`` (``obs.export_chrome_trace``); each carries the
+wall-clock anchor of its origin, and ``obs.merge_chrome_traces`` shifts
+them onto the earliest one.  The merged trace is validated
+(``obs.validate_merged_trace``); the exit code is 1 when it is not valid.
 
-Three input modes:
-
-    python tools/trace_report.py --run             # self-contained probe:
-        profile one split-phase round in-process, full merge (host
-        timeline + device planes), report + gauges; --model picks the
-        drive (host-split advection, or the fused split-phase step of
-        gol/advection-fused/vlasov) and --halo-backend pins the halo
-        transport (ISSUE 7)
-    python tools/trace_report.py LOGDIR            # post-hoc: an existing
-        jax.profiler log dir; the host track is rebuilt from the capture's
-        own TraceAnnotations (no live timeline needed)
-    python tools/trace_report.py --fleet T1 T2 ..  # unify per-process
-        merged traces on their shared epoch-zero into one fleet trace
-
-``--json`` prints the full machine-readable record (CI consumes the
-``overlap``/``kernels`` keys); ``--merged-out`` exports the merged Chrome
-trace for perfetto.  Backends that emit no execution lines (and
-``DCCRG_XPLANE=0``) report ``device_evidence: false`` and exit 0 — the
-documented no-op — unless ``--require-devices`` makes absence an error.
+Device time is read from a ``jax.profiler`` capture instead, where the
+program's phase spans sit on the profiler's host plane on the device
+ops' clock (``benchmark/xtrace.py``).
 """
 from __future__ import annotations
 
 import argparse
 import json
-import os
 import pathlib
 import sys
-import tempfile
 
 ROOT = pathlib.Path(__file__).resolve().parent.parent
-
-
-def _ensure_env() -> None:
-    if str(ROOT) not in sys.path:
-        sys.path.insert(0, str(ROOT))
-    sys.path.insert(0, str(ROOT / "tools"))
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in flags:
-        os.environ["XLA_FLAGS"] = (
-            flags + " --xla_force_host_platform_device_count=4"
-        ).strip()
-
-
-def run_probe(steps: int = 6, model: str = "advection",
-              halo_backend: str | None = None):
-    """Profile one split-phase round in-process and return
-    ``(merged, summary)`` — the full live-host merge, gauges recorded.
-
-    ``model`` picks the drive: ``advection`` profiles the host-split
-    start/compute/wait loop (the source paper's pattern), while
-    ``advection-fused``, ``vlasov`` and ``gol`` profile the model's
-    FUSED split-phase step (one compiled start → interior → finish →
-    boundary program, ISSUE 7).  ``halo_backend`` exports
-    ``DCCRG_HALO_BACKEND`` before any schedule compiles, so any model's
-    overlap can be measured on either transport from the CLI."""
-    from dccrg_tpu import obs
-    import check_telemetry as ct
-
-    if halo_backend:
-        os.environ["DCCRG_HALO_BACKEND"] = halo_backend
-    obs.enable()
-    obs.enable_timeline()
-    g, adv, state, dt = ct.build_workload()
-    if model == "advection":
-        state = ct.drive(g, adv, state, dt, 2)      # warm the compiles
-        state = ct.drive_split(g, adv, state, dt, 1)
-        with tempfile.TemporaryDirectory() as td:
-            with obs.profile_trace(td):
-                ct.drive_split(g, adv, state, dt, steps)
-            return obs.merge_profile(td)
-    name = "advection" if model == "advection-fused" else model
-    step_once, mstate = ct.build_fused_model(g, name)
-    mstate = ct.drive_fused(step_once, mstate, 1)   # warm the compiles
-    with tempfile.TemporaryDirectory() as td:
-        with obs.profile_trace(td):
-            ct.drive_fused(step_once, mstate, steps)
-        return obs.merge_profile(td, extra_labels={"model": name})
-
-
-def report_record(merged, summary, top: int = 10,
-                  gaps_min_us: float = 100.0) -> dict:
-    """The machine-readable report: summary + top kernels + gaps +
-    the recompile-key cross-reference (when this process compiled)."""
-    from dccrg_tpu import obs
-
-    kernels = list(summary["kernels"].items())[:top]
-    recompiles = obs.metrics.report()["counters"].get(
-        "epoch.recompiles", {}
-    )
-    compiled = {k.split("=", 1)[1] for k in recompiles if "=" in k}
-    return {
-        "window_s": summary["window_s"],
-        "aligned": summary["aligned"],
-        "alignment": summary["alignment"],
-        "device_evidence": summary["device_evidence"],
-        "devices": summary["devices"],
-        "overlap": summary["overlap"],
-        "top_kernels": [
-            {"kernel": name, **rec,
-             "compiled_this_process": name in compiled}
-            for name, rec in kernels
-        ],
-        "host_gaps": merged.host_gaps(min_us=gaps_min_us, top=top),
-    }
-
-
-def print_report(rec: dict) -> None:
-    print(f"window {rec['window_s'] * 1e3:.1f} ms   "
-          f"aligned: {rec['aligned']}   "
-          f"devices: {len(rec['devices'])}")
-    if not rec["device_evidence"]:
-        print("no device execution evidence in this capture "
-              "(deviceless backend or DCCRG_XPLANE=0) — host-only report")
-        return
-    for dev, d in sorted(rec["devices"].items(), key=lambda kv: str(kv[0])):
-        print(f"  device {dev} ({d['kind']}): busy {d['busy_s'] * 1e3:.2f} ms"
-              f" ({d['fraction'] * 100:.1f}%), {d['spans']} spans")
-    ov = rec["overlap"]["halo"]
-    if ov["fraction"] is not None:
-        print(f"overlap[halo]: {ov['fraction'] * 100:.1f}% of "
-              f"{ov['inflight_s'] * 1e3:.2f} ms in-flight hidden under "
-              f"interior compute "
-              f"(compute {ov['device_compute_s'] * 1e3:.2f} ms, "
-              f"collectives {ov['device_collective_s'] * 1e3:.2f} ms)")
-    else:
-        print("overlap[halo]: no halo spans on the host track")
-    print(f"top kernels by device time:")
-    for k in rec["top_kernels"]:
-        mark = "*" if k["compiled_this_process"] else " "
-        print(f" {mark} {k['kernel']:32s} {k['time_us'] / 1e3:10.2f} ms  "
-              f"{k['count']:8d} calls  ({k['module'] or '-'})")
-    if rec["top_kernels"]:
-        print("   (* = kernel label also in this process's "
-              "epoch.recompiles)")
-    if rec["host_gaps"]:
-        print("host gaps (all devices idle):")
-        for gap in rec["host_gaps"]:
-            phases = ", ".join(gap["open_host_phases"]) or "-"
-            print(f"   +{gap['start_us'] / 1e3:10.2f} ms  "
-                  f"{gap['dur_us'] / 1e3:8.2f} ms   open: {phases}")
 
 
 def main(argv=None) -> int:
@@ -161,87 +28,38 @@ def main(argv=None) -> int:
         description=__doc__,
         formatter_class=argparse.RawDescriptionHelpFormatter,
     )
-    ap.add_argument("log_dir", nargs="?", default=None,
-                    help="existing jax.profiler log dir to analyze "
-                         "post-hoc (host track from its annotations)")
-    ap.add_argument("--run", action="store_true",
-                    help="profile a built-in split-phase advection round "
-                         "in-process and report the live merge")
-    ap.add_argument("--steps", type=int, default=6,
-                    help="probe steps under --run")
-    ap.add_argument("--model",
-                    choices=("advection", "advection-fused", "gol",
-                             "vlasov"),
-                    default="advection",
-                    help="drive profiled under --run: 'advection' is "
-                         "the host-split loop; the others drive the "
-                         "model's fused split-phase step (ISSUE 7)")
-    ap.add_argument("--halo-backend", choices=("collective", "pallas",
-                                               "auto"),
-                    default=None,
-                    help="export DCCRG_HALO_BACKEND before the probe "
-                         "compiles its halo schedules")
-    ap.add_argument("--fleet", nargs="+", default=None, metavar="TRACE",
-                    help="merge per-process merged traces onto their "
-                         "shared epoch-zero; write with --merged-out")
-    ap.add_argument("--top", type=int, default=10,
-                    help="kernels/gaps listed")
-    ap.add_argument("--gaps-min-us", type=float, default=100.0,
-                    help="minimum device-idle gap reported")
+    ap.add_argument("--fleet", nargs="+", required=True, metavar="TRACE",
+                    help="per-process timeline exports to merge onto "
+                         "their shared epoch-zero")
     ap.add_argument("--json", action="store_true",
                     help="print the machine-readable record (CI mode)")
     ap.add_argument("--merged-out", default=None, metavar="FILE",
-                    help="also export the merged Chrome trace here")
-    ap.add_argument("--require-devices", action="store_true",
-                    help="exit 1 when the capture holds no device "
-                         "execution evidence (CI on device hosts)")
+                    help="also write the fleet trace here")
     args = ap.parse_args(argv)
-    _ensure_env()
+    if str(ROOT) not in sys.path:
+        sys.path.insert(0, str(ROOT))
+    from dccrg_tpu.obs.events import (merge_chrome_traces,
+                                      validate_merged_trace)
 
-    if args.fleet:
-        from dccrg_tpu.obs.merge import (merge_chrome_traces,
-                                         validate_merged_trace)
-
-        fleet = merge_chrome_traces(args.fleet, out_path=args.merged_out)
-        failures = validate_merged_trace(fleet)
-        rec = {
-            "sources": fleet["otherData"]["sources"],
-            "events": len(fleet["traceEvents"]),
-            "origin_unix_s": fleet["otherData"]["origin_unix_s"],
-            "valid": not failures,
-            "failures": failures,
-        }
-        if args.json:
-            print(json.dumps(rec, indent=1))
-        else:
-            print(f"fleet trace: {rec['events']} events from "
-                  f"{len(rec['sources'])} processes on epoch-zero "
-                  f"{rec['origin_unix_s']:.6f}"
-                  + (f" -> {args.merged_out}" if args.merged_out else ""))
-            for f in failures:
-                print(f"FAIL: {f}", file=sys.stderr)
-        return 1 if failures else 0
-
-    if args.run or args.log_dir is None:
-        merged, summary = run_probe(steps=args.steps, model=args.model,
-                                    halo_backend=args.halo_backend)
-    else:
-        from dccrg_tpu.obs.merge import build_from_capture
-
-        merged = build_from_capture(args.log_dir)
-        summary = merged.summary()
-    if args.merged_out:
-        merged.export(args.merged_out)
-    rec = report_record(merged, summary, top=args.top,
-                        gaps_min_us=args.gaps_min_us)
+    fleet = merge_chrome_traces(args.fleet, out_path=args.merged_out)
+    failures = validate_merged_trace(fleet)
+    rec = {
+        "sources": fleet["otherData"]["sources"],
+        "events": len(fleet["traceEvents"]),
+        "origin_unix_s": fleet["otherData"]["origin_unix_s"],
+        "valid": not failures,
+        "failures": failures,
+    }
     if args.json:
-        print(json.dumps(rec, indent=1, default=float))
+        print(json.dumps(rec, indent=1))
     else:
-        print_report(rec)
-    if args.require_devices and not rec["device_evidence"]:
-        print("FAIL: no device execution evidence", file=sys.stderr)
-        return 1
-    return 0
+        print(f"fleet trace: {rec['events']} events from "
+              f"{len(rec['sources'])} processes on epoch-zero "
+              f"{rec['origin_unix_s']:.6f}"
+              + (f" -> {args.merged_out}" if args.merged_out else ""))
+        for f in failures:
+            print(f"FAIL: {f}", file=sys.stderr)
+    return 1 if failures else 0
 
 
 if __name__ == "__main__":
