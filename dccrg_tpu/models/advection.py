@@ -810,6 +810,7 @@ class Advection:
             make_fused_run,
             pallas_available,
             pick_step_block,
+            run_ping_pong,
         )
 
         pallas_update = None
@@ -949,10 +950,12 @@ class Advection:
                 return fused_run_fn(
                     (mx3, my3, mzu3, mzd3), state, steps, dt)
 
-        # Blocked multi-step run: the whole fori_loop inside one shard_map
-        # so the constant vz halo stacks are built once per run call, not
+        # Blocked multi-step run: the whole loop inside one shard_map so
+        # the constant vz halo stacks are built once per run call, not
         # once per step (the generic run path re-derives them every
-        # iteration because the step body cannot know vz is loop-invariant)
+        # iteration because the step body cannot know vz is loop-invariant);
+        # two steps per loop iteration (run_ping_pong) so no step copies the
+        # density
         dense_run = None
         if blocked_update is not None:
 
@@ -962,13 +965,12 @@ class Advection:
                 mzd = zf_dn[0][:, None, None]
                 v_lo, v_hi = extend.planes(vz)
 
-                def one(i, r):
+                def one(r):
                     return blocked_step(
                         r, vx, vy, vz, v_lo, v_hi, mzu, mzd, dt
                     )
 
-                out = jax.lax.fori_loop(0, steps, one, rho)
-                return (out[None],)
+                return (run_ping_pong(one, rho, steps)[None],)
 
             run_sm = shard_map(
                 run_body,
@@ -978,18 +980,23 @@ class Advection:
                 check_vma=False,
             )
 
-            def dense_run_fn(zf_up, zf_dn, state, steps, dt):
+            # returns the new density alone: every other field handed back
+            # through the jit would cost a full device copy per call
+            def dense_run_fn(zf_up, zf_dn, rho, vx, vy, vz, steps, dt):
                 (new_rho,) = run_sm(
-                    zf_up, zf_dn,
-                    state["density"], state["vx"], state["vy"], state["vz"],
+                    zf_up, zf_dn, rho, vx, vy, vz,
                     jnp.asarray(dt, dtype), jnp.asarray(steps, jnp.int32),
                 )
-                return {**state, "density": new_rho}
+                return new_rho
 
             dense_run_fn = traced_jit("advection.dense_run", dense_run_fn)
 
             def dense_run(state, steps, dt):
-                return dense_run_fn(zf_up_dev, zf_dn_dev, state, steps, dt)
+                new_rho = dense_run_fn(
+                    zf_up_dev, zf_dn_dev, state["density"], state["vx"],
+                    state["vy"], state["vz"], steps, dt,
+                )
+                return {**state, "density": new_rho}
 
         dx = self._dx
 

@@ -24,6 +24,7 @@ __all__ = [
     "make_flux_update",
     "make_flux_update_blocked_direct",
     "pick_step_block",
+    "run_ping_pong",
     "make_fused_run",
     "fused_run_fits",
 ]
@@ -303,6 +304,26 @@ def make_flux_update_blocked_direct(nzl: int, ny: int, nx: int, block: int,
                     mx, my, mz_up, mz_dn)
 
     return update
+
+
+def run_ping_pong(step, x, steps):
+    """``steps`` successive ``step``s of ``x`` (``steps`` a traced int32),
+    two per loop iteration: an odd count's first step runs in a
+    ``lax.cond``, then ``steps // 2`` iterations each run
+    ``step(step(a))``.
+
+    A kernel cannot write its result into the buffer it is still
+    reading, so one step per iteration (``fori_loop(0, steps, step, x)``)
+    makes XLA copy the whole carry before every step.  With two, the
+    first step writes a loop-local buffer and the second writes back
+    into the carry the first has finished reading: the buffers ping-pong
+    and the loop body holds no copy.  (An explicit ``(a, b)`` carry
+    compiles to the same module; the compiler drops the unread ``b``.)
+    The odd step goes before the loop rather than after it: after it,
+    the TPU compile copies the loop's result twice more on every call to
+    feed the ``cond``."""
+    x = jax.lax.cond(steps % 2 == 1, step, lambda r: r, x)
+    return jax.lax.fori_loop(0, steps // 2, lambda _, a: step(step(a)), x)
 
 
 def fused_run_fits(nzl: int, ny: int, nx: int) -> bool:

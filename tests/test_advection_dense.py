@@ -140,11 +140,13 @@ def test_plane_kernel_interpret():
 
 @pytest.mark.parametrize("periodic", [(True, True, True), (True, True, False)])
 @pytest.mark.parametrize("nz,n_dev", [(32, 1), (32, 4)])
-def test_blocked_kernel_interpret(periodic, nz, n_dev):
+@pytest.mark.parametrize("steps", [0, 1, 2, 5, 6])
+def test_blocked_kernel_interpret(periodic, nz, n_dev, steps):
     """The blocked per-step kernel (multi-plane z-blocks, halo stacks
     spliced in VMEM) matches the XLA dense path — with several blocks per
     device (m>1, interior strided-slice halo rows) and across devices
-    (ppermute-received edge rows)."""
+    (ppermute-received edge rows) — and the two-buffer whole run equals
+    ``steps`` successive steps, even and odd counts alike."""
     from dccrg_tpu.ops.dense_advection import pick_step_block
 
     g, _ = make(nz=nz, periodic=periodic, n_dev=n_dev)
@@ -166,17 +168,36 @@ def test_blocked_kernel_interpret(periodic, nz, n_dev):
         np.asarray(a["density"]), np.asarray(b["density"]), rtol=2e-7, atol=1e-9
     )
 
-    # the hoisted multi-step run matches stepping (called directly: on one
-    # device run() would prefer the whole-block fused kernel)
+    # the hoisted multi-step run is ``steps`` of the same kernel's steps,
+    # bit for bit (called directly: on one device run() would prefer the
+    # whole-block fused kernel), and hands every other field back as given
     import jax.numpy as jnp
 
-    a = pal._dense_run(s0, jnp.asarray(5, jnp.int32), dt)
-    b = s0
-    for _ in range(5):
-        b = xla.step(b, dt)
-    np.testing.assert_allclose(
-        np.asarray(a["density"]), np.asarray(b["density"]), rtol=1e-6, atol=1e-9
+    a = pal._dense_run(s0, jnp.asarray(steps, jnp.int32), dt)
+    b = c = s0
+    for _ in range(steps):
+        b = pal.step(b, dt)
+        c = pal._dense_run(c, jnp.asarray(1, jnp.int32), dt)
+    np.testing.assert_array_equal(
+        np.asarray(a["density"]), np.asarray(c["density"])
     )
+    if n_dev == 1:
+        np.testing.assert_array_equal(
+            np.asarray(a["density"]), np.asarray(b["density"])
+        )
+    else:
+        # across CPU devices XLA contracts step()'s arithmetic in its own
+        # module differently from the run's (~1 ulp, with a one-buffer
+        # fori_loop run as well); on the chip both are the same kernel
+        np.testing.assert_allclose(
+            np.asarray(a["density"]), np.asarray(b["density"]),
+            rtol=2e-7, atol=1e-9,
+        )
+    assert a.keys() == s0.keys()
+    for name in s0:
+        assert (a[name].shape, a[name].dtype) == (s0[name].shape, s0[name].dtype)
+        if name != "density":
+            np.testing.assert_array_equal(np.asarray(a[name]), np.asarray(s0[name]))
 
 
 @pytest.mark.parametrize("periodic", [(True, True, True), (True, True, False)])

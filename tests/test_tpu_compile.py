@@ -12,6 +12,7 @@ The topology is described only inside the module-scoped fixture below
 TPU library, and every xdist worker imports this file.
 """
 import os
+import re
 
 import numpy as np
 import pytest
@@ -94,6 +95,55 @@ def test_blocked_direct_128x512x512(one_chip):
     c, p = s(nz, ny, nx), s(1, ny, nx)
     _compile(upd, c, p, p, c, c, c, p, p, s(1, 1, nx), s(1, ny, 1),
              s(nz, 1, 1), s(nz, 1, 1), s(), kernel="advection_blocked_direct")
+
+
+def _computation(text, name):
+    """The instruction lines of HLO computation ``name`` in ``text``."""
+    lines = text.splitlines()
+    start = next(i for i, line in enumerate(lines)
+                 if re.match(rf"(ENTRY )?%{re.escape(name)} ", line))
+    end = next(i for i in range(start, len(lines)) if lines[i] == "}")
+    return lines[start + 1:end]
+
+
+def test_dense_run_loop_128x512x512(one_chip):
+    """The uniform whole run's loop (``run_ping_pong`` around the blocked
+    kernel, as in Advection's dense run on one chip): the ``while`` body
+    runs the kernel twice and copies no full-size array — one step per
+    iteration copies the density before every step."""
+    from dccrg_tpu.ops.dense_advection import (
+        make_flux_update_blocked_direct,
+        pick_step_block,
+        run_ping_pong,
+    )
+
+    nz, ny, nx = 128, 512, 512
+    block = pick_step_block(nz, ny, nx)
+    upd = make_flux_update_blocked_direct(nz, ny, nx, block, np.ones(3), 1.0)
+
+    def run(rho, vx, vy, vz, mx, my, mzu, mzd, dt, steps):
+        # one device: the halo planes are the block's own wrapped edges
+        v_lo, v_hi = vz[-1:], vz[:1]
+
+        def one(r):
+            return upd(r, r[-1:], r[:1], vx, vy, vz, v_lo, v_hi,
+                       mx, my, mzu, mzd, dt)
+
+        return run_ping_pong(one, rho, steps)
+
+    s = lambda *shape: _spec(one_chip, shape)  # noqa: E731
+    c = s(nz, ny, nx)
+    text = _compile(run, c, c, c, c, s(1, 1, nx), s(1, ny, 1), s(nz, 1, 1),
+                    s(nz, 1, 1), s(), _spec(one_chip, (), jnp.int32),
+                    kernel="advection_blocked_direct").as_text()
+    (body_name,) = re.findall(r" while\(.*?body=%([\w.\-]+)", text)
+    body = _computation(text, body_name)
+    kernels = [ln for ln in body
+               if re.match(r"\s*%advection_blocked_direct\S* = .*custom-call\(", ln)]
+    copies = [ln for ln in body
+              if re.search(rf"= f32\[{nz},{ny},{nx}\]\S* copy\(", ln)]
+    assert len(kernels) == 2
+    assert copies == []
 
 
 def test_plane_flux_update(one_chip):
