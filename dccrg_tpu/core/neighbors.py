@@ -90,7 +90,8 @@ class LeafSet:
 
     def __post_init__(self):
         assert self.cells.dtype == np.uint64
-        assert (np.diff(self.cells) > 0).all(), "cells must be sorted unique"
+        assert (self.cells[1:] > self.cells[:-1]).all(), \
+            "cells must be sorted unique"
         assert len(self.owner) == len(self.cells)
 
     def __len__(self) -> int:

@@ -211,7 +211,8 @@ class Grid:
                 owner = morton_partition(self.mapping, cells, self.n_devices)
             else:
                 owner = block_partition(cells, self.n_devices)
-        self.leaves = LeafSet(cells=cells, owner=owner.astype(np.int32))
+        self.leaves = LeafSet(cells=cells,
+                              owner=owner.astype(np.int32, copy=False))
         self.initialized = True
         if leaf_set is not None:
             # the neighbor engine itself rejects many inconsistent sets

@@ -1312,14 +1312,22 @@ class Advection:
     def _record_run(self, path: str, steps, state) -> None:
         """Post-run reconciliation (obs.fused): the whole-run paths keep
         their ghost traffic inside jit, so the host seam sees nothing —
-        record ``steps x schedule bytes`` once per dispatch instead.  The
-        bytes per step are computed once per halo schedule (one per
-        epoch) and density dtype and shape, not on every call."""
+        record ``steps x bytes per step`` once per dispatch instead.  On
+        the dense path a step sends one z-plane of density each way
+        around the slab ring (none on one device); elsewhere the bytes
+        per step are the halo schedule's, computed once per schedule
+        (one per epoch) and density dtype and shape, not on every call."""
         from ..obs import fused
 
         if not self.grid.telemetry.enabled:
             return
         rho = state["density"]
+        if self.dense is not None:
+            i = self.dense
+            planes = 2 * i.n_devices if i.n_devices > 1 else 0
+            bps = planes * i.ny * i.nx * np.dtype(rho.dtype).itemsize
+            fused.record_run("advection", path, steps, bps)
+            return
         try:
             ex = self.grid.halo(None)
             key = (ex, rho.dtype, rho.shape)

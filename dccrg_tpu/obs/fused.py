@@ -9,9 +9,11 @@ call of
 
 * ``fused.runs{model,path}``   — dispatches of a whole-run kernel,
 * ``fused.steps{model,path}``  — device-side steps those dispatches ran,
-* ``fused.halo_bytes_equiv{model,path}`` — ``steps x schedule bytes``,
-  the ghost payload the host seam WOULD have moved for the same steps
-  (0 on a single device, where the schedule really ships nothing).
+* ``fused.halo_bytes_equiv{model,path}`` — ``steps x bytes per step``,
+  the ghost payload the run ships: the halo schedule's bytes, which the
+  host seam WOULD have moved for the same steps, or on the dense slab
+  layout its two z-planes per device (0 on a single device, where
+  nothing is shipped).
 
 ``halo.bytes_moved`` (host seam) + ``fused.halo_bytes_equiv`` together
 account for every step's ghost traffic, whichever path ran.

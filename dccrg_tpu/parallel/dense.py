@@ -41,8 +41,8 @@ def detect_dense(mapping, topology, leaves, n_devices: int) -> DenseInfo | None:
     if nz % n_devices != 0:
         return None
     per = len(leaves) // n_devices
-    expected = np.repeat(np.arange(n_devices, dtype=np.int32), per)
-    if not np.array_equal(leaves.owner, expected):
+    if not all((leaves.owner[d * per:(d + 1) * per] == d).all()
+               for d in range(n_devices)):
         return None
     # leaves must be exactly the level-0 cells 1..n in order
     if leaves.cells[0] != 1 or leaves.cells[-1] != nx * ny * nz:

@@ -12,6 +12,12 @@ ascending id order; rows ``[n_local, n_local + n_ghost)`` hold ghost copies
 of remote neighbors in ascending id order; row ``R - 1`` is a scratch row
 that absorbs padded gathers/scatters.  ``R`` is uniform across devices so
 payloads live as dense ``[D, R, ...]`` arrays sharded over the mesh.
+
+The row layout and the per-hood tables are the per-cell tables: O(N·K) host
+time and ~300 B of host memory per cell.  A snapshot the dense fast path
+takes (``parallel/dense.py``) needs none of them to run, so its epoch builds
+them on first read, with the same code and the same result as an eager
+build.
 """
 from __future__ import annotations
 
@@ -26,6 +32,12 @@ from .dense import detect_dense
 from .shapes import bucket_k, bucket_rows
 
 __all__ = ["HoodState", "Epoch", "build_epoch"]
+
+#: the per-cell tables: the row layout and the per-hood state
+_TABLE_FIELDS = frozenset((
+    "R", "n_local", "n_ghost", "local_pos", "ghost_pos", "row_of",
+    "cell_len", "cell_level", "cell_ids", "local_mask", "hoods",
+))
 
 
 @dataclass
@@ -71,6 +83,29 @@ class Epoch:
     hoods: dict = field(default_factory=dict)   # hood id (None = default) -> HoodState
     #: set when the grid qualifies for the dense uniform fast path
     dense = None
+
+    @classmethod
+    def deferred(cls, mapping, topology, leaves, n_devices, build) -> "Epoch":
+        """An epoch without its per-cell tables: the first read of one
+        calls ``build()`` (an epoch of the same snapshot, tables built)
+        and keeps its tables, each field not assigned before."""
+        epoch = cls.__new__(cls)
+        epoch.mapping, epoch.topology = mapping, topology
+        epoch.leaves, epoch.n_devices = leaves, n_devices
+        epoch._build_tables = build
+        return epoch
+
+    def __getattr__(self, name):
+        # reached only for an attribute the instance lacks: on a deferred
+        # epoch, a table field not built yet
+        build = self.__dict__.get("_build_tables")
+        if build is None or name not in _TABLE_FIELDS:
+            raise AttributeError(name)
+        built = build()
+        for f in _TABLE_FIELDS:
+            self.__dict__.setdefault(f, getattr(built, f))
+        self.__dict__.pop("_build_tables", None)
+        return self.__dict__[name]
 
     # ------------------------------------------------------------- lookups
 
@@ -181,11 +216,19 @@ def build_epoch(
     by shape survive the rebuild.  Builds handed no hints produce the
     deterministic natural buckets.
 
+    A snapshot the dense fast path takes (``detect_dense`` accepts it
+    and ``uniform_geometry`` holds) gets a deferred epoch: its per-cell
+    tables are built on first read (``Epoch.deferred``), by the same
+    code as an eager build.
+
     Telemetry: the whole build is the ``epoch.build`` phase, holding
-    ``epoch.hood_build`` (per-hood neighbor searches),
-    ``epoch.row_layout``, ``epoch.finish_hood`` (per-hood device tables
-    and schedules) and ``epoch.detect_dense``; the resulting table
-    shapes land as ``epoch.*`` gauges.
+    ``epoch.detect_dense`` and, where the tables are built at once,
+    ``epoch.tables``.  ``epoch.tables`` (eager or deferred, once per
+    built epoch) holds ``epoch.hood_build`` (per-hood neighbor
+    searches), ``epoch.row_layout`` and ``epoch.finish_hood`` (per-hood
+    device tables and schedules), and sets the table-shape ``epoch.*``
+    gauges; ``epoch.tables_deferred`` counts the epochs built without
+    their tables.
     """
     from ..obs import metrics
 
@@ -196,18 +239,6 @@ def build_epoch(
         )
     if metrics.enabled:
         metrics.gauge("epoch.n_cells", len(epoch.leaves))
-        metrics.gauge("epoch.rows_per_device", epoch.R)
-        metrics.gauge("epoch.bucket_R", epoch.R)
-        for hid, h in epoch.hoods.items():
-            metrics.gauge("epoch.bucket_K", h.nbr_rows.shape[2],
-                          hood="default" if hid is None else str(hid))
-        metrics.gauge("epoch.ghost_cells", int(epoch.n_ghost.sum()))
-        metrics.gauge("epoch.hoods", len(epoch.hoods))
-        # send/recv schedule size: cells exchanged per full halo update,
-        # summed over hoods (each pair table is symmetric by construction)
-        metrics.gauge("epoch.send_table_cells", sum(
-            int(h.pair_counts.sum()) for h in epoch.hoods.values()
-        ))
         # per-device allocator state right after the re-layout — the
         # moment OOM margins change (no-op on statless backends)
         from ..obs import sample_hbm
@@ -228,50 +259,93 @@ def _build_epoch_impl(
 ) -> Epoch:
     from ..obs import metrics
 
-    hints = shape_hints or {}
-
-    N = len(leaves)
-    D = n_devices
-    owner = leaves.owner.astype(np.int64)
-
-    # --- pass 1: neighbor lists + ghost requirements per hood
-    hood_raw = {}
-    all_pairs = []
-    for hid, offsets in neighborhoods.items():
-        with metrics.phase("epoch.hood_build"):
-            lists, to_start, to_src, pairs, is_outer = _build_hood(
-                mapping, topology, leaves, offsets, D
-            )
-        hood_raw[hid] = (offsets, lists, to_start, to_src, pairs, is_outer)
-        all_pairs.append(pairs)
-    if all_pairs:
-        from ..utils.setops import unique_pairs
-
-        cat = np.concatenate(all_pairs, axis=0)
-        dev_u, pos_u = unique_pairs(cat[:, 0], cat[:, 1], max(N, 1))
-        pairs = np.stack([dev_u, pos_u], axis=1)
-    else:
-        pairs = np.zeros((0, 2), dtype=np.int64)
-
-    # --- row layout
-    with metrics.phase("epoch.row_layout"):
-        epoch, len_all = _row_layout(mapping, topology, leaves, D, pairs,
-                                     prev_R=hints.get("R"))
-
-    # --- pass 2: per-hood device tables + schedules
-    for hid, (offsets, lists, to_start, to_src, h_pairs, is_outer) in (
-        hood_raw.items()
-    ):
-        with metrics.phase("epoch.finish_hood"):
-            epoch.hoods[hid] = _finish_hood(
-                epoch, offsets, lists, to_start, to_src, h_pairs, len_all,
-                is_outer, prev_K=hints.get("K", {}).get(hid),
-            )
     with metrics.phase("epoch.detect_dense"):
-        epoch.dense = (
-            detect_dense(mapping, topology, leaves, D)
+        dense = (
+            detect_dense(mapping, topology, leaves, n_devices)
             if uniform_geometry else None
         )
+
+    # the hoods as they are now: the grid's dict changes with its hoods
+    hoods = dict(neighborhoods)
+
+    def build():
+        return _build_tables(mapping, topology, leaves, n_devices, hoods,
+                             shape_hints)
+
+    if dense is None:
+        epoch = build()
+    else:
+        epoch = Epoch.deferred(mapping, topology, leaves, n_devices, build)
+        metrics.inc("epoch.tables_deferred")
+    epoch.dense = dense
+    return epoch
+
+
+def _build_tables(
+    mapping: Mapping,
+    topology: Topology,
+    leaves: LeafSet,
+    n_devices: int,
+    neighborhoods: dict,
+    shape_hints: dict | None,
+) -> Epoch:
+    """The per-cell tables of a snapshot, as an epoch with ``dense``
+    unset: the ``epoch.tables`` phase, then the table-shape ``epoch.*``
+    gauges."""
+    from ..obs import metrics
+
+    hints = shape_hints or {}
+    N = len(leaves)
+    D = n_devices
+
+    with metrics.phase("epoch.tables"):
+        # --- pass 1: neighbor lists + ghost requirements per hood
+        hood_raw = {}
+        all_pairs = []
+        for hid, offsets in neighborhoods.items():
+            with metrics.phase("epoch.hood_build"):
+                lists, to_start, to_src, pairs, is_outer = _build_hood(
+                    mapping, topology, leaves, offsets, D
+                )
+            hood_raw[hid] = (offsets, lists, to_start, to_src, pairs,
+                             is_outer)
+            all_pairs.append(pairs)
+        if all_pairs:
+            from ..utils.setops import unique_pairs
+
+            cat = np.concatenate(all_pairs, axis=0)
+            dev_u, pos_u = unique_pairs(cat[:, 0], cat[:, 1], max(N, 1))
+            pairs = np.stack([dev_u, pos_u], axis=1)
+        else:
+            pairs = np.zeros((0, 2), dtype=np.int64)
+
+        # --- row layout
+        with metrics.phase("epoch.row_layout"):
+            epoch, len_all = _row_layout(mapping, topology, leaves, D, pairs,
+                                         prev_R=hints.get("R"))
+
+        # --- pass 2: per-hood device tables + schedules
+        for hid, (offsets, lists, to_start, to_src, h_pairs, is_outer) in (
+            hood_raw.items()
+        ):
+            with metrics.phase("epoch.finish_hood"):
+                epoch.hoods[hid] = _finish_hood(
+                    epoch, offsets, lists, to_start, to_src, h_pairs,
+                    len_all, is_outer, prev_K=hints.get("K", {}).get(hid),
+                )
+    if metrics.enabled:
+        metrics.gauge("epoch.rows_per_device", epoch.R)
+        metrics.gauge("epoch.bucket_R", epoch.R)
+        for hid, h in epoch.hoods.items():
+            metrics.gauge("epoch.bucket_K", h.nbr_rows.shape[2],
+                          hood="default" if hid is None else str(hid))
+        metrics.gauge("epoch.ghost_cells", int(epoch.n_ghost.sum()))
+        metrics.gauge("epoch.hoods", len(epoch.hoods))
+        # send/recv schedule size: cells exchanged per full halo update,
+        # summed over hoods (each pair table is symmetric by construction)
+        metrics.gauge("epoch.send_table_cells", sum(
+            int(h.pair_counts.sum()) for h in epoch.hoods.values()
+        ))
     return epoch
 
 

@@ -18,6 +18,14 @@ __all__ = [
 ]
 
 
+def _equal_counts(n: int, n_parts: int) -> np.ndarray:
+    """Cells per part of ``n`` cells striped into ``n_parts`` equal
+    blocks, the first ``n % n_parts`` one cell larger."""
+    counts = np.full(n_parts, n // n_parts, dtype=np.int64)
+    counts[: n % n_parts] += 1
+    return counts
+
+
 def weighted_blocks(
     order: np.ndarray,
     weights: np.ndarray | None,
@@ -46,9 +54,7 @@ def weighted_blocks(
     owner = np.empty(n, dtype=np.int32)
     if weights is None:
         # equal-count striping like the reference's block assignment
-        counts = np.full(n_parts, n // n_parts, dtype=np.int64)
-        counts[: n % n_parts] += 1
-        bounds = np.concatenate([[0], np.cumsum(counts)])
+        bounds = np.concatenate([[0], np.cumsum(_equal_counts(n, n_parts))])
         for p in range(n_parts):
             owner[order[bounds[p] : bounds[p + 1]]] = p
         return owner
@@ -125,6 +131,10 @@ def _min_max_load_blocks(cum: np.ndarray, w: np.ndarray, n_parts: int) -> np.nda
 def block_partition(cells: np.ndarray, n_parts: int, weights=None, imbalance_tol=None) -> np.ndarray:
     """Contiguous id-order striping (the reference's default initial
     assignment)."""
+    if weights is None:
+        # id order is the cells' own order: the blocks are runs
+        return np.repeat(np.arange(n_parts, dtype=np.int32),
+                         _equal_counts(len(cells), n_parts))
     return weighted_blocks(np.arange(len(cells)), weights, n_parts, imbalance_tol)
 
 

@@ -47,7 +47,7 @@ def _refined(n=8):
 
 GRID_SPANS = ("grid.initialize", "grid.partition", "epoch.build",
               "epoch.hood_build", "epoch.row_layout", "epoch.finish_hood",
-              "epoch.detect_dense")
+              "epoch.detect_dense", "epoch.tables")
 #: the set-up spans each path's build leaves in the registry
 SETUP_SPANS = {
     "uniform": GRID_SPANS + ("advection.init", "advection.init.dense",
@@ -70,13 +70,15 @@ BUILD = {
 @pytest.fixture(scope="module")
 def setup_phases():
     """{path: the registry's phase table right after that path's grid,
-    model and initial state were built}."""
+    model and initial state were built, and the epoch's tables read once
+    (the dense path's epoch builds them on first read)}."""
     out = {}
     for path, (make, kw) in BUILD.items():
         obs.enable()
         obs.metrics.reset()
         adv = Advection(make(), dtype=np.float32, **kw)
         adv.initialize_state()
+        assert adv.grid.epoch.R > 0
         out[path] = obs.metrics.report()["phases"]
     return out
 

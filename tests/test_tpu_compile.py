@@ -253,3 +253,66 @@ def test_halo_dma_ring_4_chips(topo):
     x = jax.ShapeDtypeStruct((D, 1024, 128), jnp.float32,
                              sharding=NamedSharding(mesh, spec))
     _compile(fn, x)
+
+
+def test_dense_run_4_chips_512_slabs(topo):
+    """The four-chip uniform cell's whole run: a 512x512x2048 grid as four
+    z-slabs of 512^3, Advection's dense run body (``run_ping_pong`` around
+    the blocked kernel, each slab's edge planes ppermuted around the slab
+    ring by ``HaloExtend``) on the 2x2 v5e mesh.  Each chip's share fits
+    its 16 GB, the halo goes by collective-permute, and the ``while``
+    body runs the kernel twice and copies no full-size slab."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from dccrg_tpu.ops.dense_advection import (
+        make_flux_update_blocked_direct,
+        pick_step_block,
+        run_ping_pong,
+    )
+    from dccrg_tpu.parallel.dense import DenseInfo, HaloExtend
+    from dccrg_tpu.parallel.mesh import SHARD_AXIS
+
+    D, nzl, ny, nx = 4, 512, 512, 512
+    extend = HaloExtend(DenseInfo(nx=nx, ny=ny, nz=D * nzl, nz_local=nzl,
+                                  n_devices=D, periodic=(True,) * 3))
+    block = pick_step_block(nzl, ny, nx)
+    assert block >= 2
+    upd = make_flux_update_blocked_direct(nzl, ny, nx, block, np.ones(3), 1.0)
+
+    def body(zf_up, zf_dn, rho, vx, vy, vz, mx, my, dt, steps):
+        rho, vx, vy, vz = rho[0], vx[0], vy[0], vz[0]
+        mzu, mzd = zf_up[0][:, None, None], zf_dn[0][:, None, None]
+        v_lo, v_hi = extend.planes(vz)
+
+        def one(r):
+            r_lo, r_hi = extend.planes(r)
+            return upd(r, r_lo, r_hi, vx, vy, vz, v_lo, v_hi, mx, my,
+                       mzu, mzd, dt)
+
+        return run_ping_pong(one, rho, steps)[None]
+
+    mesh = Mesh(np.array(topo.devices[:D]), (SHARD_AXIS,))
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(SHARD_AXIS),) * 6 + (P(),) * 4,
+                       out_specs=P(SHARD_AXIS), check_vma=False)
+    slab = NamedSharding(mesh, P(SHARD_AXIS))
+    every = NamedSharding(mesh, P())
+    c, z = _spec(slab, (D, nzl, ny, nx)), _spec(slab, (D, nzl))
+    compiled = _compile(fn, z, z, c, c, c, c, _spec(every, (1, 1, nx)),
+                        _spec(every, (1, ny, 1)), _spec(every, ()),
+                        _spec(every, (), jnp.int32),
+                        kernel="advection_blocked_direct")
+    mem = compiled.memory_analysis()
+    per_chip = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                + mem.temp_size_in_bytes)
+    assert per_chip <= 16e9
+    text = compiled.as_text()
+    assert "collective-permute" in text
+    (body_name,) = re.findall(r" while\(.*?body=%([\w.\-]+)", text)
+    loop = _computation(text, body_name)
+    kernels = [ln for ln in loop
+               if re.match(r"\s*%advection_blocked_direct\S* = .*custom-call\(", ln)]
+    copies = [ln for ln in loop
+              if re.search(rf"= f32\[{nzl},{ny},{nx}\]\S* copy\(", ln)]
+    assert len(kernels) == 2
+    assert copies == []

@@ -232,6 +232,7 @@ DEFAULT_ALLOW = (
     # metrics), not gated
     "grid.initialize",
     "grid.partition",
+    "epoch.tables",
     "epoch.row_layout",
     "epoch.finish_hood",
     "epoch.detect_dense",
