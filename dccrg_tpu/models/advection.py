@@ -708,10 +708,14 @@ class Advection:
         dense pass per level per step, z-slab sharded over the device mesh
         with circular ppermute plane rings.  See
         ``models/boxed_advection.py`` for the full scheme and the
-        multi-device correctness argument."""
+        multi-device correctness argument.  Sets ``_boxed_moved``, per
+        level whether the Pallas moves carry it; ``_record_run`` counts
+        each boxed dispatch as ``boxed.kernel_runs{form=pallas}`` where
+        any level's do, else ``{form=xla}``."""
         from .boxed_advection import build_boxed_run
 
-        return build_boxed_run(self, layout)
+        run, self._boxed_moved = build_boxed_run(self, layout)
+        return run
 
     # ------------------------------------------------------ dense fast path
 
@@ -1337,6 +1341,9 @@ class Advection:
         except Exception:  # noqa: BLE001 — telemetry must never raise
             self._bps_key, self._bps = None, 0
         fused.record_run("advection", path, steps, self._bps)
+        if path == "boxed":
+            metrics.inc("boxed.kernel_runs",
+                        form="pallas" if any(self._boxed_moved) else "xla")
 
     def run(self, state, steps: int, dt):
         """Advance ``steps`` timesteps in a single device-side loop

@@ -49,6 +49,16 @@ selections are computed once at run start; the loop body touches only
 density.  Produces the same update as the general gather path
 (solve.hpp:129-260 semantics) with a different — but fixed —
 floating-point association order.
+
+The run takes and returns the state as epoch rows; each call moves every
+level's density and velocities into its box and the density back.  Where
+``use_pallas`` is set, the dtype is float32 and Pallas runs (on a TPU, or
+``use_pallas="interpret"``), ``boxed_move_gather`` / ``boxed_move_scatter``
+(``ops/boxed_moves.py``) move each level whose leaves are one ascending
+range of rows, by runs of leaves; other levels, and every level in f64,
+on the CPU or with ``use_pallas=False``, move by per-element gather and
+scatter.  The moves only move values, so both give the same density bit
+for bit.
 """
 from __future__ import annotations
 
@@ -58,10 +68,22 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from ..ops.boxed_moves import (
+    box_runs,
+    make_box_gather,
+    make_box_scatter,
+    moves_fit,
+    rows_of,
+)
+from ..ops.dense_advection import pallas_available
 from ..parallel.exec_cache import traced_jit
 from ..parallel.mesh import SHARD_AXIS, put_table, shard_spec
 
 __all__ = ["build_boxed_run"]
+
+
+def _round_up(n, unit):
+    return -(-n // unit) * unit
 
 
 def _clip(v, lo, hi):
@@ -94,9 +116,48 @@ def _route_segments(g, gm, n_valid):
     return segs
 
 
+def _level_moves(leaf_s, rows_s, R, interpret):
+    """The Pallas moves of one level between the epoch rows and its box
+    (``ops/boxed_moves.py``): ``(consts, statics)``, or None where a
+    device's leaves are not one ascending range of rows in box order, a
+    box row holds too many runs, or the range does not fit VMEM (the
+    per-element gather and scatter move that level)."""
+    D, nzl, by, bx = leaf_s.shape
+    spans = []
+    for k in range(D):
+        r = rows_s[k][leaf_s[k]]
+        if len(r) and not (np.diff(r) == 1).all():
+            return None
+        spans.append((int(r[0]) if len(r) else 0, len(r)))
+    ny, nx = _round_up(by, 8), _round_up(bx, 128)
+    runs = [box_runs(leaf_s[k], ny, nx) for k in range(D)]
+    if any(t is None for t in runs):
+        return None
+    K = max(t[1] for t in runs)
+    if any(t[1] != K for t in runs):
+        runs = [box_runs(leaf_s[k], ny, nx, K) for k in range(D)]
+    n = max(1, max(c for _, c in spans))
+    n_rows = -(-(nx + n + nx) // 128) + 1     # front zeros, range, one window
+    if not moves_fit(n_rows, ny, nx):
+        return None
+    consts = dict(
+        n=n, front=nx, n_rows=n_rows, plane_pad=((0, 0), (0, ny - by),
+                                                 (0, nx - bx)),
+        # rows past the flat's end that a device's window may reach
+        tail=max(0, max(r0 for r0, _ in spans) + n - R),
+        ragged=any(c != n for _, c in spans),
+        gather=make_box_gather(nzl, ny, nx, K, n_rows, interpret=interpret),
+        scatter=make_box_scatter(nzl, ny, nx, K, n_rows, interpret=interpret),
+    )
+    statics = dict(mv_tab=np.stack([t[0] for t in runs]),
+                   mv_span=np.array(spans, dtype=np.int32))
+    return consts, statics
+
+
 def build_boxed_run(adv, layout):
-    """Build the jitted ``run(state, steps, dt) -> state`` for ``adv`` (an
-    ``Advection`` model) over ``layout`` (a ``BoxedLayout``)."""
+    """Build ``(run, moved)`` for ``adv`` (an ``Advection`` model) over
+    ``layout`` (a ``BoxedLayout``): the jitted ``run(state, steps, dt) ->
+    state`` and, per level, whether the Pallas moves carry it."""
     dtype = adv.dtype
     grid = adv.grid
     mapping = grid.mapping
@@ -110,6 +171,12 @@ def build_boxed_run(adv, layout):
     lvl_index = {b.level: i for i, b in enumerate(boxes)}
     pair_of_fine = {pr.fine_level: pr for pr in layout.pairs}
     L = len(boxes)
+    interpret = adv.use_pallas == "interpret"
+    pallas = (
+        bool(adv.use_pallas) and np.dtype(dtype) == np.float32
+        and (interpret or pallas_available(dtype))
+    )
+    moves = []       # per level: the Pallas moves' consts, or None
 
     # ---------------------------------------------- per-level static tables
     consts = []      # python-side metadata per level
@@ -235,21 +302,29 @@ def build_boxed_run(adv, layout):
 
         rows_s = slab_int(b.rows.reshape(bz, by, bx))
         leaf_s = slab_int(b.leaf_mask)
-
-        # final scatter tables: per device, flat slab positions of its
-        # leaves and their local epoch rows (padded to a common length;
-        # pads write into the scratch row)
-        flats, rowss = [], []
-        for k in range(D):
-            fl = np.flatnonzero(leaf_s[k].ravel())
-            flats.append(fl)
-            rowss.append(rows_s[k].ravel()[fl])
-        M = max((len(f) for f in flats), default=0) or 1
-        leaf_flat_s = np.zeros((D, M), dtype=np.int32)
-        leaf_rows_s = np.full((D, M), scratch, dtype=np.int32)
-        for k in range(D):
-            leaf_flat_s[k, : len(flats[k])] = flats[k]
-            leaf_rows_s[k, : len(rowss[k])] = rowss[k]
+        mv = _level_moves(leaf_s, rows_s, grid.epoch.R, interpret) \
+            if pallas else None
+        if mv is not None:
+            moves.append(mv[0])
+            move_tables = mv[1]
+        else:
+            # per-element moves: per device, the flat slab positions of
+            # its leaves and their local epoch rows (padded to a common
+            # length; pads write into the scratch row)
+            moves.append(None)
+            flats, rowss = [], []
+            for k in range(D):
+                fl = np.flatnonzero(leaf_s[k].ravel())
+                flats.append(fl)
+                rowss.append(rows_s[k].ravel()[fl])
+            M = max((len(f) for f in flats), default=0) or 1
+            leaf_flat_s = np.zeros((D, M), dtype=np.int32)
+            leaf_rows_s = np.full((D, M), scratch, dtype=np.int32)
+            for k in range(D):
+                leaf_flat_s[k, : len(flats[k])] = flats[k]
+                leaf_rows_s[k, : len(rowss[k])] = rowss[k]
+            move_tables = dict(rows=rows_s.astype(np.int32),
+                               leaf_flat=leaf_flat_s, leaf_rows=leaf_rows_s)
 
         area = np.array(
             [
@@ -267,7 +342,6 @@ def build_boxed_run(adv, layout):
         )
         statics.append(
             dict(
-                rows=rows_s.astype(np.int32),
                 leaf=leaf_s,
                 use_rho=use_rho_s,
                 m_same=m_same_s,
@@ -275,8 +349,7 @@ def build_boxed_run(adv, layout):
                 m_highf=m_highf_s,
                 any_face=any_face_s,
                 pool_mask=~use_rho_s,
-                leaf_flat=leaf_flat_s,
-                leaf_rows=leaf_rows_s,
+                **move_tables,
             )
         )
 
@@ -442,8 +515,34 @@ def build_boxed_run(adv, layout):
         C = [{k: v[0] for k, v in s.items()} for s in st]  # strip dev axis
 
         def to_slab(flat, li):
-            vals = flat[C[li]["rows"]]
-            return jnp.where(C[li]["leaf"], vals, 0)
+            mv = moves[li]
+            if mv is None:
+                vals = flat[C[li]["rows"]]
+                return jnp.where(C[li]["leaf"], vals, 0)
+            bz_, by_, bx_ = C[li]["leaf"].shape
+            compact = jax.lax.dynamic_slice_in_dim(
+                jnp.pad(flat, (0, mv["tail"])), C[li]["mv_span"][0], mv["n"]
+            )
+            box = mv["gather"](C[li]["mv_tab"],
+                               rows_of(compact, mv["front"], mv["n_rows"]))
+            return box[:, :by_, :bx_]
+
+        def to_rows(out, li, rho):
+            mv = moves[li]
+            if mv is None:
+                return out.at[C[li]["leaf_rows"]].set(
+                    rho.reshape(-1)[C[li]["leaf_flat"]]
+                )
+            r0, cnt = C[li]["mv_span"][0], C[li]["mv_span"][1]
+            rows = mv["scatter"](C[li]["mv_tab"], jnp.pad(rho, mv["plane_pad"]))
+            new = rows.reshape(-1)[mv["front"]:mv["front"] + mv["n"]]
+            out_p = jnp.pad(out, (0, mv["tail"]))
+            if mv["ragged"]:
+                old = jax.lax.dynamic_slice_in_dim(out_p, r0, mv["n"])
+                pos = jnp.arange(mv["n"], dtype=jnp.int32)
+                new = jnp.where(pos < cnt, new, old)
+            out_p = jax.lax.dynamic_update_slice_in_dim(out_p, new, r0, 0)
+            return out_p[:out.shape[0]]
 
         rhos = tuple(to_slab(rho_flat, li) for li in range(L))
         vels = [tuple(to_slab(v, li) for v in v_flat) for li in range(L)]
@@ -517,9 +616,7 @@ def build_boxed_run(adv, layout):
         rhos = jax.lax.fori_loop(0, steps, step, rhos)
         out = rho_flat
         for li in range(L):
-            out = out.at[C[li]["leaf_rows"]].set(
-                rhos[li].reshape(-1)[C[li]["leaf_flat"]]
-            )
+            out = to_rows(out, li, rhos[li])
         return out[None]
 
     statics_dev = [
@@ -537,6 +634,8 @@ def build_boxed_run(adv, layout):
         in_specs=(data_spec, data_spec, data_spec, data_spec, P(), P(),
                   st_specs),
         out_specs=data_spec,
+        # pallas_call outputs carry no varying axes
+        check_vma=not any(mv is not None for mv in moves),
     )
 
     # the boxed tables ride into the jit as a RUNTIME argument pytree
@@ -559,4 +658,4 @@ def build_boxed_run(adv, layout):
     def run(state, steps, dt):
         return run_impl(statics_dev, state, steps, dt)
 
-    return run
+    return run, tuple(mv is not None for mv in moves)

@@ -157,6 +157,29 @@ def test_plane_flux_update(one_chip):
              s(nz, 1, 1), s(), kernel="advection_plane")
 
 
+@pytest.mark.parametrize("box, runs", [((116, 116, 116), 1), ((96, 96, 96), 2)])
+def test_boxed_moves_refined_levels(one_chip, box, runs):
+    """The boxed path's moves between epoch rows and a level's box at the
+    refined benchmark grid's two levels: the ball's 116^3 level-1 box
+    (one run of leaves a row, ~0.8 M leaves) and the 96^3 level-0 box
+    around the ball's hole (two runs a row), planes padded to 8 x 128."""
+    from dccrg_tpu.ops.boxed_moves import (
+        make_box_gather,
+        make_box_scatter,
+        moves_fit,
+    )
+
+    nz, by, bx = box
+    ny, nx = -(-by // 8) * 8, -(-bx // 128) * 128
+    n_rows = -(-(nx + 800_992 + nx) // 128) + 1
+    assert moves_fit(n_rows, ny, nx)
+    tab = _spec(one_chip, (nz, 1, ny * runs * 4), jnp.int32)
+    _compile(make_box_gather(nz, ny, nx, runs, n_rows), tab,
+             _spec(one_chip, (n_rows, 128)), kernel="boxed_move_gather")
+    _compile(make_box_scatter(nz, ny, nx, runs, n_rows), tab,
+             _spec(one_chip, (nz, ny, nx)), kernel="boxed_move_scatter")
+
+
 def test_flat_amr_refined(one_chip):
     """bench.py's 48^3 ball-refined grid: a 96^3 fine-voxel layout, the
     x extent lane-padded to 128 (it fits VMEM).  The unpadded form takes
