@@ -35,25 +35,17 @@ import time
 
 import numpy as np
 
-from bench import (
-    GOL_N,
-    LARGE,
-    NX,
-    NY,
-    NZ,
-    PIC_GRID,
-    PIC_N,
-    POISSON_N,
-    REFINED_N,
-    VLASOV_N,
-    VLASOV_NV,
-    ball_refined_grid,
-    gol_grid,
-    refined_grid,
-    uniform_grid,
-)
-
-FUSED = (NX, NY, NZ)
+#: uniform grid of the fused whole-run kernel (fits VMEM)
+FUSED = (128, 128, 64)
+#: the streaming regime: f32 density alone is 128 MiB, past VMEM
+LARGE = (512, 512, 128)
+REFINED_N = 48          # 48^3 level-0, ball refined -> ~198k cells, 2 levels
+POISSON_N = 32          # 32^3 level-0, centered ball r 0.25 refined once
+GOL_N = 500             # the reference example's board (game_of_life.cpp)
+VLASOV_N = 32           # spatial grid (BASELINE.md config 5)
+VLASOV_NV = 8           # velocity bins per dimension (nv^3 per cell)
+PIC_N = 1_000_000       # particles (BASELINE.md config 4)
+PIC_GRID = 32           # uniform PIC grid edge
 #: relative tolerance of each advection comparison (f32, same scheme)
 ADV_TOL = 1e-5
 F32_EPS = float(np.finfo(np.float32).eps)
@@ -66,6 +58,110 @@ class SmokeFailure(RuntimeError):
 def _check(cond: bool, what: str) -> None:
     if not cond:
         raise SmokeFailure(what)
+
+
+# ------------------------------------------------------------------ grids
+
+
+def uniform_grid(shape, n_devices=None):
+    from dccrg_tpu import CartesianGeometry, Grid, make_mesh
+
+    nx, ny, nz = shape
+    return (
+        Grid()
+        .set_initial_length((nx, ny, nz))
+        .set_neighborhood_length(0)
+        .set_periodic(True, True, True)
+        .set_geometry(
+            CartesianGeometry,
+            start=(0.0, 0.0, 0.0),
+            level_0_cell_length=(1.0 / nx, 1.0 / ny, 1.0 / nz),
+        )
+        .initialize(mesh=make_mesh(n_devices=n_devices))
+    )
+
+
+def ball_refined_grid(n: int, radii: tuple, max_ref: int,
+                      center=(0.5, 0.5, 0.5), n_devices=None):
+    """Periodic n^3 grid with a ball around ``center`` refined once per
+    radius (the refined advection and the Poisson grids)."""
+    from dccrg_tpu import CartesianGeometry, Grid, make_mesh
+
+    g = (
+        Grid()
+        .set_initial_length((n, n, n))
+        .set_neighborhood_length(0)
+        .set_periodic(True, True, True)
+        .set_maximum_refinement_level(max_ref)
+        .set_geometry(
+            CartesianGeometry,
+            start=(0.0, 0.0, 0.0),
+            level_0_cell_length=(1.0 / n,) * 3,
+        )
+        .initialize(mesh=make_mesh(n_devices=n_devices))
+    )
+    for rad in radii:
+        ids = g.get_cells()
+        c = g.geometry.get_center(ids)
+        r = np.linalg.norm(c - np.asarray(center), axis=1)
+        lv = g.mapping.get_refinement_level(ids)
+        for cid in ids[(r < rad) & (lv == lv.max())]:
+            g.refine_completely(int(cid))
+        g.stop_refining()
+    return g
+
+
+def refined_grid(n: int, n_devices=None):
+    """The refined configuration: the ball of radius 0.3 around
+    (0.3, 0.5, 0.5) of an n^3 periodic grid refined once."""
+    return ball_refined_grid(n, (0.3,), 1, center=(0.3, 0.5, 0.5),
+                             n_devices=n_devices)
+
+
+def gol_grid(n: int, n_devices=None):
+    """The reference example's n x n board with the length-1 vertex
+    neighborhood (examples/game_of_life.cpp)."""
+    from dccrg_tpu import Grid, make_mesh
+
+    return (
+        Grid()
+        .set_initial_length((n, n, 1))
+        .set_neighborhood_length(1)
+        .initialize(mesh=make_mesh(n_devices=n_devices))
+    )
+
+
+def pic_setup(n_particles: int, length: int):
+    """Periodic ``length``^3 grid on one device, uniformly random
+    particles, capacity from the actual max occupancy (doubled for drift
+    during the run), and the rotating velocity field of the reference's
+    particle test.  Returns ``(particles_model, points, velocity)``."""
+    from dccrg_tpu import CartesianGeometry, Grid, make_mesh
+    from dccrg_tpu.models.particles import Particles
+
+    g = (
+        Grid()
+        .set_initial_length((length, length, length))
+        .set_neighborhood_length(1)
+        .set_periodic(True, True, True)
+        .set_maximum_refinement_level(0)
+        .set_load_balancing_method("RCB")
+        .set_geometry(
+            CartesianGeometry,
+            start=(0.0, 0.0, 0.0),
+            level_0_cell_length=(1.0 / length,) * 3,
+        )
+        .initialize(mesh=make_mesh(n_devices=1))
+    )
+    pts = np.random.default_rng(0).uniform(0.0, 1.0, size=(n_particles, 3))
+    occ = np.bincount(g.leaves.position(g.get_existing_cell(pts)))
+    pc = Particles(g, max_particles_per_cell=2 * int(occ.max()))
+    vel = pc.velocity_field(
+        lambda c: np.stack(
+            [0.5 - c[:, 1], c[:, 0] - 0.5, np.full(len(c), 0.05)], axis=-1
+        )
+    )
+    return pc, pts, vel
 
 
 # ---------------------------------------------------------------- helpers
@@ -145,7 +241,8 @@ def halo_backend(name: str):
 def _advect(grid, steps, **kw):
     """Build an f32 Advection on ``grid``, run ``steps`` twice through
     ``run()`` (cold, then warm); returns (model, state0, out, record).
-    ``setup_s`` is the host time to build the model and its state."""
+    ``setup_s`` is the host time to build the model and its state;
+    ``path`` the whole run the model took (``Advection.path``)."""
     from dccrg_tpu.models import Advection
 
     t0 = time.perf_counter()
@@ -153,9 +250,9 @@ def _advect(grid, steps, **kw):
     state = adv.initialize_state()
     dt = np.float32(0.4 * adv.max_time_step(state))
     setup = time.perf_counter() - t0
-    out, first, path = _engaged("advection", lambda: adv.run(state, steps, dt))
-    out, warm, _ = _engaged("advection", lambda: adv.run(state, steps, dt))
-    return adv, state, out, {"path": path, "setup_s": setup,
+    out, first = _timed(lambda: adv.run(state, steps, dt))
+    out, warm = _timed(lambda: adv.run(state, steps, dt))
+    return adv, state, out, {"path": adv.path, "setup_s": setup,
                              "first_s": first, "run_s": warm}
 
 
@@ -175,7 +272,7 @@ def _compare_advection(name, grid, steps, tol, *, use_pallas, ref_kw,
     if expect is not None:
         expect(adv, rec)
     ref, _, ref_out, ref_rec = _advect(grid, steps, **ref_kw)
-    if getattr(ref, "dense_kind", None) is not None:
+    if ref.dense_kind is not None:
         ref_rec["path"] += f"/{ref.dense_kind[0]}"
     m0, m1 = adv.total_mass(s0), adv.total_mass(out)
     rec.update(
@@ -186,7 +283,7 @@ def _compare_advection(name, grid, steps, tol, *, use_pallas, ref_kw,
         tol=tol, mass_rel_drift=abs(m1 - m0) / abs(m0),
         mass_tol=steps * F32_EPS,
     )
-    if getattr(adv, "dense_kind", None) is not None:
+    if adv.dense_kind is not None:
         rec["dense_kind"] = list(adv.dense_kind)
     _check(rec["max_rel_err"] <= tol,
            f"{name}: max rel err {rec['max_rel_err']:.3e} > {tol}")
@@ -263,7 +360,7 @@ def _gol(n, turns, use_pallas):
 
 
 def _poisson(n, iters, use_pallas):
-    """bench.py's Poisson configuration: the fused flat BiCG kernel vs
+    """The Poisson configuration: the fused flat BiCG kernel vs
     the XLA flat BiCG, a fixed number of iterations from the same start."""
     import jax
 
@@ -331,8 +428,6 @@ def _pic(n_particles, length, steps):
     """Device-side push + re-bucket loop vs the host-orchestrated
     re-bucket: the same particles, positions equal up to the rounding
     of one fused push per step (positions lie in [0, 1))."""
-    from benchmarks.microbench import pic_setup
-
     pc, pts, vel = pic_setup(n_particles, length)
     _check(pc._dev_rebucket is not None, "pic: device re-bucket not built")
     dt = 0.2 / length
@@ -363,8 +458,8 @@ def phase_models(*, gol_n=GOL_N, gol_turns=200, poisson_n=POISSON_N,
                  poisson_iters=30, vlasov_n=VLASOV_N, vlasov_nv=VLASOV_NV,
                  vlasov_steps=10, pic_n=PIC_N, pic_grid=PIC_GRID, pic_steps=3,
                  use_pallas=True):
-    """One short call of every other workload bench.py times, at its
-    bench size, each against its XLA or host path."""
+    """One short call of every other workload, at its chip size, each
+    against its XLA or host path."""
     return [
         _gol(gol_n, gol_turns, use_pallas),
         _poisson(poisson_n, poisson_iters, use_pallas),

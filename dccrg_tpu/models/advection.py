@@ -218,10 +218,26 @@ class Advection:
         self.dense = (grid.epoch.dense if allow_dense and not overlap
                       else None)
         self.boxed = None
+        #: the whole-run candidates ``_init_paths`` builds; None where
+        #: one was not built
+        self._fused_run = self._dense_run = None
+        self._boxed_run = self._flat_run = None
+        #: the dense per-step kernel (``_build_dense_bundle``), None off
+        #: the dense path; the flat run's form (``pallas``, ``ml``,
+        #: ``ml_pallas``, ``sharded``, or an ``*_interpret`` one), None
+        #: without a flat run
+        self.dense_kind = self.flat_kind = None
+        self._flat_n_vox = 0
         #: (epoch, dtype, shape) -> halo bytes per step (``_record_run``)
         self._bps_key, self._bps = None, 0
+        #: (epoch, wide-halo step spec) of ``_wide_spec``
+        self._wide_cached = None
         with metrics.phase("advection.init"):
             self._init_paths(allow_boxed)
+        #: the whole-run path ``run()`` takes: ``fused``, ``dense``,
+        #: ``boxed``, ``flat``, ``general`` or ``split`` (the
+        #: ``fused.runs`` label and the ``jit_advection_<path>_run`` module)
+        self.path, self._launch = self._choose_path()
 
     def _init_paths(self, allow_boxed):
         """Build every candidate whole-run path, each under its own
@@ -259,22 +275,53 @@ class Advection:
             # slab-mode boxed but handled exactly by the flat rolls)
             with metrics.phase("advection.init.flat"):
                 self._flat_run = self._build_flat_run()
-            # cost-based choice when both fast paths qualify: prefer
-            # boxed only when the flat form's voxel inflation exceeds
-            # its per-voxel rate advantage over the boxed passes (one
-            # edge constant per compiled form, _BOXED_EDGE).  Interpret
-            # mode (tests) and the 2-level sharded XLA form keep the
-            # flat preference so the flat numerics stay exercised
-            if (
-                self._flat_kind in ("pallas", "ml", "ml_pallas")
-                and self._flat_run is not None
-                and self.boxed is not None
-            ):
-                boxed_vol = sum(
-                    int(np.prod(b.shape)) for b in self.boxed.boxes.values()
-                )
-                edge = _BOXED_EDGE[self._flat_kind]
-                self._prefer_boxed = self._flat_n_vox > edge * boxed_vol
+
+    def _choose_path(self, with_flat=True):
+        """``(path, fn(state, steps, dt))`` of the whole-run dispatch,
+        from the candidates ``_init_paths`` built:
+
+        * ``overlap=True``: ``split``;
+        * dense grid: ``fused`` where the fused VMEM run was built, else
+          ``dense`` where the blocked run was, else ``general`` (the
+          dense bundle's XLA step in a loop);
+        * other grids: ``boxed`` where it was built and either the cost
+          edge prefers it or no flat run exists, else ``flat`` where a
+          flat run exists, else ``general`` (the gather path's step).
+
+        ``with_flat=False`` chooses as if no flat run were built."""
+        if self.overlap:
+            return "split", self._build_general_run()
+        if self.dense is not None:
+            if self._fused_run is not None:
+                return "fused", self._fused_run
+            if self._dense_run is not None:
+                return "dense", self._dense_run
+            return "general", self._build_general_run()
+        flat = self._flat_run if with_flat else None
+        if self._boxed_run is not None and (
+            flat is None or self._boxed_beats_flat()
+        ):
+            return "boxed", self._boxed_run
+        if flat is not None:
+            return "flat", self._run_flat
+        return "general", self._build_general_run()
+
+    def _boxed_beats_flat(self) -> bool:
+        """Where both AMR whole runs exist: boxed only when the flat
+        form's voxel inflation exceeds its per-voxel rate advantage over
+        the boxed passes (one edge constant per compiled form,
+        ``_BOXED_EDGE``)."""
+        if self.flat_kind == "sharded":
+            return False  # multi-device: flat wins; unmeasured on the chip
+        edge = _BOXED_EDGE.get(self.flat_kind)
+        if edge is None:
+            # the interpret forms (tests) keep flat, so its numerics stay
+            # exercised
+            return False
+        boxed_vol = sum(
+            int(np.prod(b.shape)) for b in self.boxed.boxes.values()
+        )
+        return self._flat_n_vox > edge * boxed_vol
 
     # ------------------------------------------------------ static tables
 
@@ -549,7 +596,6 @@ class Advection:
 
         # use_pallas doubles as the fast-path opt-out: False always means
         # the reference boxed numerics
-        self._flat_kind = None
         if not self.use_pallas:
             return None
 
@@ -570,15 +616,15 @@ class Advection:
                 and (interpret or pallas_available(self.dtype))
                 and flat_ml_kernel_fits(self._flat_n_vox, tml["vl"])
             ):
-                self._flat_kind = ("ml_pallas_interpret" if interpret
-                                   else "ml_pallas")
+                self.flat_kind = ("ml_pallas_interpret" if interpret
+                                  else "ml_pallas")
                 return self._build_ml_pallas_run(tml, interpret)
             jdt = (
                 jnp.float32
                 if np.dtype(self.dtype) == np.float32
                 else jnp.float64
             )
-            self._flat_kind = "ml"
+            self.flat_kind = "ml"
             return make_flat_ml_run(self.grid, tml, dtype=jdt)
 
         # multi-device: z-slab-sharded XLA form (no Pallas requirement)
@@ -591,7 +637,7 @@ class Advection:
                 else jnp.float64
             )
             self._flat_n_vox = int(np.prod(ts["shape"])) * ts["n_devices"]
-            self._flat_kind = "sharded"
+            self.flat_kind = "sharded"
             return make_flat_amr_run_sharded(self.grid, ts, dtype=jdt)
 
         interpret = self.use_pallas == "interpret"
@@ -605,7 +651,7 @@ class Advection:
             return None
         nz1, ny1, nx1 = t["shape"]
         self._flat_n_vox = nz1 * ny1 * nx1
-        self._flat_kind = "pallas_interpret" if interpret else "pallas"
+        self.flat_kind = "pallas_interpret" if interpret else "pallas"
         # lane-align the x extent when the pad fits VMEM: Mosaic pads
         # registers to 128 lanes regardless, so the explicit pad costs no
         # extra compute and turns the 12 per-step x rolls lane-aligned
@@ -824,7 +870,7 @@ class Advection:
         #: ("plane",) / ("xla",) — so the bench's HBM-traffic model can
         #: count the bytes the engaged path actually moves
         dense_kind = ("xla",)
-        use_pallas = getattr(self, "use_pallas", True)
+        use_pallas = self.use_pallas
         # use_pallas="interpret" forces the kernels through the Pallas
         # interpreter so CI (CPU) exercises the full integration path
         interpret = use_pallas == "interpret"
@@ -1182,7 +1228,7 @@ class Advection:
 
         if not wide_enabled() or self.tables is None:
             return None
-        cached = getattr(self, "_wide_cached", None)
+        cached = self._wide_cached
         if cached is not None and cached[0] is self.grid.epoch:
             return cached[1]
         plan = get_wide_plan(self.grid, self.hood_id, relevance="face")
@@ -1358,91 +1404,42 @@ class Advection:
         device scalars) and ``advection.run.launch`` (the jitted
         whole-run call, ``jit_advection_<path>_run`` on the device)."""
         with TraceAnnotation("advection.run"):
-            path, launch = self._launcher()
             with TraceAnnotation("advection.run.record"):
-                self._record_run(path, steps, state)
+                self._record_run(self.path, steps, state)
             with TraceAnnotation("advection.run.args"):
-                # the gather-path loops take steps as a Python int (jit's
-                # own scalar path); the whole-run kernels a device scalar
-                n = (steps if path in ("general", "split")
-                     else jnp.asarray(steps, jnp.int32))
+                n = jnp.asarray(steps, jnp.int32)
                 d = jnp.asarray(dt, self.dtype)
             with TraceAnnotation("advection.run.launch"):
-                return launch(state, n, d)
+                return self._launch(state, n, d)
 
-    def _launcher(self):
-        """``(path, fn(state, steps, dt))`` of the whole-run dispatch."""
-        if getattr(self, "_fused_run", None) is not None:
-            return "fused", self._fused_run
-        if (
-            getattr(self, "_prefer_boxed", False)
-            and getattr(self, "_boxed_run", None) is not None
-        ):
-            return "boxed", self._boxed_run
-        if getattr(self, "_flat_run", None) is not None:
-            return "flat", self._flat_or_general
-        return self._general_launcher()
-
-    def _flat_or_general(self, state, steps, dt):
+    def _run_flat(self, state, steps, dt):
         # the flat kernel is an optimization; if the TPU compiler
-        # rejects it (op support varies by generation), fall back to
-        # the boxed/general dispatch permanently for this instance —
+        # rejects it (op support varies by generation), run the path
+        # chosen without it, and keep to that path for this instance —
         # but only after the fallback succeeds on the same inputs
         # (utils/fallback.py's policy), so a caller error propagates
+        def without_flat():
+            path, fn = self._choose_path(with_flat=False)
+            self._record_run(path, steps, state)
+            return fn(state, steps, dt)
+
         return fallback_call(
             "flat AMR kernel",
             lambda: self._flat_run(state, steps, dt),
-            lambda: self._run_general(state, steps, dt),
-            self._disable_flat,
+            without_flat,
+            self._drop_flat,
         )
 
-    def _disable_flat(self):
-        self._flat_run = None
-
-    def _run_general(self, state, steps, dt):
-        """The non-flat whole-run dispatch, recorded under its own path
-        (the flat kernel's fallback)."""
-        path, fn = self._general_launcher()
-        self._record_run(path, steps, state)
-        return fn(state, steps, dt)
-
-    def _general_launcher(self):
-        """The non-flat dispatch: boxed, dense, or the general
-        gather-path fori_loop (built on first use)."""
-        if getattr(self, "_boxed_run", None) is not None:
-            return "boxed", self._boxed_run
-        if getattr(self, "_dense_run", None) is not None:
-            return "dense", self._dense_run
-        if not hasattr(self, "_run"):
-            self._run = self._build_general_run()
-        return ("split" if self.overlap else "general"), self._run
+    def _drop_flat(self):
+        """The flat run is refused for good: choose again without it."""
+        self._flat_run = self.flat_kind = None
+        self.path, self._launch = self._choose_path()
 
     def _build_general_run(self):
-        """The gather-path whole run: ``steps`` of the (split-phase or
-        blocking) step in one ``fori_loop``."""
-        from ..parallel.exec_cache import (
-            record_run_donation,
-            run_donate_enabled,
-        )
-
-        donate = run_donate_enabled()
-
-        def probe_wrap(dispatch):
-            """Measure donation effectiveness per dispatch via the
-            ``is_deleted`` probe, like the ensemble's stacked-state
-            donation path."""
-            if not donate:
-                return dispatch
-
-            def wrapped(state, steps, dt):
-                probe = state["density"]
-                out = dispatch(state, steps, dt)
-                record_run_donation("advection", probe)
-                return out
-
-            return wrapped
-
-        if getattr(self, "_split_fn", None) is not None:
+        """The gather-path whole run: ``steps`` of the split-phase or
+        blocking step in one ``fori_loop``; on a dense grid, of the dense
+        bundle's step."""
+        if self.overlap:
             inner = self._split_fn
 
             def build():
@@ -1453,22 +1450,14 @@ class Advection:
                         state,
                     )
 
-                # state is positional arg 4; donation joins the
-                # cache key so flipping DCCRG_RUN_DONATE re-keys
-                return traced_jit(
-                    "advection.split_run", run_fn,
-                    donate_argnums=(4,) if donate else (),
-                )
+                return traced_jit("advection.split_run", run_fn)
 
             fn = self.grid.exec_cache.get(
-                self._kernel_key("advection.split_run") + (donate,),
-                build,
+                self._kernel_key("advection.split_run"), build
             )
             args = self._split_args
-            return probe_wrap(lambda state, steps, dt: fn(
-                *args, state, steps, dt
-            ))
-        if hasattr(self, "_step_fn"):
+            return lambda state, steps, dt: fn(*args, state, steps, dt)
+        if self.dense is None:
             inner = self._step_fn
 
             def build():
@@ -1479,20 +1468,15 @@ class Advection:
                         state,
                     )
 
-                # state is positional arg 3
-                return traced_jit(
-                    "advection.general_run", run_fn,
-                    donate_argnums=(3,) if donate else (),
-                )
+                return traced_jit("advection.general_run", run_fn)
 
             fn = self.grid.exec_cache.get(
-                self._kernel_key("advection.general_run") + (donate,),
-                build,
+                self._kernel_key("advection.general_run"), build
             )
             rings, t, dev = self._rings, self.tables.tree(), self._dev
-            return probe_wrap(lambda state, steps, dt: fn(
+            return lambda state, steps, dt: fn(
                 rings, t, dev, state, steps, dt
-            ))
+            )
         # dense XLA-only path: the step came from the cached dense
         # bundle (plain (state, dt) signature)
         inner = self._step

@@ -344,22 +344,11 @@ class Vlasov:
                     state,
                 )
 
-            # state is positional arg 6 of run; donation joins the cache
-            # key below so flipping DCCRG_RUN_DONATE re-keys, not re-uses
-            return step_k, traced_jit(
-                "vlasov.run", run,
-                donate_argnums=(6,) if donate else (),
-            )
+            return step_k, traced_jit("vlasov.run", run)
 
-        from ..parallel.exec_cache import (
-            record_run_donation,
-            run_donate_enabled,
-        )
-
-        donate = run_donate_enabled()
         step_fn, run_fn = self.grid.exec_cache.get(
             ("vlasov.step", ex.structure_key, str(np.dtype(dtype)),
-             has_open, donate), build
+             has_open), build
         )
         vbT = jnp.asarray(self.v_bins.T, dtype)
         args = (rings, t, dev, vbT, bnd_pos_dev, bnd_neg_dev)
@@ -368,18 +357,9 @@ class Vlasov:
         self._step = self._step_xla = (
             lambda state, dt: step_fn(*args, state, dt)
         )
-        if donate:
-            def run_donated(state, steps, dt):
-                probe = state["f"]
-                out = run_fn(*args, state, steps, dt)
-                record_run_donation("vlasov", probe)
-                return out
-
-            self._run = self._run_xla = run_donated
-        else:
-            self._run = self._run_xla = (
-                lambda state, steps, dt: run_fn(*args, state, steps, dt)
-            )
+        self._run = self._run_xla = (
+            lambda state, steps, dt: run_fn(*args, state, steps, dt)
+        )
         if self.overlap:
             # the eager kernels above stay on _step_xla/_run_xla (the
             # in-process oracle); step()/run() take the fused split form
@@ -491,37 +471,17 @@ class Vlasov:
                     state,
                 )
 
-            # state is positional arg 5 of run (see _build_general_step)
-            return step_k, traced_jit(
-                "vlasov.split_run", run,
-                donate_argnums=(5,) if donate else (),
-            )
+            return step_k, traced_jit("vlasov.split_run", run)
 
-        from ..parallel.exec_cache import (
-            record_run_donation,
-            run_donate_enabled,
-        )
-
-        donate = run_donate_enabled()
         step_fn, run_fn = self.grid.exec_cache.get(
             ("vlasov.split_step", ex.structure_key, str(np.dtype(dtype)),
-             has_open, donate), build
+             has_open), build
         )
         vbT = jnp.asarray(self.v_bins.T, dtype)
         args = (rings, inner, outer, local, vbT)
         self._split_fn_k, self._split_args = step_fn, args
         self._step = lambda state, dt: step_fn(*args, state, dt)
-        if donate:
-            def run_donated(state, steps, dt):
-                probe = state["f"]
-                out = run_fn(*args, state, steps, dt)
-                record_run_donation("vlasov", probe)
-                return out
-
-            self._run = run_donated
-        else:
-            self._run = lambda state, steps, dt: run_fn(*args, state,
-                                                        steps, dt)
+        self._run = lambda state, steps, dt: run_fn(*args, state, steps, dt)
 
     # ------------------------------------------------------------ user API
 

@@ -15,8 +15,8 @@ seam instead:
   checkpoint I/O (``io/checkpoint.py``) — all recording from HOST code
   outside jit boundaries, so jitted programs never carry per-call dict
   churn;
-* a JSON exporter (:func:`export_json` -> ``telemetry.json``, consumed
-  by ``bench.py``) and a ``jax.profiler`` capture context
+* a JSON exporter (:func:`export_json` -> ``telemetry.json``) and a
+  ``jax.profiler`` capture context
   (:func:`profile_trace`);
 * program spans on the profiler's own clock: every phase is also a
   ``jax.profiler.TraceAnnotation`` of its name (``<layer>.<what>``:

@@ -1,11 +1,10 @@
 """Per-device memory gauges from ``Device.memory_stats()``.
 
-The bench's OOM margins were invisible per round: a grid that barely
-fits HBM today silently stops fitting after a refinement change.
+OOM margins are invisible without them: a grid that barely fits HBM
+today silently stops fitting after a refinement change.
 ``sample_hbm`` snapshots each local device's allocator statistics into
 ``hbm.*{device=d}`` gauges — called at every epoch rebuild
-(``parallel/epoch.py``, the moment payload arrays are re-laid-out) and
-at bench checkpoints (``bench.py`` after each measurement).
+(``parallel/epoch.py``, the moment payload arrays are re-laid-out).
 
 Backends without allocator stats (CPU returns ``None``; some plugins
 raise) record nothing — the gauges simply stay absent there.

@@ -60,8 +60,6 @@ __all__ = [
     "ExecutableCache",
     "BatchStepSpec",
     "WideStepSpec",
-    "run_donate_enabled",
-    "record_run_donation",
     "cohort_key",
     "default_steps_per_dispatch",
     "max_steps_per_dispatch",
@@ -154,32 +152,6 @@ class WideStepSpec(NamedTuple):
     budget: int
     args: tuple = ()
     local_mask: object = None
-
-
-def run_donate_enabled() -> bool:
-    """Whether the solo model ``run()`` kernels donate their input state
-    buffers (``DCCRG_RUN_DONATE``, default OFF — solo callers commonly
-    reuse the pre-run state, which donation invalidates; the ensemble's
-    stacked state donates via ``DCCRG_ENSEMBLE_DONATE`` instead).
-    Effectiveness is measured, not assumed: the first donated dispatch
-    probes ``is_deleted`` on the input buffer and gauges
-    ``run.donate_effective``."""
-    return os.environ.get("DCCRG_RUN_DONATE", "0").lower() in (
-        "1", "true", "on",
-    )
-
-
-def record_run_donation(model: str, probe) -> None:
-    """After a donated solo ``run()`` dispatch: gauge whether the input
-    buffer was actually consumed.  ``is_deleted`` on the pre-dispatch
-    leaf is the ground truth (the ensemble's ``DCCRG_ENSEMBLE_DONATE``
-    uses the same probe) — backends are free to ignore donation (CPU
-    commonly does), so effectiveness is a measurement, not a promise."""
-    try:
-        eff = 1.0 if probe.is_deleted() else 0.0
-    except Exception:  # noqa: BLE001 — telemetry must never raise
-        eff = 0.0
-    _metrics.gauge("run.donate_effective", eff, model=model)
 
 
 def max_steps_per_dispatch() -> int:

@@ -2,11 +2,11 @@
 sharding paths run on any host, mirroring the reference's
 "mpiexec -n N on localhost" testing model (reference tests/README:5-7).
 
-The chip runs (chip_smoke.py, bench.py) drive a real TPU; tests always run
-on the virtual CPU mesh for device-count-invariant assertions.  jax may
-already be imported by a pytest plugin, so the platform is set via
-jax.config (backends initialize lazily); the environment variables are
-for the child processes some tests start.
+The chip runs (chip_smoke.py, benchmark/run.py) drive a real TPU; tests
+always run on the virtual CPU mesh for device-count-invariant
+assertions.  jax may already be imported by a pytest plugin, so the
+platform is set via jax.config (backends initialize lazily); the
+environment variables are for the child processes some tests start.
 """
 import os
 

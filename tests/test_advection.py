@@ -148,3 +148,85 @@ def test_max_diff_indicator():
     # steep hump edge -> some large indicators; far field flat -> zeros
     assert md.max() > 1.0
     assert (md < 1e-12).sum() > len(md) / 4
+
+
+def _path_grid(n=8, n_dev=1, radii=(), center=(0.45, 0.45, 0.45),
+               stretched=False):
+    """An n^3 periodic grid, a ball around ``center`` refined once per
+    radius; ``stretched``: level 0 on a non-uniform mesh."""
+    from dccrg_tpu.geometry.stretched import StretchedCartesianGeometry
+
+    g = (
+        Grid()
+        .set_initial_length((n, n, n))
+        .set_neighborhood_length(0)
+        .set_periodic(True, True, True)
+        .set_maximum_refinement_level(len(radii))
+    )
+    if stretched:
+        g = g.set_geometry(
+            StretchedCartesianGeometry,
+            coordinates=[np.linspace(0.0, 1.0, n + 1) ** 1.3] * 3,
+        )
+    else:
+        g = g.set_geometry(CartesianGeometry, start=(0.0, 0.0, 0.0),
+                           level_0_cell_length=(1.0 / n,) * 3)
+    g = g.initialize(mesh=make_mesh(n_devices=n_dev))
+    for rad in radii:
+        ids = g.get_cells()
+        r = np.linalg.norm(g.geometry.get_center(ids) - np.asarray(center),
+                           axis=1)
+        lv = g.mapping.get_refinement_level(ids)
+        for cid in ids[(r < rad) & (lv == lv.max())]:
+            g.refine_completely(int(cid))
+        g.stop_refining()
+    return g
+
+
+F32_INTERPRET = {"dtype": np.float32, "use_pallas": "interpret"}
+
+
+@pytest.mark.parametrize("grid_kw, adv_kw, path, flat_kind", [
+    ({}, F32_INTERPRET, "fused", None),
+    ({}, dict(F32_INTERPRET, fused_budget=0), "dense", None),
+    ({}, {"dtype": np.float32, "use_pallas": False}, "general", None),
+    ({"radii": (0.28,)}, F32_INTERPRET, "flat", "pallas_interpret"),
+    ({"radii": (0.3, 0.15), "n_dev": 2}, F32_INTERPRET, "boxed", "ml"),
+    ({"n": 6, "radii": (0.6, 0.55)}, {}, "flat", "ml"),
+    ({"radii": (0.3, 0.15)}, {"allow_boxed": False}, "general", None),
+    ({"n": 6, "stretched": True}, {}, "general", None),
+    ({"radii": (0.28,)}, {"overlap": True}, "split", None),
+    ({"radii": (0.28,), "n_dev": 4}, {"dtype": np.float32}, "flat",
+     "sharded"),
+], ids=["uniform_fused", "uniform_past_fused_budget", "uniform_no_pallas",
+        "ball_2_levels", "ball_3_levels_2_devices", "broad_ball_3_levels_f64",
+        "ball_3_levels_no_boxed",
+        "stretched", "overlap", "ball_2_levels_4_devices"])
+def test_advection_path_decision(monkeypatch, grid_kw, adv_kw, path,
+                                 flat_kind):
+    """``Advection.path`` names the whole run the model chose, and one
+    ``run()`` is counted under that label in ``fused.runs``."""
+    from dccrg_tpu import obs
+    from dccrg_tpu.ops import dense_advection
+
+    adv_kw = dict(adv_kw)
+    if "fused_budget" in adv_kw:
+        # a uniform grid whose fused run does not fit its VMEM budget
+        monkeypatch.setattr(dense_advection, "_FUSED_VMEM_BUDGET",
+                            adv_kw.pop("fused_budget"))
+    adv = Advection(_path_grid(**grid_kw), **adv_kw)
+    assert adv.path == path
+    assert adv.flat_kind == flat_kind
+    state = adv.initialize_state()
+    dt = 0.4 * adv.max_time_step(state)
+    obs.enable()
+    obs.metrics.reset()
+    adv.run(state, 2, dt)
+    runs = obs.metrics.report()["counters"].get("fused.runs", {})
+    assert {k for k in runs if "model=advection" in k} == {
+        f"model=advection,path={path}"}
+    if path == "boxed":
+        # every level moves through the Pallas moves, and is counted so
+        assert adv._boxed_moved == (True,) * len(adv.boxed.boxes)
+        assert obs.metrics.counter_value("boxed.kernel_runs",
+                                         form="pallas") == 1

@@ -417,11 +417,11 @@ def test_fuzz_paths_agree(seed):
         st = adv.step(st, dt)
     ref = np.asarray(adv.get_cell_data(st, "density", ids), np.float64)
     scale = np.abs(ref).max()
-    if getattr(adv, "_boxed_run", None) is not None:
+    if adv.path == "boxed":
         b = adv._boxed_run(s0, jnp.asarray(3, jnp.int32), dt)
         rb = np.asarray(adv.get_cell_data(b, "density", ids), np.float64)
         assert np.abs(rb - ref).max() / scale < 5e-6
-    if getattr(flat, "_flat_run", None) is not None:
+    if flat.flat_kind is not None:
         a = flat.run(s0, 3, dt)
         ra = np.asarray(flat.get_cell_data(a, "density", ids), np.float64)
         assert np.abs(ra - ref).max() / scale < 5e-6
@@ -460,7 +460,7 @@ def test_fuzz_three_level_boxed(seed):
     if g.mapping.get_refinement_level(ids).max() < 2:
         pytest.skip("refinement did not reach level 2")
     adv = Advection(g, dtype=np.float32, use_pallas=False)
-    if getattr(adv, "_boxed_run", None) is None:
+    if adv.path != "boxed":
         pytest.skip("boxed layout ineligible for this pattern")
     s0 = adv.initialize_state()
     s0 = adv.set_cell_data(
@@ -502,6 +502,8 @@ def test_boxed_kernel_matches_xla_f32(kw, steps):
     which the move back merges.  The moves only move values; the two
     programs agree to rounding (XLA's CPU compiler fuses multiply-adds
     differently in them)."""
+    import jax.numpy as jnp
+
     from dccrg_tpu import obs
 
     kw = {"n": 8, "maxref": 1, **kw}
@@ -517,7 +519,6 @@ def test_boxed_kernel_matches_xla_f32(kw, steps):
         counts = [b.leaf_mask.reshape(D, -1).sum(axis=1)
                   for b in kern.boxed.boxes.values()]
         assert any(len(set(c.tolist())) > 1 for c in counts)
-    kern._flat_run = None           # dispatch the boxed run
     # signed velocities on every axis, so each face is upwind either way
     rng = np.random.default_rng(steps + len(kw))
     ids = g.get_cells()
@@ -533,10 +534,10 @@ def test_boxed_kernel_matches_xla_f32(kw, steps):
     dt = np.float32(0.4 * xla.max_time_step(state))
     obs.enable()
     obs.metrics.reset()
+    assert xla.path == "boxed"
     want = xla.run(state, steps, dt)
-    got = kern.run(state, steps, dt)
-    assert obs.metrics.counter_value("boxed.kernel_runs", form="pallas") == 1
     assert obs.metrics.counter_value("boxed.kernel_runs", form="xla") == 1
+    got = kern._boxed_run(state, jnp.asarray(steps, jnp.int32), dt)
     a = np.asarray(g.get_cell_data(got, "density", ids))
     b = np.asarray(g.get_cell_data(want, "density", ids))
     np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-6 * np.abs(b).max())

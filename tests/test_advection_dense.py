@@ -91,7 +91,7 @@ def test_pallas_integration_interpret(periodic):
     g, _ = make(periodic=periodic, n_dev=1)
     pal = Advection(g, dtype=np.float32, use_pallas="interpret")
     xla = Advection(g, dtype=np.float32, use_pallas=False)
-    assert pal._fused_run is not None and xla._fused_run is None
+    assert pal.path == "fused" and xla.path == "general"
 
     s0 = pal.initialize_state()
     cells = g.get_cells()
@@ -124,7 +124,7 @@ def test_plane_kernel_interpret():
     assert pick_step_block(7, 8, 8) == 0
     pal = Advection(g, dtype=np.float32, use_pallas="interpret")
     xla = Advection(g, dtype=np.float32, use_pallas=False)
-    assert pal._dense_run is None  # blocked path did not engage
+    assert pal.dense_kind == ("plane",)  # blocked path did not engage
 
     s0 = pal.initialize_state()
     cells = g.get_cells()
@@ -154,7 +154,7 @@ def test_blocked_kernel_interpret(periodic, nz, n_dev, steps):
     xla = Advection(g, dtype=np.float32, use_pallas=False)
     nzl = nz // n_dev
     assert pick_step_block(nzl, 8, 8) >= 2  # blocked path engages
-    assert pal._dense_run is not None
+    assert pal.dense_kind[0] == "blocked_direct"
 
     s0 = pal.initialize_state()
     cells = g.get_cells()

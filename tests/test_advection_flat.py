@@ -55,8 +55,8 @@ def test_flat_matches_boxed(periodic):
     g = make(periodic)
     flat = Advection(g, dtype=np.float32, use_pallas="interpret")
     boxed = Advection(g, dtype=np.float32, use_pallas=False)
-    assert flat._flat_run is not None
-    assert getattr(boxed, "_flat_run", None) is None  # gated on use_pallas
+    assert flat.path == "flat"
+    assert boxed.flat_kind is None  # gated on use_pallas
     s0, ids = seeded_state(flat, g)
     dt = np.float32(0.3 * flat.max_time_step(s0))
 
@@ -91,7 +91,7 @@ def test_flat_open_boundary_differs_from_periodic():
 def test_flat_gating():
     """f64, uniform grids, and multi-device stay off the flat path."""
     g = make()
-    assert getattr(Advection(g), "_flat_run", None) is None  # f64 default
+    assert Advection(g).flat_kind is None  # f64 default
 
     n = 8
     gu = (
@@ -142,8 +142,8 @@ def test_flat_sharded_matches_boxed(n_dev, periodic):
 
     flat = Advection(g, dtype=np.float32)
     boxed = Advection(g, dtype=np.float32, use_pallas=False)
-    assert flat._flat_run is not None  # engages without Pallas
-    assert getattr(boxed, "_flat_run", None) is None  # opt-out honored
+    assert (flat.path, flat.flat_kind) == ("flat", "sharded")  # no Pallas
+    assert boxed.flat_kind is None  # opt-out honored
     s0, ids = seeded_state(flat, g)
     dt = np.float32(0.3 * flat.max_time_step(s0))
     a = flat.run(s0, 7, dt)
@@ -184,7 +184,7 @@ def test_flat_sharded_device_count_invariant():
             g, dtype=np.float32,
             use_pallas="interpret" if n_dev == 1 else True,
         )
-        assert adv._flat_run is not None
+        assert adv.path == "flat"
         s0, ids = seeded_state(adv, g)
         dt = np.float32(0.3 * adv.max_time_step(s0))
         out = adv.run(s0, 7, dt)
@@ -201,7 +201,7 @@ def test_flat_run_feeds_adaptation_cycle():
     model rebuilds its fast paths for the adapted grid."""
     g = make()
     adv = Advection(g, dtype=np.float32, use_pallas="interpret")
-    assert adv._flat_run is not None
+    assert adv.path == "flat"
     s0, ids = seeded_state(adv, g)
     dt = np.float32(0.3 * adv.max_time_step(s0))
     state = adv.run(s0, 5, dt)
@@ -217,6 +217,41 @@ def test_flat_run_feeds_adaptation_cycle():
     out = adv2.run(state2, 3, np.float32(0.3 * adv2.max_time_step(state2)))
     m2 = lvl_mass(adv2.grid, ids2, adv2.get_cell_data(out, "density", ids2))
     assert m2 == pytest.approx(m1, rel=1e-5)
+
+
+def test_refused_flat_kernel_falls_back_and_path_names_it():
+    """A flat kernel the compiler refuses (a typed rejection) falls back
+    to the path chosen without it, on the same inputs, and from then on
+    ``path`` names that path and ``run()`` takes it."""
+    from dccrg_tpu import obs
+
+    g = make()
+    adv = Advection(g, dtype=np.float32, use_pallas="interpret")
+    assert (adv.path, adv.flat_kind) == ("flat", "pallas_interpret")
+
+    def refused(state, steps, dt):
+        raise NotImplementedError("Mosaic: unsupported lowering")
+
+    adv._flat_run = refused
+    s0, ids = seeded_state(adv, g)
+    dt = np.float32(0.3 * adv.max_time_step(s0))
+    obs.enable()
+    obs.metrics.reset()
+    out = adv.run(s0, 4, dt)
+    assert (adv.path, adv.flat_kind) == ("boxed", None)
+    assert obs.metrics.counter_value("kernel.fallbacks",
+                                     label="flat AMR kernel",
+                                     kind="disabled") == 1
+    assert obs.metrics.counter_value("fused.runs", model="advection",
+                                     path="boxed") == 1
+    want = adv._boxed_run(s0, jnp.asarray(4, jnp.int32), dt)
+    np.testing.assert_array_equal(np.asarray(out["density"]),
+                                  np.asarray(want["density"]))
+    adv.run(s0, 4, dt)
+    assert obs.metrics.counter_value("fused.runs", model="advection",
+                                     path="boxed") == 2
+    assert obs.metrics.counter_value("fused.runs", model="advection",
+                                     path="flat") == 1
 
 
 def test_pad_lane_extent():

@@ -51,7 +51,7 @@ def test_ml_flat_matches_general_path(n_dev):
     g = ball_grid(n_dev)
     ids = np.sort(g.leaves.cells)
     adv_ml = Advection(g, dtype=np.float64)
-    assert adv_ml._flat_kind == "ml", "3-level grid must engage the ml path"
+    assert adv_ml.flat_kind == "ml", "3-level grid must engage the ml path"
     adv_gen = Advection(g, dtype=np.float64, use_pallas=False,
                         allow_boxed=False)
     s_ml = adv_ml.initialize_state()
@@ -74,7 +74,7 @@ def test_ml_flat_nonperiodic_boundaries():
     g = ball_grid(1, periodic=(False, False, False))
     ids = np.sort(g.leaves.cells)
     adv_ml = Advection(g, dtype=np.float64)
-    assert adv_ml._flat_kind == "ml"
+    assert adv_ml.flat_kind == "ml"
     adv_gen = Advection(g, dtype=np.float64, use_pallas=False,
                         allow_boxed=False)
     rng = np.random.default_rng(0)
@@ -101,7 +101,7 @@ def test_ml_pallas_kernel_matches_general_path():
     g = ball_grid(1)
     ids = np.sort(g.leaves.cells)
     adv_k = Advection(g, dtype=np.float32, use_pallas="interpret")
-    assert adv_k._flat_kind == "ml_pallas_interpret", adv_k._flat_kind
+    assert adv_k.flat_kind == "ml_pallas_interpret", adv_k.flat_kind
     adv_gen = Advection(g, dtype=np.float32, use_pallas=False,
                         allow_boxed=False)
     s_k = adv_k.initialize_state()
@@ -136,7 +136,7 @@ def test_two_level_grids_keep_the_tuned_paths():
     g.refine_completely(1)
     g.stop_refining()
     adv = Advection(g, dtype=np.float32)
-    assert adv._flat_kind != "ml"
+    assert adv.flat_kind != "ml"
 
 
 def test_ml_run_dispatch_and_fallback_shape():

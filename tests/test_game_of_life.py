@@ -111,8 +111,8 @@ def test_dense2d_matches_general(n_dev, use_pallas, periodic):
     alive0 = cells[rng.random(len(cells)) < 0.35]
     fast = GameOfLife(g, use_pallas=use_pallas)
     slow = GameOfLife(g, allow_dense=False)
-    assert fast._dense_run is not None
-    assert slow._dense_run is None
+    assert fast.dense2d is not None
+    assert slow.dense2d is None
     s = fast.run(fast.new_state(alive_cells=alive0), 13)
     r = slow.run(slow.new_state(alive_cells=alive0), 13)
     assert set(fast.alive_cells(s).tolist()) == set(slow.alive_cells(r).tolist())
@@ -160,7 +160,7 @@ def test_gol_model_y_padding_engages():
     alive0 = cells[rng.random(len(cells)) < 0.35]
     fast = GameOfLife(g, use_pallas="interpret")
     slow = GameOfLife(g, allow_dense=False)
-    assert fast._dense_run is not None
+    assert fast.dense2d is not None
     s = fast.run(fast.new_state(alive_cells=alive0), 9)
     r = slow.run(slow.new_state(alive_cells=alive0), 9)
     assert set(fast.alive_cells(s).tolist()) == set(

@@ -3,7 +3,7 @@
 No chip is attached: ``jax.experimental.topologies`` describes a v5e
 2x2 host and the TPU compiler (installed with jax) lowers and compiles
 the kernels for it at the widths the chip smoke (``chip_smoke.py``) and
-``bench.py`` run them at.  This catches what the Pallas interpreter
+the benchmark (``benchmark/``) run them at.  This catches what the Pallas interpreter
 cannot — VMEM over the scoped limit, unaligned slices, unsupported
 lowerings — before any chip time is spent.
 
@@ -181,7 +181,7 @@ def test_boxed_moves_refined_levels(one_chip, box, runs):
 
 
 def test_flat_amr_refined(one_chip):
-    """bench.py's 48^3 ball-refined grid: a 96^3 fine-voxel layout, the
+    """chip_smoke.py's 48^3 ball-refined grid: a 96^3 fine-voxel layout, the
     x extent lane-padded to 128 (it fits VMEM).  The unpadded form takes
     ~150 s to compile here (unaligned lane rolls) and is not the
     dispatched one at this size."""
@@ -201,8 +201,8 @@ def test_flat_amr_refined(one_chip):
 
 
 def test_flat_ml_pallas(one_chip):
-    """The 3-level whole-run kernel at bench.py's refined3 size (16^3
-    level 0 refined twice: a 64^3 finest-voxel layout)."""
+    """The 3-level whole-run kernel on a 16^3 level 0 refined twice: a
+    64^3 finest-voxel layout."""
     from dccrg_tpu.ops.flat_amr import (
         flat_ml_kernel_fits,
         make_flat_ml_run_pallas,

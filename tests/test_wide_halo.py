@@ -318,51 +318,34 @@ def test_exchanges_per_step_gauge_drops_to_one_over_k():
     assert rep["gauges"]["halo.exchanges_per_step"]["model=gol"] == 1.0
 
 
-# ----------------------------------------------------- run() donation
+# ------------------------------------------------------------ solo run()
 
 
-def test_run_donation_env_gated_and_measured(monkeypatch):
-    """DCCRG_RUN_DONATE=1 donates the solo ``run()`` state with
-    MEASURED effectiveness (the ``is_deleted`` probe feeding
-    ``run.donate_effective``); default off, because solo callers may
-    legitimately reuse their input state."""
-    from dccrg_tpu.parallel.exec_cache import run_donate_enabled
-
-    monkeypatch.delenv("DCCRG_RUN_DONATE", raising=False)
-    assert run_donate_enabled() is False
-    monkeypatch.setenv("DCCRG_RUN_DONATE", "1")
-    assert run_donate_enabled() is True
-
+def test_run_equals_k_steps_advection_and_vlasov():
+    """The solo ``run()`` of Advection and Vlasov advances the state
+    exactly as ``k`` calls of ``step()`` do, and leaves its input state
+    readable (no donation)."""
     g = make_grid(hood=0)
     adv = Advection(g, dtype=np.float64, allow_dense=False)
     s0 = adv.initialize_state()
     dt = np.float64(0.4 * adv.max_time_step(s0))
-    # a donated input buffer must never be read after the call:
-    # snapshot the state the solo replay starts from
-    s0_copy = jax.tree_util.tree_map(lambda x: np.asarray(x).copy(), s0)
     out = adv.run(s0, 3, dt)
-    solo = s0_copy
+    solo = s0
     for _ in range(3):
         solo = adv.step(solo, dt)
     np.testing.assert_array_equal(np.asarray(solo["density"]),
                                   np.asarray(out["density"]))
-    rep = obs.metrics.report()
-    assert "model=advection" in rep["gauges"].get("run.donate_effective",
-                                                  {})
 
     g2 = make_grid(hood=0)
     vl = Vlasov(g2, nv=2, dtype=np.float32)
     sv = vl.initialize_state()
     dtv = np.float32(0.5 * vl.max_time_step())
-    sv_copy = jax.tree_util.tree_map(lambda x: np.asarray(x).copy(), sv)
     out2 = vl.run(sv, 3, dtv)
-    solo = sv_copy
+    solo = sv
     for _ in range(3):
         solo = vl.step(solo, dtv)
     np.testing.assert_array_equal(np.asarray(solo["f"]),
                                   np.asarray(out2["f"]))
-    rep = obs.metrics.report()
-    assert "model=vlasov" in rep["gauges"].get("run.donate_effective", {})
 
 
 # --------------------------------------------------------- wide plans

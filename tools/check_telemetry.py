@@ -56,7 +56,7 @@ Checks (exit 1 on any failure):
   all required nonzero);
 * side artifacts (``<out>.stream.jsonl`` / ``.trace.json``) land next
   to ``--out`` — or under ``tools/``
-  when ``--out`` is the repo root's ``telemetry.json``, keeping bench
+  when ``--out`` is the repo root's ``telemetry.json``, keeping its
   byproducts out of the root (``--artifact-dir`` overrides);
 * unless ``--skip-overhead``: enabling telemetry must not slow the
   workload's step loop by more than ``--threshold`` (default 1.05 =
@@ -65,8 +65,6 @@ Checks (exit 1 on any failure):
 
 Runnable standalone (``python tools/check_telemetry.py``) and as a
 ``not slow`` pytest via ``tests/test_obs.py::test_check_telemetry_tool``.
-``bench.py`` runs it per bench round to produce the round's
-``telemetry.json``.
 """
 from __future__ import annotations
 
@@ -339,7 +337,7 @@ def artifact_path(out_path: str, suffix: str,
     """Where a side artifact (``<out basename><suffix>``) lands.
 
     Default: next to ``out_path`` — EXCEPT when ``out_path`` sits at the
-    repo root (the bench's ``telemetry.json``), whose byproducts are
+    repo root (the committed ``telemetry.json``), whose byproducts are
     archived under ``tools/`` alongside ``telemetry_prev.json`` and the
     history instead of littering the root (ISSUE 8).  An explicit
     ``artifact_dir`` (``--artifact-dir``) overrides either way."""

@@ -101,13 +101,13 @@ def one_case(seed):
     ref = np.asarray(adv.get_cell_data(st, 'density', ids), np.float64)
     scale = np.abs(ref).max()
     tags = []
-    if getattr(adv, '_boxed_run', None) is not None:
+    if adv.path == 'boxed':
         b = adv._boxed_run(s0, jnp.asarray(3, jnp.int32), dt)
         rb = np.asarray(adv.get_cell_data(b, 'density', ids), np.float64)
         err = np.abs(rb - ref).max() / scale
         assert err < 5e-6, (seed, 'BOXED', n, n_dev, periodic, err)
         tags.append('boxed')
-    if getattr(flat, '_flat_run', None) is not None:
+    if flat.flat_kind is not None:
         a = flat.run(s0, 3, dt)
         ra = np.asarray(flat.get_cell_data(a, 'density', ids), np.float64)
         err = np.abs(ra - ref).max() / scale
@@ -156,7 +156,7 @@ def one(seed):
     if lv.max() < 2:
         return 'shallow'
     adv = Advection(g, dtype=np.float32, use_pallas=False)
-    if getattr(adv, '_boxed_run', None) is None:
+    if adv.path != 'boxed':
         return 'no-boxed'
     s0 = adv.initialize_state()
     s0 = adv.set_cell_data(s0, 'density', ids, rng.uniform(1, 2, len(ids)).astype(np.float32))
@@ -174,7 +174,7 @@ def one(seed):
     # multi-level flat path (when the layout qualifies): same state,
     # same oracle
     adv_ml = Advection(g, dtype=np.float32)
-    if getattr(adv_ml, '_flat_kind', None) == 'ml':
+    if adv_ml.flat_kind == 'ml':
         m = adv_ml._flat_run(s0, jnp.asarray(3, jnp.int32), dt)
         rm = np.asarray(adv_ml.get_cell_data(m, 'density', ids), np.float64)
         errm = np.abs(rm - ref).max() / np.abs(ref).max()
@@ -403,7 +403,7 @@ def one(seed):
                      ("dense", dict(use_pallas=False)),
                      ("fused", dict(use_pallas="interpret"))):
         m = GameOfLife(g, **kw)
-        if name != "general" and m._dense_run is None:
+        if name != "general" and m.dense2d is None:
             continue
         s = m.run(m.new_state(alive_cells=alive0), int(rng.integers(3, 20)))
         variants[name] = (set(m.alive_cells(s).tolist()),
