@@ -757,7 +757,8 @@ class Advection:
         multi-device correctness argument.  Sets ``_boxed_moved``, per
         level whether the Pallas moves carry it; ``_record_run`` counts
         each boxed dispatch as ``boxed.kernel_runs{form=pallas}`` where
-        any level's do, else ``{form=xla}``."""
+        any level's do, else ``{form=xla}``.  The run prepares the face
+        velocities once per velocity field (``BoxedRun``)."""
         from .boxed_advection import build_boxed_run
 
         run, self._boxed_moved = build_boxed_run(self, layout)

@@ -44,21 +44,26 @@ pooled routing differ):
   ppermuted rho planes per level, the same wire pattern as the uniform
   dense path (``parallel/dense.py``), generalized per level.
 
-Velocities are loop-invariant inside a run, so all weights and upwind
-selections are computed once at run start; the loop body touches only
-density.  Produces the same update as the general gather path
-(solve.hpp:129-260 semantics) with a different — but fixed —
-floating-point association order.
+Velocities are loop-invariant, so the run is two programs.
+``advection.boxed_prepare`` moves each level's velocities into its box and
+builds the ringed face velocities and upwind selections; it runs once per
+velocity field, keyed by the identity of the ``vx``/``vy``/``vz`` arrays
+(JAX arrays are immutable, so a new field is a new array and misses).
+``advection.boxed_run`` turns the cached face velocities into the step's
+weights in one pass and runs the step loop, which touches only density.
+Produces the same update as the general gather path (solve.hpp:129-260
+semantics) with a different — but fixed — floating-point association
+order.
 
 The run takes and returns the state as epoch rows; each call moves every
-level's density and velocities into its box and the density back.  Where
-``use_pallas`` is set, the dtype is float32 and Pallas runs (on a TPU, or
-``use_pallas="interpret"``), ``boxed_move_gather`` / ``boxed_move_scatter``
-(``ops/boxed_moves.py``) move each level whose leaves are one ascending
-range of rows, by runs of leaves; other levels, and every level in f64,
-on the CPU or with ``use_pallas=False``, move by per-element gather and
-scatter.  The moves only move values, so both give the same density bit
-for bit.
+level's density into its box and back, and hands the velocities it was
+given back unchanged.  Where ``use_pallas`` is set, the dtype is float32
+and Pallas runs (on a TPU, or ``use_pallas="interpret"``),
+``boxed_move_gather`` / ``boxed_move_scatter`` (``ops/boxed_moves.py``)
+move each level whose leaves are one ascending range of rows, by runs of
+leaves; other levels, and every level in f64, on the CPU or with
+``use_pallas=False``, move by per-element gather and scatter.  The moves
+only move values, so both give the same density bit for bit.
 """
 from __future__ import annotations
 
@@ -68,6 +73,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from ..obs.registry import metrics
 from ..ops.boxed_moves import (
     box_runs,
     make_box_gather,
@@ -79,7 +85,7 @@ from ..ops.dense_advection import pallas_available
 from ..parallel.exec_cache import traced_jit
 from ..parallel.mesh import SHARD_AXIS, put_table, shard_spec
 
-__all__ = ["build_boxed_run"]
+__all__ = ["BoxedRun", "build_boxed_run"]
 
 
 def _round_up(n, unit):
@@ -154,10 +160,48 @@ def _level_moves(leaf_s, rows_s, R, interpret):
     return consts, statics
 
 
+class BoxedRun:
+    """The boxed whole run, ``run(state, steps, dt) -> state``.
+
+    ``prepare(statics, vx, vy, vz)`` builds the per-level face velocities
+    and ``advance(statics, faces, density, flux, steps, dt)`` runs the
+    steps.  The faces of the last velocity field are kept with strong
+    references to its three arrays, so their ids cannot be reused: a call
+    with the same arrays reuses them (``boxed.velocity_prep{result=hit}``),
+    any other prepares anew and replaces them (``{result=miss}``).  The
+    returned state holds the caller's velocity arrays themselves, so the
+    next call on it hits."""
+
+    def __init__(self, statics, prepare, advance):
+        self.statics = statics
+        self.prepare = prepare
+        self.advance = advance
+        self._faces = None      # ((vx, vy, vz), faces) of the last field
+
+    def __call__(self, state, steps, dt):
+        vel = (state["vx"], state["vy"], state["vz"])
+        entry = self._faces
+        if entry is not None and all(a is b for a, b in zip(entry[0], vel)):
+            metrics.inc("boxed.velocity_prep", result="hit")
+            faces = entry[1]
+        else:
+            metrics.inc("boxed.velocity_prep", result="miss")
+            entry = self._faces = None  # free the old faces first
+            faces = self.prepare(self.statics, *vel)
+            # only immutable arrays are safe keys: a numpy array may be
+            # written in place under the same id
+            if all(isinstance(v, jax.Array) for v in vel):
+                self._faces = (vel, faces)
+        density, flux = self.advance(self.statics, faces, state["density"],
+                                     state["flux"], steps, dt)
+        return {**state, "density": density, "flux": flux}
+
+
 def build_boxed_run(adv, layout):
     """Build ``(run, moved)`` for ``adv`` (an ``Advection`` model) over
-    ``layout`` (a ``BoxedLayout``): the jitted ``run(state, steps, dt) ->
-    state`` and, per level, whether the Pallas moves carry it."""
+    ``layout`` (a ``BoxedLayout``): the :class:`BoxedRun` ``run(state,
+    steps, dt) -> state`` and, per level, whether the Pallas moves carry
+    it."""
     dtype = adv.dtype
     grid = adv.grid
     mapping = grid.mapping
@@ -486,7 +530,7 @@ def build_boxed_run(adv, layout):
 
         pconsts[fi] = dict(ci=ci, upsample=upsample, pool_route=pool_route)
 
-    # --------------------------------------------------- the sharded body
+    # --------------------------------------------------- the sharded bodies
     up_perm = [(i, (i + 1) % D) for i in range(D)]
     down_perm = [(i, (i - 1) % D) for i in range(D)]
 
@@ -509,47 +553,47 @@ def build_boxed_run(adv, layout):
             x = jnp.pad(x, pw, mode="wrap" if cov else "constant")
         return x
 
-    def body(rho_b, vx_b, vy_b, vz_b, dt, steps, st):
-        rho_flat = rho_b[0]
-        v_flat = (vx_b[0], vy_b[0], vz_b[0])
-        C = [{k: v[0] for k, v in s.items()} for s in st]  # strip dev axis
+    def strip(st):
+        return [{k: v[0] for k, v in s.items()} for s in st]  # dev axis
 
-        def to_slab(flat, li):
-            mv = moves[li]
-            if mv is None:
-                vals = flat[C[li]["rows"]]
-                return jnp.where(C[li]["leaf"], vals, 0)
-            bz_, by_, bx_ = C[li]["leaf"].shape
-            compact = jax.lax.dynamic_slice_in_dim(
-                jnp.pad(flat, (0, mv["tail"])), C[li]["mv_span"][0], mv["n"]
+    def to_slab(C, flat, li):
+        mv = moves[li]
+        if mv is None:
+            vals = flat[C[li]["rows"]]
+            return jnp.where(C[li]["leaf"], vals, 0)
+        bz_, by_, bx_ = C[li]["leaf"].shape
+        compact = jax.lax.dynamic_slice_in_dim(
+            jnp.pad(flat, (0, mv["tail"])), C[li]["mv_span"][0], mv["n"]
+        )
+        box = mv["gather"](C[li]["mv_tab"],
+                           rows_of(compact, mv["front"], mv["n_rows"]))
+        return box[:, :by_, :bx_]
+
+    def to_rows(C, out, li, rho):
+        mv = moves[li]
+        if mv is None:
+            return out.at[C[li]["leaf_rows"]].set(
+                rho.reshape(-1)[C[li]["leaf_flat"]]
             )
-            box = mv["gather"](C[li]["mv_tab"],
-                               rows_of(compact, mv["front"], mv["n_rows"]))
-            return box[:, :by_, :bx_]
+        r0, cnt = C[li]["mv_span"][0], C[li]["mv_span"][1]
+        rows = mv["scatter"](C[li]["mv_tab"], jnp.pad(rho, mv["plane_pad"]))
+        new = rows.reshape(-1)[mv["front"]:mv["front"] + mv["n"]]
+        out_p = jnp.pad(out, (0, mv["tail"]))
+        if mv["ragged"]:
+            old = jax.lax.dynamic_slice_in_dim(out_p, r0, mv["n"])
+            pos = jnp.arange(mv["n"], dtype=jnp.int32)
+            new = jnp.where(pos < cnt, new, old)
+        out_p = jax.lax.dynamic_update_slice_in_dim(out_p, new, r0, 0)
+        return out_p[:out.shape[0]]
 
-        def to_rows(out, li, rho):
-            mv = moves[li]
-            if mv is None:
-                return out.at[C[li]["leaf_rows"]].set(
-                    rho.reshape(-1)[C[li]["leaf_flat"]]
-                )
-            r0, cnt = C[li]["mv_span"][0], C[li]["mv_span"][1]
-            rows = mv["scatter"](C[li]["mv_tab"], jnp.pad(rho, mv["plane_pad"]))
-            new = rows.reshape(-1)[mv["front"]:mv["front"] + mv["n"]]
-            out_p = jnp.pad(out, (0, mv["tail"]))
-            if mv["ragged"]:
-                old = jax.lax.dynamic_slice_in_dim(out_p, r0, mv["n"])
-                pos = jnp.arange(mv["n"], dtype=jnp.int32)
-                new = jnp.where(pos < cnt, new, old)
-            out_p = jax.lax.dynamic_update_slice_in_dim(out_p, new, r0, 0)
-            return out_p[:out.shape[0]]
-
-        rhos = tuple(to_slab(rho_flat, li) for li in range(L))
-        vels = [tuple(to_slab(v, li) for v in v_flat) for li in range(L)]
-
-        # static per-level face weights and upwind selections (velocity is
-        # loop-invariant; the ring exchanges here run once per run)
-        stat = []
+    def prepare_body(st, vx_b, vy_b, vz_b):
+        """Per level and axis, the ringed face velocity and its upwind
+        selection: ``[[(v_face >= 0, v_face) per axis] per level]``, the
+        part of the run that reads only velocities."""
+        C = strip(st)
+        vels = [tuple(to_slab(C, v[0], li) for v in (vx_b, vy_b, vz_b))
+                for li in range(L)]
+        faces = []
         for li, c in enumerate(consts):
             p = pconsts.get(li)
             ups = (
@@ -571,11 +615,26 @@ def build_boxed_run(adv, layout):
                         (vl + 2 * vh) / 3,
                     ),
                 )
-                w = jnp.where(
-                    C[li]["any_face"][d], dt * v_face * c["area"][d], 0
-                )
-                per_axis.append((v_face >= 0, w))
-            stat.append(per_axis)
+                per_axis.append((v_face >= 0, v_face))
+            faces.append(per_axis)
+        return jax.tree.map(lambda a: a[None], faces)
+
+    def run_body(st, faces, rho_b, dt, steps):
+        rho_flat = rho_b[0]
+        C = strip(st)
+        rhos = tuple(to_slab(C, rho_flat, li) for li in range(L))
+
+        # per-level face weights: one elementwise pass a call (dt is a
+        # fresh scalar every call), the loop body touches only density
+        stat = [
+            [
+                (upsel[0], jnp.where(
+                    C[li]["any_face"][d], dt * v_face[0] * c["area"][d], 0
+                ))
+                for d, (upsel, v_face) in enumerate(faces[li])
+            ]
+            for li, c in enumerate(consts)
+        ]
 
         def step(i, rhos):
             rz = [zring(r) for r in rhos]
@@ -616,7 +675,7 @@ def build_boxed_run(adv, layout):
         rhos = jax.lax.fori_loop(0, steps, step, rhos)
         out = rho_flat
         for li in range(L):
-            out = to_rows(out, li, rhos[li])
+            out = to_rows(C, out, li, rhos[li])
         return out[None]
 
     statics_dev = [
@@ -628,34 +687,30 @@ def build_boxed_run(adv, layout):
         for s in statics
     ]
     data_spec = P(SHARD_AXIS)
-    sm = shard_map(
-        body,
-        mesh=mesh,
-        in_specs=(data_spec, data_spec, data_spec, data_spec, P(), P(),
-                  st_specs),
-        out_specs=data_spec,
-        # pallas_call outputs carry no varying axes
-        check_vma=not any(mv is not None for mv in moves),
+    # pallas_call outputs carry no varying axes
+    check_vma = not any(mv is not None for mv in moves)
+    sm_prepare = shard_map(
+        prepare_body, mesh=mesh,
+        in_specs=(st_specs, data_spec, data_spec, data_spec),
+        out_specs=data_spec, check_vma=check_vma,
+    )
+    sm_run = shard_map(
+        run_body, mesh=mesh,
+        in_specs=(st_specs, data_spec, data_spec, P(), P()),
+        out_specs=data_spec, check_vma=check_vma,
     )
 
-    # the boxed tables ride into the jit as a RUNTIME argument pytree
+    # the boxed tables ride into the jits as a RUNTIME argument pytree
     # (not closed over): same-shape boxings share one executable
-    def run_impl(statics_arg, state, steps, dt):
+    def run_impl(statics_arg, faces, density, flux, steps, dt):
         dt = jnp.asarray(dt, dtype)
         steps = jnp.asarray(steps, jnp.int32)
-        density = sm(
-            state["density"], state["vx"], state["vy"], state["vz"],
-            dt, steps, statics_arg,
-        )
-        return {
-            **state,
-            "density": density,
-            "flux": jnp.zeros_like(state["flux"]),
-        }
+        return (sm_run(statics_arg, faces, density, dt, steps),
+                jnp.zeros_like(flux))
 
-    run_impl = traced_jit("advection.boxed_run", run_impl)
-
-    def run(state, steps, dt):
-        return run_impl(statics_dev, state, steps, dt)
-
+    run = BoxedRun(
+        statics_dev,
+        traced_jit("advection.boxed_prepare", sm_prepare),
+        traced_jit("advection.boxed_run", run_impl),
+    )
     return run, tuple(mv is not None for mv in moves)

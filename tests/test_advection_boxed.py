@@ -542,3 +542,102 @@ def test_boxed_kernel_matches_xla_f32(kw, steps):
     b = np.asarray(g.get_cell_data(want, "density", ids))
     np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-6 * np.abs(b).max())
     assert np.isclose(kern.total_mass(got), kern.total_mass(state), rtol=1e-6)
+
+
+# ------------------------------------- face velocities prepared once a field
+
+
+def _signed_state(adv, g, seed):
+    """Seeded density and signed velocities on every axis (each face is
+    upwind either way), ghost copies refreshed."""
+    rng = np.random.default_rng(seed)
+    ids = g.get_cells()
+    state = adv.initialize_state()
+    for f, lo, hi in (("density", 1.0, 2.0), ("vx", -0.3, 0.3),
+                      ("vy", -0.3, 0.3), ("vz", -0.3, 0.3)):
+        state = adv.set_cell_data(state, f, ids, rng.uniform(lo, hi, len(ids)))
+    return g.update_copies_of_remote_neighbors(state)
+
+
+@pytest.mark.parametrize("n_devices", [1, 4])
+def test_boxed_cached_runs_match_fresh_models(n_devices):
+    """Repeated boxed runs on one model reuse the face velocities its
+    first call prepared; each call gives bit for bit what a fresh model
+    gives, which prepares them anew, and hands back the very velocity
+    arrays it was given.  (On several devices ``run`` takes the flat
+    path, so the boxed run is called directly.)"""
+    g = _grid(n=8, maxref=1, n_devices=n_devices)
+    adv = Advection(g, dtype=np.float64, allow_dense=False)
+    state = _signed_state(adv, g, seed=n_devices)
+    dt = np.float64(0.4 * adv.max_time_step(state))
+    s = state
+    for _ in range(3):
+        fresh = Advection(g, dtype=np.float64, allow_dense=False)
+        want = fresh._boxed_run(s, 4, dt)
+        got = adv._boxed_run(s, 4, dt)
+        for f in ("vx", "vy", "vz"):
+            assert got[f] is s[f]
+        np.testing.assert_array_equal(np.asarray(got["density"]),
+                                      np.asarray(want["density"]))
+        s = got
+
+
+@pytest.mark.parametrize("n_devices", [1, 4])
+def test_boxed_new_velocities_prepare_again(n_devices):
+    """A velocity field set between calls is a new array: the next call
+    prepares its faces again and equals a fresh model's run on it, and
+    not the run on the field before."""
+    from dccrg_tpu import obs
+
+    g = _grid(n=8, maxref=1, n_devices=n_devices)
+    adv = Advection(g, dtype=np.float64, allow_dense=False)
+    state = _signed_state(adv, g, seed=10 + n_devices)
+    dt = np.float64(0.4 * adv.max_time_step(state))
+    run = adv._boxed_run
+    s = run(state, 4, dt)
+    ids = g.get_cells()
+    vx = np.asarray(adv.get_cell_data(s, "vx", ids))
+    s2 = g.update_copies_of_remote_neighbors(
+        adv.set_cell_data(s, "vx", ids, -vx))
+    obs.enable()
+    obs.metrics.reset()
+    got = run(s2, 4, dt)
+    assert obs.metrics.counter_value("boxed.velocity_prep",
+                                     result="miss") == 1
+    assert obs.metrics.counter_value("boxed.velocity_prep", result="hit") == 0
+    fresh = Advection(g, dtype=np.float64, allow_dense=False)
+    want = fresh._boxed_run(s2, 4, dt)
+    np.testing.assert_array_equal(np.asarray(got["density"]),
+                                  np.asarray(want["density"]))
+    stale = run(s, 4, dt)
+    assert not np.array_equal(np.asarray(stale["density"]),
+                              np.asarray(got["density"]))
+
+
+def test_boxed_velocity_prep_counts_one_miss_then_hits():
+    """``boxed.velocity_prep`` counts each boxed dispatch once: a miss for
+    the first call on a field, hits for the calls that follow on the
+    state it returned.  Host numpy velocities can be written in place
+    under the same id, so they are prepared on every call."""
+    from dccrg_tpu import obs
+
+    g = _grid(n=8, maxref=1)
+    adv = Advection(g, dtype=np.float64, allow_dense=False)
+    assert adv.path == "boxed"
+    state = _signed_state(adv, g, seed=20)
+    dt = np.float64(0.4 * adv.max_time_step(state))
+    obs.enable()
+    obs.metrics.reset()
+    s = state
+    for _ in range(4):
+        s = adv.run(s, 2, dt)
+    count = obs.metrics.counter_value
+    assert count("boxed.velocity_prep", result="miss") == 1
+    assert count("boxed.velocity_prep", result="hit") == 3
+    host = {**s, **{f: np.asarray(s[f]) for f in ("vx", "vy", "vz")}}
+    a = adv.run(host, 2, dt)
+    b = adv.run(host, 2, dt)
+    assert count("boxed.velocity_prep", result="miss") == 3
+    assert count("boxed.velocity_prep", result="hit") == 3
+    np.testing.assert_array_equal(np.asarray(a["density"]),
+                                  np.asarray(b["density"]))

@@ -180,6 +180,80 @@ def test_boxed_moves_refined_levels(one_chip, box, runs):
              _spec(one_chip, (nz, ny, nx)), kernel="boxed_move_scatter")
 
 
+def test_boxed_prepare_and_run(topo, monkeypatch):
+    """The boxed whole run's two programs for one v5e chip, on a 16^3
+    grid with a ball refined once, in float32 with every level moved by
+    the Pallas moves: ``advection.boxed_prepare`` gathers the three
+    velocities into each level's box, once per velocity field;
+    ``advection.boxed_run`` gathers only the density and scatters it
+    back.  The grid is built on the CPU; the test hands
+    ``build_boxed_run`` the described chip's mesh and tells it Pallas is
+    there."""
+    from types import SimpleNamespace
+
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from dccrg_tpu import CartesianGeometry, Grid, make_mesh
+    from dccrg_tpu.models import Advection, boxed_advection
+    from dccrg_tpu.parallel.mesh import SHARD_AXIS
+
+    n = 16
+    jax.config.update("jax_enable_x64", True)  # the grid's 64-bit ids
+    try:
+        g = (Grid().set_initial_length((n, n, n))
+             .set_neighborhood_length(0).set_periodic(True, True, True)
+             .set_maximum_refinement_level(1)
+             .set_geometry(CartesianGeometry, start=(0.0, 0.0, 0.0),
+                           level_0_cell_length=(1.0 / n,) * 3)
+             .initialize(mesh=make_mesh(n_devices=1)))
+        ids = g.get_cells()
+        r = np.linalg.norm(g.geometry.get_center(ids) - 0.5, axis=1)
+        for cid in ids[r < 0.25]:
+            g.refine_completely(int(cid))
+        g.stop_refining()
+        adv = Advection(g, dtype=np.float32, allow_dense=False)
+        state = adv.initialize_state()
+    finally:
+        jax.config.update("jax_enable_x64", False)
+    assert adv.boxed is not None
+    mesh = Mesh(np.array(topo.devices[:1]), (SHARD_AXIS,))
+    monkeypatch.setattr(boxed_advection, "pallas_available", lambda _: True)
+    monkeypatch.setattr(boxed_advection, "put_table", lambda a, _: a)
+    chip = SimpleNamespace(dtype=np.float32, use_pallas=True,
+                           grid=SimpleNamespace(mapping=g.mapping,
+                                                topology=g.topology,
+                                                mesh=mesh, epoch=g.epoch))
+    run, moved = boxed_advection.build_boxed_run(chip, adv.boxed)
+    L = len(moved)
+    assert L == 2 and all(moved)
+
+    def rows(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                    sharding=NamedSharding(mesh, P(SHARD_AXIS)))
+
+    def every(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, P()))
+
+    statics = jax.tree.map(rows, run.statics)
+    rho = rows(state["density"])
+    gathers = r"= .* custom-call\(.*custom_call_target=\"tpu_custom_call\".*"
+    prep = _compile(run.prepare.fn, statics, rho, rho, rho,
+                    kernel="boxed_move_gather")
+    text = prep.as_text()
+    assert "HloModule jit_advection_boxed_prepare" in text
+    assert len(re.findall(r"%boxed_move_gather\S* " + gathers, text)) == 3 * L
+    assert "boxed_move_scatter" not in text
+    faces = jax.tree.map(rows, jax.eval_shape(run.prepare.fn, statics,
+                                              rho, rho, rho))
+    step = _compile(run.advance.fn, statics, faces, rho,
+                    rows(state["flux"]), every((), jnp.int32), every(()),
+                    kernel="boxed_move_scatter")
+    text = step.as_text()
+    assert "HloModule jit_advection_boxed_run" in text
+    assert len(re.findall(r"%boxed_move_gather\S* " + gathers, text)) == L
+
+
 def test_flat_amr_refined(one_chip):
     """chip_smoke.py's 48^3 ball-refined grid: a 96^3 fine-voxel layout, the
     x extent lane-padded to 128 (it fits VMEM).  The unpadded form takes
